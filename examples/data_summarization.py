@@ -1,14 +1,17 @@
 """Data summarization with the distributed GreedyML driver.
 
-Runs the actual shard_map implementation (the one the 512-chip dry-run
-lowers) on 8 forced host devices: selects k diverse exemplars from a
-mixture-of-Gaussians image set with the k-medoid objective, then shows the
-facility-location coreset used by the training pipeline.
+Runs the actual shard_map implementation over every device there is: the
+chips of an accelerator host, or 8 simulated host devices when JAX is held
+to the CPU. Selects k diverse exemplars from a mixture-of-Gaussians image
+set with the k-medoid objective, then shows the facility-location coreset
+used by the training pipeline.
 
-    PYTHONPATH=src python examples/data_summarization.py
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/data_summarization.py
+    PYTHONPATH=src python examples/data_summarization.py    # on a TPU host
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+from repro.launch.mesh import force_host_devices
+
+force_host_devices(8, count_flag=None)   # before jax is imported
 
 import jax
 import jax.numpy as jnp
@@ -20,32 +23,41 @@ from repro.core.greedyml import greedyml_distributed
 from repro.core.simulate import global_value
 from repro.data import synthetic
 from repro.launch.mesh import make_machine_mesh
+from repro.runtime import compile_cache
 
 N, D, K = 2048, 256, 32
 
+compile_cache.enable()
+# the tree's machines: the largest power of two of devices present
+machines = 1 << (len(jax.devices()).bit_length() - 1)
 print(f"k-medoid exemplar selection: {N} images, d={D}, k={K}")
 imgs = synthetic.gen_images(N, D, classes=16, seed=3)
-
-mesh = make_machine_mesh(8, 2)                     # T(m=8, L=3, b=2)
-obj = make_objective("kmedoid")
 ids = jnp.arange(N, dtype=jnp.int32)
-sol = greedyml_distributed(obj, ids, jnp.asarray(imgs), jnp.ones(N, bool),
-                           K, mesh, tree_axes=("lvl0", "lvl1", "lvl2"))
-sel = np.asarray(sol.ids)[np.asarray(sol.valid)]
-print(f"GreedyML over {mesh.devices.size} devices "
-      f"(axes {mesh.axis_names}): picked {len(sel)} exemplars")
-print(f"  global k-medoid value: "
-      f"{global_value('kmedoid', imgs, sel):.4f}")
+obj = make_objective("kmedoid")
+fac = make_objective("facility")
 
 ref = greedy(obj, ids, jnp.asarray(imgs), jnp.ones(N, bool), K)
 ref_sel = np.asarray(ref.ids)[np.asarray(ref.valid)]
 print(f"  sequential Greedy     : "
       f"{global_value('kmedoid', imgs, ref_sel):.4f}")
 
+if machines < 2:
+    print("one device: the GreedyML tree needs at least two")
+    raise SystemExit(0)
+
+mesh = make_machine_mesh(machines, 2)              # T(m, L=log2 m, b=2)
+tree_axes = tuple(reversed(mesh.axis_names))
+sol = greedyml_distributed(obj, ids, jnp.asarray(imgs), jnp.ones(N, bool),
+                           K, mesh, tree_axes=tree_axes)
+sel = np.asarray(sol.ids)[np.asarray(sol.valid)]
+print(f"GreedyML over {mesh.devices.size} devices "
+      f"(axes {mesh.axis_names}): picked {len(sel)} exemplars")
+print(f"  global k-medoid value: "
+      f"{global_value('kmedoid', imgs, sel):.4f}")
+
 # facility-location coreset (what --data-selection greedyml:facility uses)
-fac = make_objective("facility")
 sol_f = greedyml_distributed(fac, ids, jnp.asarray(imgs), jnp.ones(N, bool),
-                             K, mesh, tree_axes=("lvl0", "lvl1", "lvl2"))
+                             K, mesh, tree_axes=tree_axes)
 sel_f = np.asarray(sol_f.ids)[np.asarray(sol_f.valid)]
 print(f"facility-location coreset: {len(sel_f)} docs, "
       f"coverage={global_value('facility', imgs, sel_f):.4f}")

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.greedy import Solution, greedy, replay_value, select_better
 from repro.kernels import ops as kernel_ops
@@ -276,10 +275,10 @@ def greedyml_distributed(objective, ids: jax.Array, payloads: jax.Array,
                            sample_level=sample_level, engine=engine,
                            node_engine=node_engine, seed=seed,
                            constraint=constraint)
-    out = shard_map(fn, mesh=mesh,
-                    in_specs=tuple(in_specs),
-                    out_specs=Solution(P(), P(), P(), P(), P()),
-                    check_rep=False)(*args)
+    out = jax.shard_map(fn, mesh=mesh,
+                        in_specs=tuple(in_specs),
+                        out_specs=Solution(P(), P(), P(), P(), P()),
+                        check_vma=False)(*args)
     return out
 
 
@@ -489,9 +488,9 @@ class LevelDispatcher:
             return jax.tree.map(lambda x: x[None], s)
 
         sol_spec = Solution(spec, spec, spec, spec, spec)
-        return jax.jit(shard_map(body, mesh=self.mesh,
-                                 in_specs=(spec, spec, spec),
-                                 out_specs=sol_spec, check_rep=False))
+        return jax.jit(jax.shard_map(body, mesh=self.mesh,
+                                     in_specs=(spec, spec, spec),
+                                     out_specs=sol_spec, check_vma=False))
 
     def _build_level(self, lvl: int, has_aug: bool):
         axes, radices = self.tree_axes, self.radices
@@ -542,8 +541,9 @@ class LevelDispatcher:
             return jax.tree.map(lambda x: x[None], out)
 
         in_specs = (sol_spec, P()) if has_aug else (sol_spec,)
-        return jax.jit(shard_map(shbody, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=sol_spec, check_rep=False))
+        return jax.jit(jax.shard_map(shbody, mesh=self.mesh,
+                                     in_specs=in_specs,
+                                     out_specs=sol_spec, check_vma=False))
 
 
 def randgreedi_distributed(objective, ids, payloads, valid, k, mesh,
@@ -604,6 +604,6 @@ def randgreedi_distributed(objective, ids, payloads, valid, k, mesh,
     if augment is not None:
         in_specs.append(P())
         args.append(augment)
-    return shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=Solution(P(), P(), P(), P(), P()),
-                     check_rep=False)(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=Solution(P(), P(), P(), P(), P()),
+                         check_vma=False)(*args)

@@ -14,7 +14,7 @@ coverage — see kernels/rules.py), a greedy step is
        step, emitting only (best_idx, best_gain) scalars.
 
 Grid: (N/BN,) — each program holds a (BN, C) row-block of the cached matrix
-in VMEM. BN comes from the EnginePlan (kernels/plans.py); when even BN=8
+in VMEM; the (N,) state row stays resident whole. BN comes from the EnginePlan (kernels/plans.py); when even BN=8
 does not fit, the planner routes the caller to the per-step engine (the
 paper's memory-capped regime).
 
@@ -34,24 +34,43 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import rules as R
 from repro.kernels.rules import (KernelRule, fold_winner,  # noqa: F401
                                  masked_argmax, partial_gains)
-from repro.kernels.tpu_compat import compiler_params
 
 F32 = jnp.float32
+LANES = 128
 
 
-def _step_body(m, prev, row_ref, mask_ref,
-               newrow_ref, best_ref, gain_ref, acc_ref, rule: KernelRule):
-    """The fused step over one (BN, C) slab `m` (already rescaled to the
-    matrix's logical f32/uint32 values) — shared by the plain and the
-    int8-quantized kernel entry points."""
+def read_col(mat_ref, idx, scale=None):
+    """Column `idx` (clamped at 0) of a VMEM matrix ref as a (1, R) row.
+    Mosaic slices the lane axis only at 128-aligned offsets, so this loads
+    the aligned 128-lane window that holds the column and picks its lane
+    (`rules.lane_pick`). `scale`: the (1, R) per-row scales of int8
+    storage, applied to the window exactly as `rules.dequant` would to the
+    whole slab."""
+    i = jnp.maximum(idx, 0)
+    base = pl.multiple_of(i // LANES * LANES, LANES)
+    win = mat_ref[:, pl.ds(base, LANES)]
+    if scale is not None:
+        win = R.dequant(win, scale)
+    return R.lane_pick(win, i - base).T
+
+
+def _kernel(prev_ref, mat_ref, *refs, rule: KernelRule, quant: bool):
+    """The fused step over one (BN, C) slab of the cached matrix. The
+    state row (and, for int8 storage, the per-row scales) arrive whole as
+    (N/BN, BN) blocks, so a row block narrower than 128 lanes still tiles;
+    grid step `ni` reads and writes row `ni` of them. int8 storage is
+    rescaled on-chip against its scales before the identical f32 algebra."""
+    scale_ref, refs = (refs[0], refs[1:]) if quant else (None, refs)
+    row_ref, mask_ref, newrow_ref, best_ref, gain_ref, acc_ref = refs
     ni = pl.program_id(0)
-    r = row_ref[...]                                   # (1, BN)
+    prev = prev_ref[0]
+    scale = scale_ref[pl.ds(ni, 1), :] if quant else None
+    m = R.dequant(mat_ref[...], scale) if quant else mat_ref[...]
 
     # 1. deferred update: fold the previous winner's column into the state
-    col = jax.lax.dynamic_slice(m, (0, jnp.maximum(prev, 0)),
-                                (m.shape[0], 1)).T     # (1, BN)
-    new_r = R.fold_winner(r, col, prev, rule)
-    newrow_ref[...] = new_r
+    new_r = R.fold_winner(row_ref[pl.ds(ni, 1), :],
+                          read_col(mat_ref, prev, scale), prev, rule)
+    newrow_ref[pl.ds(ni, 1), :] = new_r
 
     # 2. partial gains for this row block, accumulated on-chip
     @pl.when(ni == 0)
@@ -60,40 +79,27 @@ def _step_body(m, prev, row_ref, mask_ref,
 
     acc_ref[...] += R.partial_gains(new_r, m, rule)
 
-    # 3. masked argmax at the final grid step — scalars out, no (1, C) row
+    # 3. masked argmax at the final grid step — scalars out (broadcast
+    # over one lane-dense (1, 128) block), no (1, C) row
     @pl.when(ni == pl.num_programs(0) - 1)
     def _argmax():
         first, mx = R.masked_argmax(acc_ref[...], mask_ref[...])
-        best_ref[0, 0] = first
-        gain_ref[0, 0] = mx
+        best_ref[...] = jnp.broadcast_to(first, best_ref.shape)
+        gain_ref[...] = jnp.broadcast_to(mx, gain_ref.shape)
 
 
-def _kernel(prev_ref, mat_ref, row_ref, mask_ref,
-            newrow_ref, best_ref, gain_ref, acc_ref, *, rule: KernelRule):
-    _step_body(mat_ref[...], prev_ref[0, 0], row_ref, mask_ref,
-               newrow_ref, best_ref, gain_ref, acc_ref, rule)
-
-
-def _kernel_quant(prev_ref, mat_ref, scale_ref, row_ref, mask_ref,
-                  newrow_ref, best_ref, gain_ref, acc_ref, *,
-                  rule: KernelRule):
-    # int8 rescale-accumulate: dequantize the (BN, C) slab against its
-    # (1, BN) per-row scales ON-CHIP, then run the identical f32 algebra
-    m = R.dequant(mat_ref[...], scale_ref[...])
-    _step_body(m, prev_ref[0, 0], row_ref, mask_ref,
-               newrow_ref, best_ref, gain_ref, acc_ref, rule)
-
-
-@functools.partial(jax.jit, static_argnames=("rule", "block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("rule", "block_n", "interpret",
+                                             "vmem_limit_bytes"))
 def fused_step_pallas(mat: jax.Array, row: jax.Array, mask: jax.Array,
                       prev: jax.Array, rule: KernelRule,
                       block_n: int = 256, interpret: bool = False,
-                      scale=None):
+                      scale=None, vmem_limit_bytes: int = 0):
     """mat: (N, C) cached matrix, row: (N,) state in the rule's row dtype,
     mask: (C,) 0/1 f32, prev: () int32 previous winner (-1 = none).
     scale: (1, N) f32 per-row scales when `mat` is int8-quantized storage
     (rules.quantize_rows) — the kernel rescales each slab to f32 on-chip
     before the shared algebra; None for f32/bf16/uint32 storage.
+    vmem_limit_bytes: Mosaic's scoped-VMEM limit (plans.vmem_limit).
 
     Returns (new_row (N,), best () int32, best_gain () f32). best_gain is
     the raw masked part-sum — callers normalize by the valid ground count.
@@ -101,40 +107,40 @@ def fused_step_pallas(mat: jax.Array, row: jax.Array, mask: jax.Array,
     """
     n, c = mat.shape
     assert n % block_n == 0 and c % 128 == 0, (n, c, block_n)
-    grid = (n // block_n,)
+    nb = n // block_n
+    whole_row = pl.BlockSpec((nb, block_n), lambda ni: (0, 0))
     in_specs = [
-        pl.BlockSpec((1, 1), lambda ni: (0, 0)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((block_n, c), lambda ni: (ni, 0)),
-        pl.BlockSpec((1, block_n), lambda ni: (0, ni)),
+        whole_row,
         pl.BlockSpec((1, c), lambda ni: (0, 0)),
     ]
-    operands = [prev.reshape(1, 1).astype(jnp.int32), mat,
-                row.reshape(1, n), mask.reshape(1, c)]
-    kernel = _kernel
+    operands = [prev.reshape(1).astype(jnp.int32), mat,
+                row.reshape(nb, block_n), mask.reshape(1, c)]
     if scale is not None:
         assert scale.shape == (1, n), (scale.shape, n)
-        # the scale row blocks exactly like the state row
-        in_specs.insert(2, pl.BlockSpec((1, block_n), lambda ni: (0, ni)))
-        operands.insert(2, scale)
-        kernel = _kernel_quant
+        in_specs.insert(2, whole_row)
+        operands.insert(2, scale.reshape(nb, block_n))
     new_row, best, gain = pl.pallas_call(
-        functools.partial(kernel, rule=rule),
-        grid=grid,
+        functools.partial(_kernel, rule=rule, quant=scale is not None),
+        grid=(nb,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_n), lambda ni: (0, ni)),
-            pl.BlockSpec((1, 1), lambda ni: (0, 0)),
-            pl.BlockSpec((1, 1), lambda ni: (0, 0)),
+            whole_row,
+            pl.BlockSpec((1, LANES), lambda ni: (0, 0)),
+            pl.BlockSpec((1, LANES), lambda ni: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, n), rule.dtype),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-            jax.ShapeDtypeStruct((1, 1), F32),
+            jax.ShapeDtypeStruct((nb, block_n), rule.dtype),
+            jax.ShapeDtypeStruct((1, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((1, LANES), F32),
         ],
         scratch_shapes=[pltpu.VMEM((1, c), F32)],
         # the row-block dim carries the gains accumulator + end-of-grid
         # argmax, so it is order-dependent
-        compiler_params=compiler_params("arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit_bytes or None),
         interpret=interpret,
     )(*operands)
-    return new_row[0], best[0, 0], gain[0, 0]
+    return new_row.reshape(n), best[0, 0], gain[0, 0]

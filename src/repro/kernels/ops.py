@@ -249,7 +249,9 @@ def fused_step(mat, row, mask, prev, rule: KernelRule, backend=None,
     new_row, best, gain = fused_step_pallas(
         mat.q if quant else mat, r, mk, prev, rule, block_n=bn,
         interpret=(b == "interpret"),
-        scale=mat.scale if quant else None)
+        scale=mat.scale if quant else None,
+        vmem_limit_bytes=plans.vmem_limit(
+            plans.fused_need(bn, n_pad, c_pad, mat.dtype.itemsize)))
     return new_row[:n], best, gain
 
 
@@ -279,7 +281,9 @@ def greedy_loop(mat, row, mask, k: int, rule: KernelRule, backend=None,
     new_row, bests, gains_ = greedy_loop_pallas(
         mat.q if quant else mat, r, mk, k, rule, block_n=bn,
         interpret=(b == "interpret"),
-        scale=mat.scale if quant else None)
+        scale=mat.scale if quant else None,
+        vmem_limit_bytes=plans.vmem_limit(
+            plans.loop_need(bn, n_pad, c_pad, mat.dtype.itemsize)))
     return new_row[:n], bests, gains_
 
 
@@ -330,19 +334,23 @@ def greedy_loop_resident(ground, cands, row, mask, k: int,
         g = _dummy_ground()
         cd = _pad_to(_pad_to(cands, 0, 128), 1, 128)
         n_pad, c_pad = cd.shape[1], cd.shape[0]
+        d_pad = None
         r = _pad_to(_cast_row(row, rule), 0, 128).reshape(1, n_pad)
     else:
         g = _pad_to(_pad_to(ground, 0, RES_TILE_N), 1, 128, bucket=False)
         cd = _pad_to(_pad_to(cands, 0, 128), 1, 128, bucket=False)
-        n_pad, c_pad = g.shape[0], cd.shape[0]
+        n_pad, c_pad, d_pad = g.shape[0], cd.shape[0], g.shape[1]
         r = _pad_to(_cast_row(row, rule), 0, RES_TILE_N,
                     value=_row_pad_value(rule)).reshape(1, n_pad)
     mk = _pad_to(mask.astype(F32), 0, 128).reshape(1, c_pad)
     ctl = jnp.stack([kq_, jnp.asarray(ln, jnp.int32),
                      jnp.asarray(lc, jnp.int32)]).reshape(1, 3)
+    # the kernel holds its on-chip matrix in f32 whatever the plan's
+    # storage dtype, so its working set is the f32 one
+    need = plans.resident_need(n_pad, c_pad, d_pad, rule=rule)
     new_row, bests, gains_ = greedy_loop_resident_pallas(
         g, cd, r, mk, ctl, k, rule, interpret=(b == "interpret"),
-        cache_dtype=cache_dtype)
+        cache_dtype=cache_dtype, vmem_limit_bytes=plans.vmem_limit(need))
     return new_row[:n], bests, gains_
 
 
@@ -458,7 +466,7 @@ def stream_filter(ground, batch, rows, row0, values, counts, expos, m_max,
                      bucket=False)
         n_pad = bt.shape[1]
     else:
-        g = _pad_to(_pad_to(ground, 0, RES_TILE_N, bucket=False), 1, 128,
+        g = _pad_to(_pad_to(ground, 0, 128, bucket=False), 1, 128,
                     bucket=False)
         bt = _pad_to(_pad_to(batch, 0, 128, bucket=False), 1, 128,
                      bucket=False)
@@ -483,10 +491,13 @@ def stream_filter(ground, batch, rows, row0, values, counts, expos, m_max,
                           bucket=False),
             spent=spent.astype(F32).reshape(l, 1),
             budget=jnp.asarray(budget, F32).reshape(1, 1))
+    need = plans.stream_need(n, l, b, d, plan["dtype"])
     out = stream_filter_pallas(g, bt, r, r0, vals, cnt, exp_, m_, bv, k,
                                eps_log, rule,
                                interpret=(bk == "interpret"),
-                               gscale=gscale, **cost_kw)
+                               gscale=gscale,
+                               vmem_limit_bytes=plans.vmem_limit(need),
+                               **cost_kw)
     rows_o, vals_o, cnt_o, admits, expos_o, m_o, expired = out[:7]
     res = (rows_o[:, :n], vals_o[:, 0], cnt_o[:, 0], admits[:, :b] > 0,
            expos_o[:, 0], m_o[0, 0], expired[:, 0] > 0)

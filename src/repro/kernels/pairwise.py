@@ -30,10 +30,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import rules as R
 from repro.kernels.rules import KernelRule, pairwise_block  # noqa: F401
-from repro.kernels.tpu_compat import compiler_params
 
 F32 = jnp.float32
 
@@ -73,7 +73,8 @@ def pairwise_pallas(ground: jax.Array, cands: jax.Array, mode: str = "dist",
         out_specs=pl.BlockSpec((TILE_N, TILE_C), lambda ni, ci: (ni, ci)),
         out_shape=jax.ShapeDtypeStruct((n, c), jnp.dtype(out_dtype)),
         # every block is independent — Mosaic may pipeline/reorder both dims
-        compiler_params=compiler_params("parallel", "parallel"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(ground, cands)
 
@@ -164,7 +165,8 @@ def gains_pallas(ground: jax.Array, row: jax.Array, cands: jax.Array,
         # candidate blocks are independent (parallel); the inner
         # ground/word dim accumulates into the revisited output block
         # (arbitrary), which Mosaic can still software-pipeline
-        compiler_params=compiler_params("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
     return out[0]

@@ -129,51 +129,65 @@ def _block_min(itemsize: int) -> int:
     return {1: 32, 2: 16}.get(itemsize, 8)
 
 
-def fused_block_n(n_pad: int, c_pad: int, itemsize: int = 4) -> int:
-    """Largest power-of-two row-block (≤256) whose fused-step working set
-    fits the VMEM budget; 0 if none fits.
-
-    Working set: the (BN, C) matrix slab (cache storage dtype), the
-    (BN, C) f32 gain-partials temporary the kernel materializes (int8
-    storage pays a SECOND f32 slab for the in-kernel dequant before the
-    partials), the (1, C) gains accumulator and mask blocks, and two
-    (1, BN) state rows. bf16/int8 storage floors BN at their
-    (16, 128)/(32, 128) min tiles.
-    """
-    vmem = flags.fused_vmem_mb() * 2 ** 20
+def fused_need(bn: int, n_pad: int, c_pad: int, itemsize: int = 4) -> int:
+    """VMEM bytes of one fused-step grid cell at row block `bn`: the
+    (BN, C) matrix slab (cache storage dtype), the (BN, C) f32
+    gain-partials temporary the kernel materializes (int8 storage pays a
+    SECOND f32 slab for the in-kernel dequant before the partials), the
+    (1, C) gains accumulator and mask blocks, and the whole (N,) state row
+    in and out (plus the int8 scale row)."""
     f32_slabs = 2 if itemsize == 1 else 1
+    rows = 3 if itemsize == 1 else 2
+    return (bn * c_pad * itemsize
+            + (bn * c_pad * f32_slabs + 3 * c_pad + rows * n_pad) * 4)
+
+
+def loop_need(bn: int, n_pad: int, c_pad: int, itemsize: int = 4) -> int:
+    """VMEM bytes of one streaming-megakernel grid cell: the fused-step
+    cell plus the loop's (1, C) evolving candidate mask."""
+    return fused_need(bn, n_pad, c_pad, itemsize) + c_pad * 4
+
+
+def _widest_block(n_pad: int, itemsize: int, need) -> int:
+    """Largest power-of-two row block (≤256, ≥ the dtype's min tile) whose
+    working set `need(bn)` fits the fused VMEM budget; 0 if none fits."""
+    vmem = flags.fused_vmem_mb() * 2 ** 20
     bn = 256
     while bn >= _block_min(itemsize):
-        if (bn <= n_pad
-                and (bn * c_pad * itemsize
-                     + (bn * c_pad * f32_slabs + 3 * c_pad + 2 * bn) * 4)
-                <= vmem):
+        if bn <= n_pad and need(bn) <= vmem:
             return bn
         bn //= 2
     return 0
+
+
+def fused_block_n(n_pad: int, c_pad: int, itemsize: int = 4) -> int:
+    """Row block for the per-step fused kernel (`fused_need`); 0 if none
+    fits. bf16/int8 storage floors BN at their (16, 128)/(32, 128) min
+    tiles."""
+    return _widest_block(n_pad, itemsize,
+                         lambda bn: fused_need(bn, n_pad, c_pad, itemsize))
 
 
 def loop_block_n(n_pad: int, c_pad: int, itemsize: int = 4) -> int:
-    """Row block for the STREAMING megakernel tier; 0 if none fits.
-
-    Same per-block working set as fused_block_n plus the loop's persistent
-    scratch: the full (N/BN, BN) state row, the evolving (1, C) candidate
-    mask, and the (1, C) gains accumulator."""
-    vmem = flags.fused_vmem_mb() * 2 ** 20
-    f32_slabs = 2 if itemsize == 1 else 1
-    bn = 256
-    while bn >= _block_min(itemsize):
-        if (bn <= n_pad
-                and (bn * c_pad * itemsize
-                     + (bn * c_pad * f32_slabs + 4 * c_pad + n_pad
-                        + 2 * bn) * 4)
-                <= vmem):
-            return bn
-        bn //= 2
-    return 0
+    """Row block for the STREAMING megakernel tier (`loop_need`); 0 if
+    none fits."""
+    return _widest_block(n_pad, itemsize,
+                         lambda bn: loop_need(bn, n_pad, c_pad, itemsize))
 
 
-def _resident_need(n_pad: int, c_pad: int, d_pad: Optional[int],
+# Mosaic's scoped-VMEM limit for a kernel that names none (v5e: 16 MiB)
+_SCOPED_VMEM_DEFAULT = 16 * 2 ** 20
+
+
+def vmem_limit(need: int) -> int:
+    """Scoped-VMEM limit handed to Mosaic for a kernel whose modeled
+    working set is `need` bytes: twice the model, because Pallas
+    double-buffers every pipelined block, and never below Mosaic's own
+    default."""
+    return max(_SCOPED_VMEM_DEFAULT, 2 * int(need))
+
+
+def resident_need(n_pad: int, c_pad: int, d_pad: Optional[int],
                    rule: Optional[KernelRule] = None,
                    itemsize: int = 4) -> Optional[int]:
     """Bytes of VMEM one resident-tier invocation holds (the working-set
@@ -214,7 +228,7 @@ def resident_fits(n_pad: int, c_pad: int, d_pad: Optional[int],
     column for int8). That is what raises the memory-bounded N ceiling
     ~2× per halving of the storage width — the paper's larger-instance
     regime (§6.4) at fixed per-node memory."""
-    need = _resident_need(n_pad, c_pad, d_pad, rule=rule,
+    need = resident_need(n_pad, c_pad, d_pad, rule=rule,
                           itemsize=itemsize)
     return need is not None and need <= flags.fused_vmem_mb() * 2 ** 20
 
@@ -298,6 +312,29 @@ def fused_plan(n: int, c: int, d: Optional[int] = None,
             "block_n": bn, "loop_block_n": bn_loop, "dtype": dtype}
 
 
+def stream_need(n: int, l: int, b: int, d: Optional[int],
+                dtype: str) -> int:
+    """VMEM bytes of one stream-filter dispatch: the (N, D)/(B, D) feature
+    blocks — or the (B, W) bits input for bitmap rules (N = W) — the
+    on-chip (N, B) matrix and its (B, N) transpose scratch, the (L, N)
+    level rows (in, out, and the gain-partials temporary), and the
+    (L, B) admit matrix plus per-level columns. N (rows or words) and B
+    are padded to 128 lanes and L to a sublane multiple, as the ops.py
+    wrapper pads them."""
+    n_pad, b_pad = -(-n // 128) * 128, -(-b // 128) * 128
+    l_pad = -(-l // RES_TILE_N) * RES_TILE_N
+    if dtype == "uint32":
+        feat = 4 * b_pad * n_pad
+    else:
+        d_pad = -(-(d or 0) // 128) * 128
+        feat = (n_pad * d_pad * cache_itemsize(dtype)
+                + 4 * b_pad * d_pad
+                + (4 * n_pad if dtype == "int8" else 0))   # scale row
+    return feat + 4 * (2 * n_pad * b_pad
+                       + 3 * l_pad * n_pad + 2 * l_pad * b_pad
+                       + 8 * l_pad)
+
+
 def stream_plan(n: int, l: int, b: int, d: Optional[int],
                 backend=None, rule: Optional[KernelRule] = None
                 ) -> Optional[dict]:
@@ -325,20 +362,7 @@ def stream_plan(n: int, l: int, b: int, d: Optional[int],
                    else "float32"))
     if bk == "ref":
         return {"tier": "ref", "dtype": dtype}
-    n_pad = -(-n // RES_TILE_N) * RES_TILE_N
-    l_pad = -(-l // RES_TILE_N) * RES_TILE_N
-    b_pad = -(-b // 128) * 128
-    if bitmap:
-        n_pad = -(-n // 128) * 128          # words are a lane dim too
-        feat = 4 * b_pad * n_pad            # the (B, W) bits input
-    else:
-        d_pad = -(-(d or 0) // 128) * 128
-        feat = (n_pad * d_pad * cache_itemsize(dtype)
-                + 4 * b_pad * d_pad
-                + (4 * n_pad if dtype == "int8" else 0))   # scale row
-    need = feat + 4 * (n_pad * b_pad
-                       + 3 * l_pad * n_pad + 2 * l_pad * b_pad
-                       + 8 * l_pad)
+    need = stream_need(n, l, b, d, dtype)
     if need <= flags.stream_vmem_mb() * 2 ** 20:
         return {"tier": "kernel", "dtype": dtype}
     return None
@@ -459,7 +483,7 @@ def serve_plan(rule: KernelRule, n: int, c: int, d: Optional[int],
         c_pad = bucket_len(c, 128)
         n_res = bucket_len(n, 128 if rule.is_bitmap else RES_TILE_N)
         d_pad = -(-d // 128) * 128 if d else None
-    need = _resident_need(n_res, c_pad, d_pad, rule=rule,
+    need = resident_need(n_res, c_pad, d_pad, rule=rule,
                           itemsize=itemsize)
     if need is None:
         return None

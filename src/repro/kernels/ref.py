@@ -21,17 +21,6 @@ from repro.kernels.rules import KernelRule
 F32 = jnp.float32
 
 
-def pairwise_dist(ground: jax.Array, cands: jax.Array) -> jax.Array:
-    """(N, D) × (C, D) → (N, C) Euclidean distances, the k-medoid cached
-    matrix (same ‖x‖²+‖c‖²−2⟨x,c⟩ expansion as the tiled kernel)."""
-    return R.pairwise_block(ground.astype(F32), cands.astype(F32), "dist")
-
-
-def pairwise_sim(ground: jax.Array, cands: jax.Array) -> jax.Array:
-    """(N, D) × (C, D) → (N, C) inner products, the facility cached matrix."""
-    return ground.astype(F32) @ cands.astype(F32).T
-
-
 def pairwise(ground, cands, rule: KernelRule) -> jax.Array:
     """Full logical cached matrix for any rule: feature rules do the
     pairwise compute; bitmap rules just transpose the payloads (the
@@ -162,7 +151,10 @@ def sieve_reanchor(singletons, bvalid, rows, row0, values, counts, expos,
     # distinct exponents ⇒ expired slots rank uniquely; refill the missing
     # window exponents ascending (max() covers the full-window jump where
     # even the old top fell below the new low)
-    rank = jnp.sum(expired.T & (base.T < base), axis=1, keepdims=True)
+    # (transposing the int32 exponents, not the bool mask: Mosaic has no
+    # transpose of a mask)
+    base_t = base.T
+    rank = jnp.sum((base_t < low) & (base_t < base), axis=1, keepdims=True)
     expos = jnp.where(expired, jnp.maximum(old_high + 1, low) + rank, base)
     rows = jnp.where(expired, jnp.broadcast_to(row0, rows.shape), rows)
     values = jnp.where(expired, 0.0, values)

@@ -180,7 +180,9 @@ def gain_part(row, m, rule: KernelRule):
         return rule.lam * mod + (1.0 - rule.lam) * sat
     if rule.fold == "or":
         new = jnp.bitwise_and(m, jnp.bitwise_not(row))
-        return jax.lax.population_count(new).astype(F32)
+        # via int32: Mosaic has no uint32 → f32 cast (a popcount ≤ 32 is
+        # the same number either way)
+        return jax.lax.population_count(new).astype(jnp.int32).astype(F32)
     raise KeyError(rule.fold)
 
 
@@ -217,6 +219,20 @@ def level_gains(rows, col, rule: KernelRule):
     """(L, N) per-level state rows × (1, N) arrival column → (L, 1) raw
     gains — the level-batched transpose of `partial_gains` (sieve)."""
     return jnp.sum(gain_part(rows, col, rule), axis=1, keepdims=True)
+
+
+def lane_pick(x, idx):
+    """Column `idx` of an (R, L) value as (R, 1) f32 — (R, 1) uint32 for
+    bitmap words — by a one-hot masked lane reduction: Mosaic cannot slice
+    the lane axis at a traced offset. Exact for every value: floats take
+    the max against −inf, words the sum against zeros."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) == idx
+    if x.dtype == jnp.uint32:
+        xi = jax.lax.bitcast_convert_type(x, jnp.int32)
+        col = jnp.sum(jnp.where(hit, xi, 0), axis=1, keepdims=True)
+        return jax.lax.bitcast_convert_type(col, jnp.uint32)
+    return jnp.max(jnp.where(hit, x.astype(F32), _NEG_INF), axis=1,
+                   keepdims=True)
 
 
 def masked_argmax(gains, mask):
@@ -278,13 +294,29 @@ def pairwise_block(g, c, mode: str):
     The single source of the ‖g‖²+‖c‖²−2⟨g,c⟩ expansion — shared by the
     pairwise kernel, the resident megakernel, and the stream filter so
     every engine sees bit-identical matrix entries."""
+    # explicit f32 precision: XLA on the TPU would otherwise run this in
+    # one bf16 pass while Mosaic does not, and the ref backend and the
+    # kernels must see the same matrix
     cross = jax.lax.dot_general(g, c, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=F32)   # (TN, TC)
     if mode == "dot":
         return cross
     gn = jnp.sum(g * g, axis=1, keepdims=True)         # (TN, 1)
     cn = jnp.sum(c * c, axis=1, keepdims=True).T       # (1, TC)
-    return jnp.sqrt(jnp.maximum(gn + cn - 2.0 * cross, 0.0))
+    return _dist_from_sq(gn + cn - 2.0 * cross, gn + cn)
+
+
+# Squared distances below this share of ‖g‖²+‖c‖² are rounding noise of
+# the expansion (about 1e-6 of it for a point against itself in f32, and
+# it differs between XLA's and Mosaic's matmuls), so they count as 0: a
+# selected point then sits at distance exactly 0 from itself on every
+# backend and in the direct ‖g−c‖ formula.
+DIST_REL_TOL = 2.0 ** -14
+
+
+def _dist_from_sq(d2, scale):
+    return jnp.sqrt(jnp.where(d2 > DIST_REL_TOL * scale, d2, 0.0))
 
 
 def matrix_block(g, c, rule: KernelRule):
@@ -320,10 +352,10 @@ def pairwise_col(ground, payload, rule: KernelRule):
     g = ground.astype(F32)
     p = payload.astype(F32)
     if rule.pairwise == "dist":
-        return jnp.sqrt(jnp.maximum(
-            jnp.sum((g - p[None, :]) ** 2, axis=-1), 0.0))
-    col = g @ p                                        # 'dot' family
-    return col
+        return _dist_from_sq(jnp.sum((g - p[None, :]) ** 2, axis=-1),
+                             jnp.sum(g * g, axis=-1) + jnp.sum(p * p))
+    # 'dot' family, at the matrix's f32 precision (pairwise_block)
+    return jnp.dot(g, p, precision=jax.lax.Precision.HIGHEST)
 
 
 def update_row(ground, row, payload, rule: KernelRule):

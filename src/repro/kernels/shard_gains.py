@@ -58,7 +58,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.kernels import ops as kernel_ops
 from repro.kernels import plans
@@ -204,9 +203,9 @@ def shard_greedy_distributed(objective, ids: jax.Array,
 
     spec = P(shard_axis)
     from repro.core.greedy import Solution      # noqa: F811 (pytree specs)
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=Solution(P(), P(), P(), P(), P()),
-                     check_rep=False)(ids, payloads, valid)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=Solution(P(), P(), P(), P(), P()),
+                         check_vma=False)(ids, payloads, valid)
 
 
 def shard_greedy_sim(objective, ids: jax.Array, payloads: jax.Array,

@@ -42,6 +42,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import rules as R
 from repro.kernels.rules import KernelRule, level_gains  # noqa: F401
@@ -51,10 +52,13 @@ F32 = jnp.float32
 
 
 def _body(g, batch_ref, rows_ref, row0_ref, values_ref,
-          counts_ref, expos_ref, m_ref, bvalid_ref, cost_refs, out_refs, *,
-          k: int, eps_log: float, rule: KernelRule):
+          counts_ref, expos_ref, m_ref, bvalid_ref, cost_refs, out_refs,
+          arrivals_ref, *, k: int, eps_log: float, rule: KernelRule):
     bt = batch_ref[...]                                   # (B, D) | (B, W)
     mat = R.matrix_block(g, bt, rule)                     # (N, B), on-chip
+    # arrival-major copy: the loop reads arrival i's column as row i
+    # (Mosaic slices sublanes, not lanes, at a traced offset)
+    arrivals_ref[...] = mat.T                             # (B, N)
     row0 = row0_ref[...]                                  # (1, N)
     bv = bvalid_ref[...].astype(F32)                      # (1, B)
     nb = bt.shape[0]
@@ -81,12 +85,11 @@ def _body(g, batch_ref, rows_ref, row0_ref, values_ref,
 
     def body(i, carry):
         rows, values, counts, spent, admits = carry
-        col = jax.lax.dynamic_slice(mat, (0, i),
-                                    (mat.shape[0], 1)).T  # (1, N)
+        col = arrivals_ref[pl.ds(i, 1), :]                # (1, N)
         gains = R.level_gains(rows, col, rule)            # (L, 1)
-        ok = jax.lax.dynamic_slice(bv, (0, i), (1, 1))[0, 0] > 0
+        ok = jnp.sum(R.lane_pick(bv, i)) > 0
         if cost_mode:
-            ci = jax.lax.dynamic_slice(costs, (0, i), (1, 1))[0, 0]
+            ci = jnp.sum(R.lane_pick(costs, i))
             admit = sieve_admit(gains, values, counts, vgrid, ok, k,
                                 cost=ci, spent=spent, budget=budget)
             spent = spent + jnp.where(admit, ci, 0.0)
@@ -109,7 +112,7 @@ def _body(g, batch_ref, rows_ref, row0_ref, values_ref,
     cntout_ref[...] = counts
     admit_ref[...] = admits
     expoout_ref[...] = expos
-    mout_ref[0, 0] = m_new
+    mout_ref[...] = jnp.broadcast_to(m_new, mout_ref.shape)
     expired_ref[...] = expired.astype(F32)
     if cost_mode:
         out_refs[7][...] = spent
@@ -129,12 +132,14 @@ def _kernel(ground_ref, *refs, k, eps_log, rule, quant, has_cost):
     cost_refs = None
     if has_cost:
         cost_refs, rest = tuple(rest[:3]), rest[3:]
-    _body(g, *main, cost_refs, tuple(rest), k=k, eps_log=eps_log,
-          rule=rule)
+    *outs, arrivals_ref = rest
+    _body(g, *main, cost_refs, tuple(outs), arrivals_ref, k=k,
+          eps_log=eps_log, rule=rule)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "eps_log", "rule",
-                                             "interpret"))
+                                             "interpret",
+                                             "vmem_limit_bytes"))
 def stream_filter_pallas(ground: jax.Array, batch: jax.Array,
                          rows: jax.Array, row0: jax.Array,
                          values: jax.Array, counts: jax.Array,
@@ -142,14 +147,15 @@ def stream_filter_pallas(ground: jax.Array, batch: jax.Array,
                          bvalid: jax.Array, k: int, eps_log: float,
                          rule: KernelRule, interpret: bool = False,
                          gscale=None, costs=None, spent=None,
-                         budget=None):
+                         budget=None, vmem_limit_bytes: int = 0):
     """Feature rules: ground (N, D), batch (B, D) arrivals. Bitmap rules:
     ground is an ignored placeholder and batch the (B, W) arrival bitmaps
     (N = W). rows: (L, N) level states in the rule's row dtype, row0:
     (1, N) empty-solution row, values: (L, 1) f32 raw, counts / expos:
     (L, 1) i32, m_max: (1, 1) f32, bvalid: (1, B) 0/1 f32. L must be a
     sublane multiple (SieveStreamer rounds its level count up); N/B/D
-    padded by the ops.py wrapper (arrival pads carry bvalid = 0). When
+    padded to 128 lanes by the ops.py wrapper (arrival pads carry
+    bvalid = 0). When
     `gscale` (1, N) f32 is given, `ground` is int8 per-row-quantized
     storage and the kernel rescales it to f32 on-chip.
 
@@ -157,7 +163,8 @@ def stream_filter_pallas(ground: jax.Array, batch: jax.Array,
     (all three or none) switch admission to the knapsack cost-ratio rule
     — the per-level spent track rides the same sequential loop, so the
     batch still costs ONE dispatch — and append spent (L, 1) f32 to the
-    outputs.
+    outputs. vmem_limit_bytes: Mosaic's scoped-VMEM limit
+    (plans.vmem_limit of plans.stream_need).
 
     Returns (rows (L, N), values (L, 1), counts (L, 1) i32, admits
     (L, B) f32 0/1, expos (L, 1) i32, m_new (1, 1) f32, expired (L, 1)
@@ -198,5 +205,8 @@ def stream_filter_pallas(ground: jax.Array, batch: jax.Array,
         functools.partial(_kernel, k=k, eps_log=eps_log, rule=rule,
                           quant=gscale is not None, has_cost=has_cost),
         out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((nb, n), rule.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes or None),
         interpret=interpret,
     )(*operands)

@@ -42,7 +42,7 @@ from repro.core.objective import make_objective, registry
 from repro.data.synthetic import gen_images, gen_kcover, pack_bitmaps
 from repro.kernels import ops, plans
 from repro.kernels.rules import cache_itemsize
-from repro.runtime import flags
+from repro.runtime import compile_cache, flags
 
 FEATURE_DTYPES = ("float32", "bfloat16", "int8")
 STEP_PLAN = {"tier": "step", "block_n": 0, "loop_block_n": 0,
@@ -141,12 +141,14 @@ def _fmt(fp):
             f"bn={fp['block_n']:3d} bl={fp['loop_block_n']:3d}")
 
 
-def tune_one(name, n, d, k, *, universe=0, backend="interpret", reps=2,
+def tune_one(name, n, d, k, *, universe=0, backend=None, reps=2,
              dtypes=None, blocks_per_tier=2, seed=0, verbose=True):
     """Tune one (objective, shape): measure the static plan and every
     admitted candidate, reject candidates that change the selected ids,
     and return (key, winner entry). The pool is its own candidate set,
-    so c = n (the greedy driver's shape)."""
+    so c = n (the greedy driver's shape). `backend` defaults to the
+    resolved one (plans.resolve_backend): Pallas on a TPU."""
+    backend = plans.resolve_backend(backend)
     obj = make_objective(name, universe=universe or n, backend=backend)
     rule = obj.rule
     ids, pay, valid = _pool(name, n, d, universe, seed=seed)
@@ -196,7 +198,7 @@ def tune_one(name, n, d, k, *, universe=0, backend="interpret", reps=2,
     return key, entry
 
 
-def tune(objectives, shapes, *, backend="interpret", reps=2,
+def tune(objectives, shapes, *, backend=None, reps=2,
          dtypes=None, blocks_per_tier=2, universe=0, out=None,
          verbose=True):
     """Tune the (objective × shape) grid and persist the winners to the
@@ -229,8 +231,9 @@ def main(argv=None):
     ap.add_argument("--k", type=int, default=16, help="solution size")
     ap.add_argument("--universe", type=int, default=0,
                     help="bitmap universe (coverage; default n)")
-    ap.add_argument("--backend", default="interpret",
-                    help="kernel backend to measure under")
+    ap.add_argument("--backend", default=None,
+                    help="kernel backend to measure under (default: the "
+                         "resolved one, Pallas on a TPU)")
     ap.add_argument("--reps", type=int, default=2)
     ap.add_argument("--blocks-per-tier", type=int, default=2,
                     help="power-of-two row blocks tried per tier/dtype")
@@ -242,6 +245,7 @@ def main(argv=None):
                     help="tiny CI grid: facility @ n=192 d=32 k=6, "
                          "f32+int8 only, 1 rep")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     dtypes = tuple(s for s in args.dtypes.split(",") if s) or None
     if args.smoke:
         objectives = args.objective or ["facility"]

@@ -37,7 +37,10 @@ import json
 import tempfile
 import time
 
+import jax
 import numpy as np
+
+from repro.runtime import compile_cache
 
 
 def _build(args):
@@ -247,6 +250,11 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
+    compile_cache.enable()
+    if args.mesh:
+        # one lane per device: the real chips, or the host devices forced
+        # from --lanes when JAX is held to the CPU
+        args.lanes = len(jax.devices())
     if args.smoke:
         return smoke(args)
     return run(args)

@@ -21,8 +21,10 @@ def force_host_devices(count: int = 512, *, trigger: Optional[str] = None,
                        count_flag: Optional[str] = "--lanes",
                        argv: Optional[Sequence[str]] = None) -> bool:
     """Append ``--xla_force_host_platform_device_count=N`` to XLA_FLAGS so
-    the CPU backend simulates N devices. MUST be called before ANYTHING
-    imports jax (this module deliberately does not).
+    the CPU backend simulates N devices — only when JAX is held to the
+    CPU (``JAX_PLATFORMS=cpu``); on an accelerator the mesh is made of
+    the real devices. MUST be called before ANYTHING imports jax (this
+    module deliberately does not).
 
     ``trigger``: only apply when this flag is present in ``argv``
     (default sys.argv) — e.g. faultrun's ``--mesh`` — None applies
@@ -30,6 +32,8 @@ def force_host_devices(count: int = 512, *, trigger: Optional[str] = None,
     value when present (e.g. ``--lanes 8``), falling back to ``count``.
     Returns whether the flag was applied."""
     argv = list(sys.argv if argv is None else argv)
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip() != "cpu":
+        return False
     if trigger is not None and trigger not in argv:
         return False
     n = str(count)
@@ -41,12 +45,22 @@ def force_host_devices(count: int = 512, *, trigger: Optional[str] = None,
     return True
 
 
+def auto_mesh(shape: Sequence[int], names: Sequence[str]) -> "Mesh":
+    """`jax.make_mesh` with every axis in Auto mode: the compiler places
+    the collectives, as the shard_map drivers here expect (the installed
+    JAX makes Explicit axes by default, under which plain indexing of a
+    lane-sharded result is refused)."""
+    import jax
+    from jax.sharding import AxisType
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(shape))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> "Mesh":
     """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
-    import jax
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> "Mesh":
@@ -55,7 +69,7 @@ def make_local_mesh(data: int = 1, model: int = 1) -> "Mesh":
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // data))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def make_machine_mesh(m: int, b: int, axis_prefix: str = "lvl") -> "Mesh":
@@ -63,7 +77,6 @@ def make_machine_mesh(m: int, b: int, axis_prefix: str = "lvl") -> "Mesh":
     an L-dim mesh (b, …, b); level-ℓ accumulation all-gathers over axis
     f"{axis_prefix}{ℓ}". Axis 0 is the innermost digit of the machine id,
     matching the paper's parent(id, i) = b^i · floor(id / b^i)."""
-    import jax
     if m <= 0 or b <= 1:
         raise ValueError(f"need m>0, b>1; got m={m} b={b}")
     L = int(round(math.log(m, b)))
@@ -74,7 +87,7 @@ def make_machine_mesh(m: int, b: int, axis_prefix: str = "lvl") -> "Mesh":
     axes = tuple(f"{axis_prefix}{i}" for i in range(L))
     # NOTE: jax meshes are row-major (last axis fastest-varying); the paper's
     # machine id has level-0 groups in the LOW digits, so reverse the axes.
-    return jax.make_mesh(shape, tuple(reversed(axes)))
+    return auto_mesh(shape, tuple(reversed(axes)))
 
 
 def make_tree_mesh(radices: Sequence[int], shard: int = 1,
@@ -86,7 +99,6 @@ def make_tree_mesh(radices: Sequence[int], shard: int = 1,
     that cooperate on each leaf through the sharded engine. Device order
     has the shard digit fastest, then the level-0 digit — lane =
     machine·shard + shard_digit, LevelDispatcher's layout."""
-    import jax
     radices = tuple(int(r) for r in radices)
     if not radices and shard <= 1:
         raise ValueError("empty tree with no sharding needs no mesh")
@@ -96,7 +108,7 @@ def make_tree_mesh(radices: Sequence[int], shard: int = 1,
     if shard > 1:
         shape += (shard,)
         names += (shard_axis,)
-    return jax.make_mesh(shape, names)
+    return auto_mesh(shape, names)
 
 
 def mesh_devices(mesh: "Mesh") -> int:

@@ -36,6 +36,7 @@ from repro.core.objective import make_objective
 from repro.data.synthetic import gen_images, gen_kcover, gen_stream, \
     pack_bitmaps
 from repro.kernels import plans
+from repro.runtime import compile_cache
 from repro.serving import Query, QueryEngine, QueueFull, ServeMetrics, \
     TenantSession
 from repro.streaming import stream_select_continuous
@@ -118,7 +119,7 @@ def smoke(args) -> int:
     """CI gate: correctness of the whole serving surface on a tiny mixed
     workload (see module docstring)."""
     rc = 0
-    backend = args.backend or "interpret"
+    backend = plans.resolve_backend(args.backend)
     eng = QueryEngine(backend=backend, queue_cap=64)
     universe = 384
     specs = [("facility", 5, 96, 1), ("facility", 9, 120, 2),
@@ -212,6 +213,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.smoke:
         return smoke(args)
     return run(args)

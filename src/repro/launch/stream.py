@@ -1,4 +1,4 @@
-from repro.launch.mesh import force_host_devices
+from repro.launch.mesh import auto_mesh, force_host_devices
 
 force_host_devices(4, trigger="--distributed")  # pragma: no cover - env
 # ^ MUST precede any jax import: jax locks the device count on first init.
@@ -39,6 +39,8 @@ from repro.streaming import (SieveStreamer, SlidingSieve, stream_select,
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import compile_cache
+
 
 def _make(args):
     st = gen_stream(args.objective, args.n, d=args.d,
@@ -77,7 +79,7 @@ def run(args) -> int:
         sol = win.query(wstate)
         mode = f"window[{args.window}/{win.stride}]"
     elif args.distributed:
-        mesh = jax.make_mesh((args.lanes,), ("stream",))
+        mesh = auto_mesh((args.lanes,), ("stream",))
         sol, info = stream_select_distributed(
             obj, st, args.k, mesh, ("stream",), ground=ground,
             merge_every=args.merge_every, eps=args.eps,
@@ -176,6 +178,11 @@ def main(argv=None) -> int:
     ap.add_argument("--compare", action="store_true")
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
+    compile_cache.enable()
+    if args.distributed:
+        # one lane per device: the real chips, or the host devices forced
+        # from --lanes when JAX is held to the CPU
+        args.lanes = len(jax.devices())
     if args.smoke:
         return smoke(args)
     return run(args)

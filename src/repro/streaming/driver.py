@@ -301,7 +301,6 @@ def stream_select_distributed(objective, stream: Iterable, k: int, mesh,
     ``sample_level``/``seed`` reseed the merge nodes' stochastic draws
     (seed None keeps the legacy fixed tape)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     radices = [mesh.shape[a] for a in tree_axes]
     lanes = math.prod(radices)
@@ -329,12 +328,13 @@ def stream_select_distributed(objective, stream: Iterable, k: int, mesh,
                                 seed=seed)
         return _broadcast_from_root(out, tree_axes, radices)
 
-    step = shard_map(step_fn, mesh=mesh,
-                     in_specs=(lane_spec, lane_spec, lane_spec, lane_spec),
-                     out_specs=lane_spec, check_rep=False)
-    merge = shard_map(merge_fn, mesh=mesh, in_specs=(lane_spec, rep),
-                      out_specs=Solution(rep, rep, rep, rep, rep),
-                      check_rep=False)
+    step = jax.shard_map(step_fn, mesh=mesh,
+                         in_specs=(lane_spec, lane_spec, lane_spec,
+                                   lane_spec),
+                         out_specs=lane_spec, check_vma=False)
+    merge = jax.shard_map(merge_fn, mesh=mesh, in_specs=(lane_spec, rep),
+                          out_specs=Solution(rep, rep, rep, rep, rep),
+                          check_vma=False)
 
     states, merged = None, None
     merges, done = [], 0

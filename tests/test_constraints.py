@@ -9,10 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # image has no hypothesis
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.constraints import Composite, Knapsack, KnapsackSpec, \
     PartitionMatroid, uniform_matroid

@@ -243,3 +243,51 @@ def test_pad_bucketing_powers_of_two():
     assert ops._bucket_len(300, 128) == 512
     assert ops._bucket_len(2048, 256) == 2048
     assert ops._bucket_len(2049, 256) == 4096
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.uint32])
+@pytest.mark.parametrize("idx", [0, 77, 127])
+def test_lane_pick_is_exact_column(dtype, idx):
+    """The kernels' winner-column read (one-hot lane reduction) returns
+    x[:, idx] bit for bit: negative, infinite and full-width word values
+    included."""
+    x = jax.random.normal(jax.random.PRNGKey(idx), (16, 128)) * 1e3
+    x = x.at[3, idx].set(-jnp.inf).at[4, idx].set(-0.5)
+    if dtype == jnp.uint32:
+        x = jax.lax.bitcast_convert_type(x, jnp.uint32)   # high bits set
+    else:
+        x = x.astype(dtype)
+    col = rules.lane_pick(x, jnp.int32(idx))
+    want = x[:, idx:idx + 1]
+    want = want if dtype == jnp.uint32 else want.astype(jnp.float32)
+    assert col.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(col), np.asarray(want))
+
+
+def test_vmem_limit_from_working_set():
+    """Mosaic's scoped-VMEM limit is twice the planner's working set and
+    never below the compiler's own 16 MiB default."""
+    from repro.kernels import plans
+    assert plans.vmem_limit(1) == 16 * 2 ** 20
+    need = plans.loop_need(64, 8192, 8192)
+    assert need > plans.fused_need(64, 8192, 8192)
+    assert plans.vmem_limit(need) == max(16 * 2 ** 20, 2 * need)
+
+
+@pytest.mark.parametrize("backend", ["ref", "interpret"])
+def test_self_distance_is_exactly_zero(backend):
+    """The ‖g‖²+‖c‖²−2⟨g,c⟩ expansion leaves rounding noise where g = c;
+    below `rules.DIST_REL_TOL` of the scale it counts as 0, so a point
+    sits at distance exactly 0 from itself in the cached matrix and in
+    the direct per-column formula, and other distances are unchanged."""
+    from repro.data.synthetic import gen_images
+    x = jnp.asarray(gen_images(256, 768, seed=13))      # unit-norm rows
+    m = np.asarray(ops.pairwise_matrix(x, x, rules.DIST_MIN,
+                                       backend=backend))[:256, :256]
+    col = np.asarray(rules.pairwise_col(x, x[7], rules.DIST_MIN))
+    np.testing.assert_array_equal(np.diag(m), 0.0)
+    assert col[7] == 0.0
+    x64 = np.asarray(x, np.float64)
+    exact = np.sqrt(((x64[:, None, :] - x64[None, :, :]) ** 2).sum(-1))
+    np.testing.assert_allclose(m, exact, atol=1e-5)
+    np.testing.assert_allclose(col, exact[:, 7], atol=1e-5)

@@ -74,12 +74,8 @@ def test_moe_token_exchange_grad_finite():
 
 
 def _abstract_mesh(sizes, names):
-    """jax 0.4.37 takes ((name, size), …); ≥0.5 takes (sizes, names)."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(sizes), tuple(names))
+    return AbstractMesh(tuple(sizes), tuple(names))
 
 
 def test_sharding_profiles_switch_and_restore():

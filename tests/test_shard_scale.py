@@ -103,10 +103,7 @@ def test_sim_handles_invalid_and_ragged_pools():
 
 def _abstract_shard_mesh(lanes):
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh((lanes,), ("shard",))
-    except TypeError:                      # older ctor: ((name, size), ...)
-        return AbstractMesh((("shard", lanes),))
+    return AbstractMesh((lanes,), ("shard",))
 
 
 def test_dispatch_count_per_lane_contract():
@@ -253,6 +250,12 @@ def test_sharded_leaves_refuse_degraded_tree(monkeypatch, tmp_path):
 def test_force_host_devices(monkeypatch):
     from repro.launch.mesh import force_host_devices
     monkeypatch.delenv("XLA_FLAGS", raising=False)
+    # an accelerator platform: its real devices form the mesh, untouched
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    assert not force_host_devices(8, trigger="--mesh", argv=["prog",
+                                                             "--mesh"])
+    assert "XLA_FLAGS" not in os.environ
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     # trigger absent: untouched
     assert not force_host_devices(8, trigger="--mesh", argv=["prog"])
     assert "XLA_FLAGS" not in os.environ

@@ -1,0 +1,204 @@
+"""Compile every main-path Pallas kernel for a TPU v5e, with no chip attached.
+
+Interpret mode runs the kernel bodies as plain jnp on the CPU, so it cannot
+see what Mosaic refuses: lane slices at traced offsets, blocks that break
+the (8, 128) tiling rule, casts the TPU has no instruction for, or a working
+set over the scoped-VMEM limit. Here each kernel is lowered and compiled
+through the `kernels/ops.py` wrappers (``backend="pallas"``) at real widths
+against a described ``v5e:2x2`` topology, and its compiled text must hold
+the Mosaic custom call.
+
+The topology is described inside a module fixture only: one process at a
+time may load the TPU compiler library, so describing it while modules are
+imported would make parallel test workers collect different tests.
+"""
+import functools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.objective import make_objective
+from repro.kernels import ops, plans
+from repro.kernels import rules as R
+from repro.kernels.shard_gains import shard_greedy_distributed
+
+N = C = 4096        # cached-matrix tiers: the (N, C) matrix is 64 MiB in f32
+D = 768
+K = 32
+NODE = 512          # an accumulation-node pool: resident-tier shapes
+WORDS = 512         # coverage universe of 16,384 elements, in uint32 words
+F32, I32, U32 = jnp.float32, jnp.int32, jnp.uint32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler here: nothing to compile
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype=F32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+
+def _compiles(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _plan(rule, n, c, d, requested="mega"):
+    return plans.select_engine(rule, n, c, d, requested=requested,
+                               backend="pallas")
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("name", ["kmedoid", "facility"])
+def test_pairwise(spec, name, dtype):
+    rule = R.get(name)
+    _compiles(lambda g, c: ops.pairwise_matrix(g, c, rule, backend="pallas",
+                                               dtype=dtype),
+              spec((N, D)), spec((C, D)))
+
+
+@pytest.mark.parametrize("case", ["f32", "int8", "coverage"])
+def test_gains(spec, case, monkeypatch):
+    if case == "coverage":
+        _compiles(lambda r, c, v: ops.gains(None, r, c, v, R.BITS_OR,
+                                            backend="pallas"),
+                  spec((WORDS,), U32), spec((C, WORDS), U32),
+                  spec((C,), jnp.bool_))
+        return
+    if case == "int8":          # per-row-quantized ground, `gscale` operand
+        monkeypatch.setenv("REPRO_FUSED_CACHE_DTYPE", "int8")
+    _compiles(lambda g, r, c, v: ops.gains(g, r, c, v, R.DOT_MAX,
+                                           backend="pallas"),
+              spec((N, D)), spec((N,)), spec((C, D)), spec((C,), jnp.bool_))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_fused_step(spec, dtype):
+    rule = R.DIST_MIN
+    plan = _plan(rule, N, C, D, requested="fused")
+    assert plan.engine == "fused" and plan.block_n
+
+    def step(g, c, row, mask, prev):
+        mat = ops.pairwise_matrix(g, c, rule, backend="pallas", dtype=dtype)
+        return ops.fused_step(mat, row, mask, prev, rule, backend="pallas",
+                              plan=plan)
+
+    _compiles(step, spec((N, D)), spec((C, D)), spec((N,)),
+              spec((C,), jnp.bool_), spec((), I32))
+
+
+@pytest.mark.parametrize("name", ["kmedoid", "coverage"])
+def test_greedy_loop_streaming(spec, name):
+    rule = R.get(name)
+    if rule.is_bitmap:
+        plan = _plan(rule, WORDS, 8 * C, None)
+        assert plan.engine == "mega_stream"
+        _compiles(lambda c, row, mask: ops.greedy_loop(
+            ops.pairwise_matrix(None, c, rule, backend="pallas"), row, mask,
+            K, rule, backend="pallas", plan=plan),
+            spec((8 * C, WORDS), U32), spec((WORDS,), U32),
+            spec((8 * C,), jnp.bool_))
+        return
+    plan = _plan(rule, N, C, D)
+    assert plan.engine == "mega_stream"
+    _compiles(lambda g, c, row, mask: ops.greedy_loop(
+        ops.pairwise_matrix(g, c, rule, backend="pallas"), row, mask, K,
+        rule, backend="pallas", plan=plan),
+        spec((N, D)), spec((C, D)), spec((N,)), spec((C,), jnp.bool_))
+
+
+@pytest.mark.parametrize("case", ["float32", "int8", "coverage"])
+def test_greedy_loop_resident(spec, case):
+    """One accumulation-node greedy; the `(1, 3)` i32 ctl operand carries
+    a traced step budget and logical extents."""
+    if case == "coverage":
+        _compiles(lambda c, row, mask, kq: ops.greedy_loop_resident(
+            None, c, row, mask, K, R.BITS_OR, backend="pallas", kq=kq),
+            spec((NODE, WORDS), U32), spec((WORDS,), U32),
+            spec((NODE,), jnp.bool_), spec((), I32))
+        return
+    rule = R.DOT_MAX
+    assert plans.resident_fits(NODE, NODE, D, rule=rule)
+    _compiles(lambda g, row, mask, kq, ln, lc: ops.greedy_loop_resident(
+        g, g, row, mask, K, rule, backend="pallas", cache_dtype=case,
+        kq=kq, logical=(ln, lc)),
+        spec((NODE, D)), spec((NODE,)), spec((NODE,), jnp.bool_),
+        spec((), I32), spec((), I32), spec((), I32))
+
+
+def test_greedy_loop_resident_serving_batch(spec):
+    """The query service's admitted batch: B=4 MMR queries stacked on a
+    vmap axis over the resident megakernel, one dispatch."""
+    obj = make_objective("mmr", backend="pallas")
+    b, c = 4, NODE
+    assert plans.serve_plan(obj.rule, c, c, D, backend="pallas") is not None
+    _compiles(lambda p, v, ks: obj.megakernel_loop_batched(p, v, ks, 16),
+              spec((b, c, D)), spec((b, c), jnp.bool_), spec((b,), I32))
+
+
+@pytest.mark.parametrize("case", ["facility", "knapsack", "coverage"])
+def test_stream_filter(spec, case):
+    """One arrival batch of B=128 against every sieve level; `knapsack`
+    adds the costs / spent / budget operands."""
+    rule = R.BITS_OR if case == "coverage" else R.DOT_MAX
+    n, lv, b = 1024, 32, 128
+    d = None if rule.is_bitmap else D
+    plan = plans.stream_plan(n if d else WORDS, lv, b, d, backend="pallas",
+                             rule=rule)
+    assert plan is not None and plan["tier"] == "kernel"
+    has_cost = case == "knapsack"
+
+    def filt(ground, batch, rows, row0, bvalid, costs, spent):
+        kw = dict(costs=costs, spent=spent,
+                  budget=jnp.float32(10.0)) if has_cost else {}
+        return ops.stream_filter(
+            ground, batch, rows, row0, jnp.zeros((lv,), F32),
+            jnp.zeros((lv,), I32), jnp.arange(lv, dtype=I32),
+            jnp.float32(0.0), bvalid, K, 0.1, rule, backend="pallas",
+            plan=plan, **kw)
+
+    if rule.is_bitmap:
+        ground, batch = None, spec((b, WORDS), U32)
+        rows, row0 = spec((lv, WORDS), U32), spec((WORDS,), U32)
+    else:
+        ground, batch = spec((n, D)), spec((b, D))
+        rows, row0 = spec((lv, n)), spec((n,))
+    _compiles(functools.partial(filt, ground) if ground is None else filt,
+              *([] if ground is None else [ground]), batch, rows, row0,
+              spec((b,), jnp.bool_), spec((b,)), spec((lv,)))
+
+
+def test_sharded_leaf_shard_map(topo):
+    """The sharded leaf tier over a real 4-device mesh: the pool's ground
+    axis is split over the `shard` axis, candidate tiles are all-gathered
+    and each lane dispatches the gains kernel on its shard."""
+    mesh = jax.sharding.Mesh(topo.devices, ("shard",))
+    obj = make_objective("facility", backend="pallas")
+    n = 8192
+    rows = NamedSharding(mesh, P("shard"))
+    _compiles(lambda ids, pay, val: shard_greedy_distributed(
+        obj, ids, pay, val, 8, mesh, tile_c=256),
+        jax.ShapeDtypeStruct((n,), I32, sharding=rows),
+        jax.ShapeDtypeStruct((n, D), F32, sharding=rows),
+        jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=rows))

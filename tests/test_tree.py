@@ -2,10 +2,7 @@
 import math
 
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # image has no hypothesis
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.tree import (AccumulationTree, MixedRadixTree, children,
                              level_of, parent, randgreedi_tree)
