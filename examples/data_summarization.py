@@ -1,10 +1,10 @@
 """Data summarization with the distributed GreedyML driver.
 
 Runs the actual shard_map implementation over every device there is: the
-chips of an accelerator host, or 8 simulated host devices when JAX is held
-to the CPU. Selects k diverse exemplars from a mixture-of-Gaussians image
-set with the k-medoid objective, then shows the facility-location coreset
-used by the training pipeline.
+chips of an accelerator host, or 8 simulated host devices on the CPU; it
+fails with fewer than two. Selects k diverse exemplars from a
+mixture-of-Gaussians image set with the k-medoid objective, then shows the
+facility-location coreset used by the training pipeline.
 
     JAX_PLATFORMS=cpu PYTHONPATH=src python examples/data_summarization.py
     PYTHONPATH=src python examples/data_summarization.py    # on a TPU host
@@ -29,7 +29,12 @@ N, D, K = 2048, 256, 32
 
 compile_cache.enable()
 # the tree's machines: the largest power of two of devices present
-machines = 1 << (len(jax.devices()).bit_length() - 1)
+devices = jax.devices()
+machines = 1 << (len(devices).bit_length() - 1)
+if machines < 2:
+    raise SystemExit(f"the GreedyML tree needs at least two devices; JAX "
+                     f"sees one {devices[0].platform} device (run with "
+                     f"JAX_PLATFORMS=cpu to simulate 8)")
 print(f"k-medoid exemplar selection: {N} images, d={D}, k={K}")
 imgs = synthetic.gen_images(N, D, classes=16, seed=3)
 ids = jnp.arange(N, dtype=jnp.int32)
@@ -40,10 +45,6 @@ ref = greedy(obj, ids, jnp.asarray(imgs), jnp.ones(N, bool), K)
 ref_sel = np.asarray(ref.ids)[np.asarray(ref.valid)]
 print(f"  sequential Greedy     : "
       f"{global_value('kmedoid', imgs, ref_sel):.4f}")
-
-if machines < 2:
-    print("one device: the GreedyML tree needs at least two")
-    raise SystemExit(0)
 
 mesh = make_machine_mesh(machines, 2)              # T(m, L=log2 m, b=2)
 tree_axes = tuple(reversed(mesh.axis_names))

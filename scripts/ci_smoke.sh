@@ -5,6 +5,8 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# every stage runs on the CPU, simulating its meshes with host devices
+export JAX_PLATFORMS=cpu
 
 echo "== tier-1 (ref backend) =="
 python -m pytest -x -q

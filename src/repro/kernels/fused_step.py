@@ -36,22 +36,7 @@ from repro.kernels.rules import (KernelRule, fold_winner,  # noqa: F401
                                  masked_argmax, partial_gains)
 
 F32 = jnp.float32
-LANES = 128
-
-
-def read_col(mat_ref, idx, scale=None):
-    """Column `idx` (clamped at 0) of a VMEM matrix ref as a (1, R) row.
-    Mosaic slices the lane axis only at 128-aligned offsets, so this loads
-    the aligned 128-lane window that holds the column and picks its lane
-    (`rules.lane_pick`). `scale`: the (1, R) per-row scales of int8
-    storage, applied to the window exactly as `rules.dequant` would to the
-    whole slab."""
-    i = jnp.maximum(idx, 0)
-    base = pl.multiple_of(i // LANES * LANES, LANES)
-    win = mat_ref[:, pl.ds(base, LANES)]
-    if scale is not None:
-        win = R.dequant(win, scale)
-    return R.lane_pick(win, i - base).T
+LANES = R.LANES
 
 
 def _kernel(prev_ref, mat_ref, *refs, rule: KernelRule, quant: bool):
@@ -69,7 +54,7 @@ def _kernel(prev_ref, mat_ref, *refs, rule: KernelRule, quant: bool):
 
     # 1. deferred update: fold the previous winner's column into the state
     new_r = R.fold_winner(row_ref[pl.ds(ni, 1), :],
-                          read_col(mat_ref, prev, scale), prev, rule)
+                          R.read_col(mat_ref, prev, scale), prev, rule)
     newrow_ref[pl.ds(ni, 1), :] = new_r
 
     # 2. partial gains for this row block, accumulated on-chip

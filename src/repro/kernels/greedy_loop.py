@@ -45,7 +45,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import rules as R
-from repro.kernels.fused_step import read_col
 from repro.kernels.rules import KernelRule
 
 F32 = jnp.float32
@@ -84,7 +83,7 @@ def _stream_body(mat_ref, scale_ref, row_ref, mask_ref,
     # deferred update: fold the previous step's winner into this row block
     # (at step k this is the final flush)
     r = R.fold_winner(rowout_ref[pl.ds(ni, 1), :],
-                      read_col(mat_ref, prev, scale), prev, rule)
+                      R.read_col(mat_ref, prev, scale), prev, rule)
     rowout_ref[pl.ds(ni, 1), :] = r
 
     @pl.when(s < k)
@@ -203,7 +202,7 @@ def _resident_kernel(ground_ref, cands_ref, row_ref, mask_ref, ctl_ref,
     elif not rule.is_bitmap and cache_dtype == "bfloat16":
         m = m.astype(jnp.bfloat16).astype(F32)
     # the loop reads the matrix back from scratch: winner columns come
-    # from aligned lane windows of a ref (read_col)
+    # from aligned lane windows of a ref (R.read_col)
     mat_ref[...] = m
 
     cols = jax.lax.broadcasted_iota(jnp.int32, (1, m.shape[1]), 1)
@@ -211,7 +210,7 @@ def _resident_kernel(ground_ref, cands_ref, row_ref, mask_ref, ctl_ref,
 
     def body(s, carry):
         row, mask, prev, bests, gains = carry
-        row = R.fold_winner(row, read_col(mat_ref, prev), prev, rule)
+        row = R.fold_winner(row, R.read_col(mat_ref, prev), prev, rule)
         best, mx = R.masked_argmax(
             R.partial_gains(row, mat_ref[...], rule), mask)
         # masked steps (s ≥ kq): the deferred fold above still flushed the
@@ -231,7 +230,7 @@ def _resident_kernel(ground_ref, cands_ref, row_ref, mask_ref, ctl_ref,
              jnp.full((1, k), -1, jnp.int32), jnp.zeros((1, k), F32))
     row, _, prev, bests, gains = jax.lax.fori_loop(0, k, body, carry)
     # flush: fold the final accepted winner so value(state) sees all of S
-    rowout_ref[...] = R.fold_winner(row, read_col(mat_ref, prev), prev,
+    rowout_ref[...] = R.fold_winner(row, R.read_col(mat_ref, prev), prev,
                                     rule)
     best_ref[...] = bests
     gain_ref[...] = gains

@@ -54,8 +54,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 F32 = jnp.float32
+LANES = 128
 
 # facility/satsum pad sentinel for invalid ground rows (≈ f32 max; keeps
 # the per-element gain part at exactly 0)
@@ -235,6 +237,20 @@ def lane_pick(x, idx):
                    keepdims=True)
 
 
+def read_col(mat_ref, idx, scale=None):
+    """Column `idx` (clamped at 0) of a VMEM matrix ref as a (1, R) row.
+    Mosaic slices the lane axis only at 128-aligned offsets, so this loads
+    the aligned 128-lane window that holds the column and picks its lane
+    (`lane_pick`). `scale`: the (1, R) per-row scales of int8 storage,
+    applied to the window exactly as `dequant` would to the whole slab."""
+    i = jnp.maximum(idx, 0)
+    base = pl.multiple_of(i // LANES * LANES, LANES)
+    win = mat_ref[:, pl.ds(base, LANES)]
+    if scale is not None:
+        win = dequant(win, scale)
+    return lane_pick(win, i - base).T
+
+
 def masked_argmax(gains, mask):
     """(1, C) gains + 0/1 mask → (first argmax () i32, max gain () f32)."""
     g = jnp.where(mask > 0, gains, _NEG_INF)
@@ -307,12 +323,15 @@ def pairwise_block(g, c, mode: str):
     return _dist_from_sq(gn + cn - 2.0 * cross, gn + cn)
 
 
-# Squared distances below this share of ‖g‖²+‖c‖² are rounding noise of
-# the expansion (about 1e-6 of it for a point against itself in f32, and
-# it differs between XLA's and Mosaic's matmuls), so they count as 0: a
-# selected point then sits at distance exactly 0 from itself on every
-# backend and in the direct ‖g−c‖ formula.
-DIST_REL_TOL = 2.0 ** -14
+# Squared distances of the expansion below this share of ‖g‖²+‖c‖² are
+# its f32 rounding noise, which differs between XLA's and Mosaic's
+# matmuls; they count as 0, so a selected point sits at distance exactly
+# 0 from itself on every backend. `scripts/kmedoid_parity.py` reads the
+# noise on a TPU v5e at d=768 as at most 4.9e-7 of the scale (Mosaic;
+# 2.5e-7 under XLA), on and off the diagonal, for unit-norm and offset
+# data: this share is 31× that, and 12× below the closest distinct pair
+# of the offset data (PERF.md).
+DIST_REL_TOL = 2.0 ** -16
 
 
 def _dist_from_sq(d2, scale):
@@ -352,8 +371,9 @@ def pairwise_col(ground, payload, rule: KernelRule):
     g = ground.astype(F32)
     p = payload.astype(F32)
     if rule.pairwise == "dist":
-        return _dist_from_sq(jnp.sum((g - p[None, :]) ** 2, axis=-1),
-                             jnp.sum(g * g, axis=-1) + jnp.sum(p * p))
+        # the direct ‖g−c‖ has no cancellation noise to cut: it is exactly
+        # 0 for a point against itself
+        return jnp.sqrt(jnp.sum((g - p[None, :]) ** 2, axis=-1))
     # 'dot' family, at the matrix's f32 precision (pairwise_block)
     return jnp.dot(g, p, precision=jax.lax.Precision.HIGHEST)
 

@@ -5,8 +5,9 @@ force_host_devices(512, count_flag=None)
 """Multi-pod dry-run: lower + compile EVERY (arch × shape × mesh) cell and
 record memory / FLOPs / collective-bytes for the roofline analysis.
 
-    PYTHONPATH=src python -m repro.launch.dryrun --mesh both \
-        [--only qwen2-7b:train_4k] [--out results/dryrun] [--no-probe]
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.dryrun \
+        --mesh both [--only qwen2-7b:train_4k] [--out results/dryrun] \
+        [--no-probe]
 
 For each cell:  with mesh: jax.jit(step, in_shardings=…).lower(**specs)
                 .compile() → memory_analysis() (fits?), cost_analysis()

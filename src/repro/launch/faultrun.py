@@ -1,4 +1,4 @@
-from repro.launch.mesh import force_host_devices
+from repro.launch.mesh import device_lanes, force_host_devices
 
 force_host_devices(8, trigger="--mesh")     # pragma: no cover - env setup
 # ^ MUST precede any jax import: jax locks the device count on first init.
@@ -22,9 +22,10 @@ log. Modes:
                        tree re-planned over the survivors)
   * --stream         — supervise the continuous streaming driver's merges
                        instead (transient replay + lane_reset)
-  * --mesh           — run every stage over a real host-simulated mesh of
-                       --lanes devices (one device per lane); default is
-                       the single-device vmap simulation
+  * --mesh           — run every stage over a real mesh, one lane per
+                       device: the chips, or --lanes (default 8) host
+                       devices on the CPU; default is the single-device
+                       vmap simulation
 
 ``--smoke`` runs the CI acceptance suite: replay bit-identity against the
 failure-free run, the degraded tree's ≥0.95× quality band, and a
@@ -37,7 +38,6 @@ import json
 import tempfile
 import time
 
-import jax
 import numpy as np
 
 from repro.runtime import compile_cache
@@ -235,7 +235,7 @@ def main(argv=None) -> int:
     ap.add_argument("--d", type=int, default=24)
     ap.add_argument("--universe", type=int, default=512)
     ap.add_argument("--k", type=int, default=8)
-    ap.add_argument("--lanes", type=int, default=8)
+    ap.add_argument("--lanes", type=int, default=None)
     ap.add_argument("--branching", type=int, default=2)
     ap.add_argument("--seed", type=int, default=2)
     ap.add_argument("--backend", default=None)
@@ -252,9 +252,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     compile_cache.enable()
     if args.mesh:
-        # one lane per device: the real chips, or the host devices forced
-        # from --lanes when JAX is held to the CPU
-        args.lanes = len(jax.devices())
+        args.lanes = device_lanes(args.lanes, "--mesh")
+    elif args.lanes is None:
+        args.lanes = 8
     if args.smoke:
         return smoke(args)
     return run(args)

@@ -10,7 +10,8 @@ the full cell (memory_analysis) and re-run the unrolled cost probes
 roofline terms next to the baseline. Variants are opt-in config/profile
 flags so baselines stay paper-faithful.
 
-    PYTHONPATH=src python -m repro.launch.hillclimb --exp llama4_token_exchange
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.hillclimb \
+        --exp llama4_token_exchange
 """
 import argparse
 import os

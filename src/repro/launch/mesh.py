@@ -21,10 +21,11 @@ def force_host_devices(count: int = 512, *, trigger: Optional[str] = None,
                        count_flag: Optional[str] = "--lanes",
                        argv: Optional[Sequence[str]] = None) -> bool:
     """Append ``--xla_force_host_platform_device_count=N`` to XLA_FLAGS so
-    the CPU backend simulates N devices — only when JAX is held to the
-    CPU (``JAX_PLATFORMS=cpu``); on an accelerator the mesh is made of
-    the real devices. MUST be called before ANYTHING imports jax (this
-    module deliberately does not).
+    the CPU backend simulates N devices. The flag touches only the CPU
+    backend: on an accelerator host ``jax.devices()`` still lists the real
+    chips (hold JAX to the CPU with ``JAX_PLATFORMS=cpu`` to simulate
+    there). MUST be called before ANYTHING imports jax (this module
+    deliberately does not).
 
     ``trigger``: only apply when this flag is present in ``argv``
     (default sys.argv) — e.g. faultrun's ``--mesh`` — None applies
@@ -32,8 +33,6 @@ def force_host_devices(count: int = 512, *, trigger: Optional[str] = None,
     value when present (e.g. ``--lanes 8``), falling back to ``count``.
     Returns whether the flag was applied."""
     argv = list(sys.argv if argv is None else argv)
-    if os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip() != "cpu":
-        return False
     if trigger is not None and trigger not in argv:
         return False
     n = str(count)
@@ -43,6 +42,19 @@ def force_host_devices(count: int = 512, *, trigger: Optional[str] = None,
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={n}")
     return True
+
+
+def device_lanes(requested: Optional[int], flag: str) -> int:
+    """Lane count of a run with one lane per device (``flag`` names the
+    launcher's mesh switch): every device JAX sees — the chips, or the
+    host devices forced from ``--lanes`` on the CPU. A ``--lanes`` that
+    names another count is an error, never a silent resize."""
+    import jax
+    n = len(jax.devices())
+    if requested not in (None, n):
+        raise SystemExit(f"{flag} runs one lane per device: --lanes "
+                         f"{requested}, but JAX sees {n} devices")
+    return n
 
 
 def auto_mesh(shape: Sequence[int], names: Sequence[str]) -> "Mesh":

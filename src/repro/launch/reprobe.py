@@ -6,7 +6,8 @@ force_host_devices(512, count_flag=None)
 fits) for already-compiled dry-run cells and merge into their JSONs —
 avoids re-compiling the full-size cell when only the probe schema changed.
 
-    PYTHONPATH=src python -m repro.launch.reprobe [--only arch:shape]
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.reprobe \
+        [--only arch:shape]
 """
 import argparse
 import os

@@ -1,4 +1,4 @@
-from repro.launch.mesh import auto_mesh, force_host_devices
+from repro.launch.mesh import auto_mesh, device_lanes, force_host_devices
 
 force_host_devices(4, trigger="--distributed")  # pragma: no cover - env
 # ^ MUST precede any jax import: jax locks the device count on first init.
@@ -14,7 +14,8 @@ a deterministic synthetic arrival stream. Modes:
   * --continuous   — vmapped-lane continuous mode with periodic GreedyML
                      tree merges (single device)
   * --distributed  — the same continuous mode via shard_map over a real
-                     (host-simulated) mesh of --lanes devices
+                     mesh, one lane per device: the chips, or --lanes
+                     (default 4) host devices on the CPU
   * --window W     — sliding-window summary of the last W arrivals
 
 ``--smoke`` runs a tiny instance through single + window + continuous
@@ -168,7 +169,7 @@ def main(argv=None) -> int:
     ap.add_argument("--backend", default=None)
     ap.add_argument("--continuous", action="store_true")
     ap.add_argument("--distributed", action="store_true")
-    ap.add_argument("--lanes", type=int, default=4)
+    ap.add_argument("--lanes", type=int, default=None)
     ap.add_argument("--merge-every", type=int, default=4)
     ap.add_argument("--window", type=int, default=0)
     ap.add_argument("--stride", type=int, default=0)
@@ -180,9 +181,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     compile_cache.enable()
     if args.distributed:
-        # one lane per device: the real chips, or the host devices forced
-        # from --lanes when JAX is held to the CPU
-        args.lanes = len(jax.devices())
+        args.lanes = device_lanes(args.lanes, "--distributed")
+    elif args.lanes is None:
+        args.lanes = 4
     if args.smoke:
         return smoke(args)
     return run(args)

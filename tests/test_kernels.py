@@ -291,3 +291,42 @@ def test_self_distance_is_exactly_zero(backend):
     exact = np.sqrt(((x64[:, None, :] - x64[None, :, :]) ** 2).sum(-1))
     np.testing.assert_allclose(m, exact, atol=1e-5)
     np.testing.assert_allclose(col, exact[:, 7], atol=1e-5)
+
+
+@pytest.mark.parametrize("backend", ["ref", "interpret"])
+def test_small_distances_survive_the_noise_cut(backend):
+    """On offset, non-unit data (‖x‖² ≈ 7000, far from unit-norm images)
+    the cut zeroes only what the expansion cannot resolve: pairs whose
+    squared distance is 2 to 256 times `rules.DIST_REL_TOL` of
+    ‖g‖²+‖c‖² stay nonzero, within that share of the exact value, while
+    self-distances are exactly 0. The direct per-column formula has no
+    cut: it keeps every small distance, a quarter of the cut's included,
+    to f32 accuracy."""
+    from repro.data.synthetic import gen_images
+    x = 3.0 + 4.0 * gen_images(16, 768, seed=5)
+    u = np.random.default_rng(3).normal(size=x.shape)
+    u /= np.linalg.norm(u, axis=1, keepdims=True)
+    x64 = x.astype(np.float64)
+    share = np.array([0.25, 2.0, 16.0, 256.0] * 4)[:, None]
+    delta = np.sqrt(share * rules.DIST_REL_TOL
+                    * 2.0 * np.sum(x64 * x64, axis=1, keepdims=True))
+    p = np.concatenate([x, (x64 + delta * u).astype(np.float32)])
+    p64 = p.astype(np.float64)
+    i, j = np.arange(16), 16 + np.arange(16)
+    exact2 = np.sum((p64[i] - p64[j]) ** 2, axis=1)
+    scale = np.sum(p64[i] ** 2, axis=1) + np.sum(p64[j] ** 2, axis=1)
+    m = np.asarray(ops.pairwise_matrix(jnp.asarray(p), jnp.asarray(p),
+                                       rules.DIST_MIN, backend=backend))
+    m = m[:32, :32].astype(np.float64)
+    np.testing.assert_array_equal(np.diag(m), 0.0)
+    resolved = share[:, 0] >= 2.0
+    ir, jr = i[resolved], j[resolved]
+    assert np.all(m[ir, jr] > 0.0)
+    assert np.all(np.abs(m[ir, jr] ** 2 - exact2[resolved])
+                  <= rules.DIST_REL_TOL * scale[resolved])
+    for a, b in zip(i, j):
+        col = np.asarray(rules.pairwise_col(jnp.asarray(p),
+                                            jnp.asarray(p[b]),
+                                            rules.DIST_MIN))
+        assert col[b] == 0.0
+        np.testing.assert_allclose(col[a], np.sqrt(exact2[a]), rtol=1e-5)

@@ -250,12 +250,9 @@ def test_sharded_leaves_refuse_degraded_tree(monkeypatch, tmp_path):
 def test_force_host_devices(monkeypatch):
     from repro.launch.mesh import force_host_devices
     monkeypatch.delenv("XLA_FLAGS", raising=False)
-    # an accelerator platform: its real devices form the mesh, untouched
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-    assert not force_host_devices(8, trigger="--mesh", argv=["prog",
-                                                             "--mesh"])
-    assert "XLA_FLAGS" not in os.environ
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    # applied whatever JAX_PLATFORMS says (the flag only touches the CPU
+    # backend), so every simulator gets its host devices
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
     # trigger absent: untouched
     assert not force_host_devices(8, trigger="--mesh", argv=["prog"])
     assert "XLA_FLAGS" not in os.environ
@@ -268,6 +265,17 @@ def test_force_host_devices(monkeypatch):
     assert force_host_devices(4, argv=["prog", "--lanes", "6"])
     assert os.environ["XLA_FLAGS"] == \
         "--foo --xla_force_host_platform_device_count=6"
+
+
+def test_device_lanes():
+    """One lane per device JAX sees; a --lanes naming another count is
+    refused, never quietly resized."""
+    from repro.launch.mesh import device_lanes
+    n = len(jax.devices())
+    assert device_lanes(None, "--mesh") == n
+    assert device_lanes(n, "--mesh") == n
+    with pytest.raises(SystemExit, match="one lane per device"):
+        device_lanes(n + 1, "--mesh")
 
 
 # ---------------------------------------------------------------------------
