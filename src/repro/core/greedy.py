@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.kernels import plans
-from repro.runtime import flags
+from repro.runtime import flags, telemetry
 
 F32 = jnp.float32
 
@@ -116,37 +116,59 @@ def greedy(objective, ids: jax.Array, payloads: jax.Array, valid: jax.Array,
     keeps the lowest candidate index — same payload, possibly different
     id.
     """
-    n = ids.shape[0]
     if ground is None:
         ground, ground_valid = payloads, valid
-    state = objective.init_state(ground, ground_valid)
-    use_sampling = 0 < sample < n
-    if use_sampling:
-        key = key if key is not None else jax.random.PRNGKey(0)
-        cand_idx = _sample_candidates(key, k, n, sample)
+    with jax.named_scope("greedy.prepare"):
+        state = objective.init_state(ground, ground_valid)
+    dims = objective.plan_dims(state, payloads)
+    use_sampling = 0 < sample < ids.shape[0]
 
     # ONE planning decision for the whole invocation: rule + shapes +
     # budgets + the sampling/constraint flags (which demote the megakernel
     # to the fused scan — identical selections either way).
     plan = plans.select_engine(
-        objective.rule, *objective.plan_dims(state, payloads),
-        requested=engine, sampling=use_sampling,
+        objective.rule, *dims, requested=engine, sampling=use_sampling,
         constrained=constraint is not None, backend=objective.backend)
+    # one record per traced invocation: what the trace of this greedy
+    # launches and pads, per execution (per lane under vmap/shard_map)
+    rec = telemetry.record("greedy", rule=objective.rule.name,
+                           logical=[int(dims[0]), int(dims[1])], k=int(k),
+                           engine=plan.engine)
+    with telemetry.span("greedy", engine=plan.engine) as sp:
+        sol = _greedy(objective, state, ids, payloads, valid, k, plan,
+                      sample, key, constraint, use_sampling)
+    streams = [r for r in telemetry.records("stream") if r["span"] == sp.id]
+    rec.update(launches=int(sp.counts.get("launches", 0)),
+               relayout_bytes=int(sp.counts.get("relayout_bytes", 0)),
+               streams=streams)
+    return sol
+
+
+def _greedy(objective, state, ids, payloads, valid, k, plan, sample, key,
+            constraint, use_sampling) -> Solution:
+    """`greedy`'s body under the resolved `plan`."""
+    n = ids.shape[0]
+    if use_sampling:
+        key = key if key is not None else jax.random.PRNGKey(0)
+        cand_idx = _sample_candidates(key, k, n, sample)
 
     if plan.engine in ("mega_stream", "mega_resident"):
-        mega = objective.megakernel_loop(state, payloads, valid, k,
-                                         plan=plan)
+        with jax.named_scope("greedy.loop"):
+            mega = objective.megakernel_loop(state, payloads, valid, k,
+                                             plan=plan)
         if mega is not None:
             return _finalize_mega(objective, mega, ids, payloads, valid, k)
 
     cache = None
     if plan.engine == "fused":
-        cache = objective.prepare(state, payloads, valid, plan=plan)
+        with jax.named_scope("greedy.prepare"):
+            cache = objective.prepare(state, payloads, valid, plan=plan)
     if cache is not None:
         return _greedy_fused(objective, state, cache, ids, payloads, valid,
                              k, constraint,
                              cand_idx if use_sampling else None)
 
+    @jax.named_scope("greedy.step")
     def step(carry, xs):
         state, selected, evals, ccounts = carry
         feas = (constraint.feasible_mask(ccounts) if constraint is not None
@@ -188,9 +210,10 @@ def greedy(objective, ids: jax.Array, payloads: jax.Array, valid: jax.Array,
           else jnp.zeros((), jnp.int32))
     carry0 = (state, jnp.zeros((n,), jnp.bool_), jnp.zeros((), jnp.int32),
               c0)
-    (state, _, evals, _), (out_ids, out_pay, out_valid) = lax.scan(
-        step, carry0, cand_idx if use_sampling else None, length=k,
-        unroll=flags.scan_unroll())
+    with jax.named_scope("greedy.loop"), telemetry.repeat(k):
+        (state, _, evals, _), (out_ids, out_pay, out_valid) = lax.scan(
+            step, carry0, cand_idx if use_sampling else None, length=k,
+            unroll=flags.scan_unroll())
     return Solution(out_ids, out_pay, out_valid, objective.value(state),
                     evals)
 
@@ -242,6 +265,7 @@ def _greedy_fused(objective, state, cache, ids, payloads, valid, k,
     n = ids.shape[0]
     use_sampling = cand_idx is not None
 
+    @jax.named_scope("greedy.step")
     def step(carry, xs):
         state, selected, evals, ccounts, prev = carry
         feas = (constraint.feasible_mask(ccounts) if constraint is not None
@@ -276,8 +300,10 @@ def _greedy_fused(objective, state, cache, ids, payloads, valid, k,
           else jnp.zeros((), jnp.int32))
     carry0 = (state, jnp.zeros((n,), jnp.bool_), jnp.zeros((), jnp.int32),
               c0, jnp.int32(-1))
-    (state, _, evals, _, prev), (out_ids, out_pay, out_valid) = lax.scan(
-        step, carry0, cand_idx, length=k, unroll=flags.scan_unroll())
+    with jax.named_scope("greedy.loop"), telemetry.repeat(k):
+        (state, _, evals, _, prev), (out_ids, out_pay, out_valid) = \
+            lax.scan(step, carry0, cand_idx, length=k,
+                     unroll=flags.scan_unroll())
     state = objective.flush_pending(state, cache, prev)
     return Solution(out_ids, out_pay, out_valid, objective.value(state),
                     evals)

@@ -431,6 +431,7 @@ class LevelDispatcher:
             self._fns[key] = build()
         return self._fns[key]
 
+    @jax.named_scope("greedyml.leaves")
     def _leaf_body(self, ids, pay, val, mid):
         key = None
         if self.sample_leaf:
@@ -440,6 +441,7 @@ class LevelDispatcher:
                       constraint=(self.constraint.bind(ids)
                                   if self.constraint is not None else None))
 
+    @jax.named_scope("greedyml.leaves")
     def _shard_leaf_body(self, ids, pay, val):
         return shard_greedy(self.objective, ids, pay, val, self.k,
                             axis=self.shard_axis, lanes=self.shard,
@@ -495,6 +497,7 @@ class LevelDispatcher:
     def _build_level(self, lvl: int, has_aug: bool):
         axes, radices = self.tree_axes, self.radices
 
+        @jax.named_scope(f"greedyml.level{lvl}")
         def body(sol, *aug):
             out, _, _ = accumulate_one_level(
                 self.objective, sol, self.k, axes, radices, lvl,

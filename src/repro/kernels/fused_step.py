@@ -108,6 +108,7 @@ def fused_step_pallas(mat: jax.Array, row: jax.Array, mask: jax.Array,
         operands.insert(2, scale.reshape(nb, block_n))
     new_row, best, gain = pl.pallas_call(
         functools.partial(_kernel, rule=rule, quant=scale is not None),
+        name="fused_step_pallas",
         grid=(nb,),
         in_specs=in_specs,
         out_specs=[

@@ -152,6 +152,7 @@ def greedy_loop_pallas(mat: jax.Array, row: jax.Array, mask: jax.Array,
     row_out, best, gain = pl.pallas_call(
         functools.partial(_stream_kernel, rule=rule,
                           quant=scale is not None),
+        name="greedy_loop_pallas",
         grid=(k + 1, nb),
         in_specs=in_specs,
         out_specs=[
@@ -276,6 +277,7 @@ def greedy_loop_resident_pallas(ground: jax.Array, cands: jax.Array,
     row_out, best, gain = pl.pallas_call(
         functools.partial(_resident_kernel, k=k, rule=rule,
                           cache_dtype=cache_dtype),
+        name="greedy_loop_resident_pallas",
         out_shape=[
             jax.ShapeDtypeStruct((1, n), rule.dtype),
             jax.ShapeDtypeStruct((1, k), jnp.int32),

@@ -20,6 +20,12 @@ hit the jit/pallas compile cache instead of retracing per shape (DESIGN
 plain next-multiple pad, and constant factors like 1/N are applied
 OUTSIDE the kernels so they never become static compile keys.
 
+Every wrapper that launches a kernel counts, while it is traced
+(`runtime.telemetry`): one ``launches``, the ``relayout_bytes`` its pads
+write to reshape operands, and a ``stream`` record of the operand the
+kernel streams (logical shape, padded shape, bytes). The greedy driver
+gathers them into its per-invocation record.
+
 Engine planning (memory gates, tier selection, backend resolution) lives
 in kernels/plans.py; the legacy names (`fused_plan`, `stream_plan`,
 `fused_replicas`, …) are re-exported here for callers and tests.
@@ -56,7 +62,7 @@ from repro.kernels.plans import (EnginePlan, RES_TILE_N,  # noqa: F401
                                  loop_block_n, resident_fits,
                                  resolve_backend, select_engine, stream_plan)
 from repro.kernels.rules import KernelRule
-from repro.runtime import flags
+from repro.runtime import flags, telemetry
 
 F32 = jnp.float32
 
@@ -116,6 +122,27 @@ def _pad_to(x: jax.Array, axis: int, mult: int, value=0,
     return jnp.pad(x, widths, constant_values=value)
 
 
+def _relayout(src, out):
+    """`out`, the padded copy of operand `src`. Counts the bytes the copy
+    writes (``relayout_bytes``) when the pads changed the shape; chained
+    pads of one operand fuse into one copy, so they count once."""
+    if out.shape != src.shape:
+        telemetry.count("relayout_bytes", out.size * out.dtype.itemsize)
+    return out
+
+
+def _launch(kernel: str, logical, streamed) -> None:
+    """Trace-time counts of one kernel launch: ``launches``, and a
+    ``stream`` record of the operand the kernel streams, logical shape
+    against the padded shape it is handed."""
+    telemetry.count("launches")
+    telemetry.record("stream", kernel=kernel,
+                     logical=[int(x) for x in logical],
+                     padded=[int(x) for x in streamed.shape],
+                     bytes=int(streamed.size * streamed.dtype.itemsize),
+                     repeat=telemetry.multiplier())
+
+
 def _dummy_ground():
     return jnp.zeros(_DUMMY_GROUND, F32)
 
@@ -128,6 +155,7 @@ def _cast_row(row, rule: KernelRule):
     return row.astype(rule.dtype)
 
 
+@jax.named_scope("ops.gains")
 def gains(ground, row, cands, cand_valid, rule: KernelRule, backend=None):
     """Per-step marginal gains for any rule: RAW part sums (C,) f32, −inf
     at invalid candidates. Callers normalize by the valid ground count.
@@ -152,19 +180,25 @@ def gains(ground, row, cands, cand_valid, rule: KernelRule, backend=None):
                          rule)
     c = cands.shape[0]
     if rule.is_bitmap:
-        bits = _pad_to(_pad_to(cands, 0, TILE_C), 1, TILE_W, bucket=False)
-        r = _pad_to(_cast_row(row, rule), 0, TILE_W, bucket=False)
+        bits = _relayout(cands, _pad_to(_pad_to(cands, 0, TILE_C), 1,
+                                        TILE_W, bucket=False))
+        r = _relayout(row, _pad_to(_cast_row(row, rule), 0, TILE_W,
+                                   bucket=False))
+        _launch("gains_pallas", cands.shape, bits)
         raw = gains_pallas(_dummy_ground(), r.reshape(1, -1), bits, rule,
                            interpret=(b == "interpret"))[:c]
         return jnp.where(cand_valid, raw, -jnp.inf)
     # feature axis never drifts between calls → plain 128-multiple pad
-    g = _pad_to(_pad_to(ground, 0, TILE_N), 1, 128, bucket=False)
-    r = _pad_to(_cast_row(row, rule), 0, TILE_N,
-                value=_row_pad_value(rule))  # pad rows ⇒ zero gain part
-    cd = _pad_to(_pad_to(cands, 0, TILE_C), 1, 128, bucket=False)
+    g = _relayout(ground, _pad_to(_pad_to(ground, 0, TILE_N), 1, 128,
+                                  bucket=False))
+    r = _relayout(row, _pad_to(_cast_row(row, rule), 0, TILE_N,
+                               value=_row_pad_value(rule)))  # ⇒ zero gain
+    cd = _relayout(cands, _pad_to(_pad_to(cands, 0, TILE_C), 1, 128,
+                                  bucket=False))
     gscale = None
     if quant:
         g, gscale, _ = _quantized_ground(g.astype(F32))
+    _launch("gains_pallas", cands.shape, cd)
     raw = gains_pallas(g, r.reshape(1, -1), cd, rule,
                        interpret=(b == "interpret"), gscale=gscale)[:c]
     return jnp.where(cand_valid, raw, -jnp.inf)
@@ -175,6 +209,7 @@ def gains(ground, row, cands, cand_valid, rule: KernelRule, backend=None):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("ops.pairwise_matrix")
 def pairwise_matrix(ground, cands, rule: KernelRule, backend=None,
                     dtype: str = "float32"):
     """The cached ground×candidate matrix for any rule.
@@ -196,14 +231,18 @@ def pairwise_matrix(ground, cands, rule: KernelRule, backend=None,
     if rule.is_bitmap:
         if b == "ref":
             return cands.T
-        return _pad_to(_pad_to(cands, 0, 128), 1, 256).T   # (W_pad, C_pad)
+        # (W_pad, C_pad)
+        return _relayout(cands, _pad_to(_pad_to(cands, 0, 128), 1, 256)).T
     if b == "ref":
         m = rules_mod.matrix_block(ground, cands, rule)
         if dtype == "int8":
             return QuantMatrix(*rules_mod.quantize_rows(m))
         return m if dtype == "float32" else m.astype(jnp.dtype(dtype))
-    g = _pad_to(_pad_to(ground, 0, 256), 1, 128, bucket=False)
-    cd = _pad_to(_pad_to(cands, 0, 128), 1, 128, bucket=False)
+    g = _relayout(ground, _pad_to(_pad_to(ground, 0, 256), 1, 128,
+                                  bucket=False))
+    cd = _relayout(cands, _pad_to(_pad_to(cands, 0, 128), 1, 128,
+                                  bucket=False))
+    _launch("pairwise_pallas", cands.shape, cd)
     if dtype == "int8":
         # quantization is a cheap jnp epilogue on the f32 kernel output
         # (one pass, fuses under jit) — zero extra dispatches. Pad
@@ -222,6 +261,7 @@ def pairwise_matrix(ground, cands, rule: KernelRule, backend=None,
                            interpret=(b == "interpret"))
 
 
+@jax.named_scope("ops.fused_step")
 def fused_step(mat, row, mask, prev, rule: KernelRule, backend=None,
                plan: Optional[EnginePlan] = None):
     """One fused greedy step over the cached matrix.
@@ -239,13 +279,14 @@ def fused_step(mat, row, mask, prev, rule: KernelRule, backend=None,
         return ref.fused_step(_dequant_mat(mat), _cast_row(row, rule),
                               mask.astype(F32), prev, rule)
     n_pad, c_pad = mat.shape
-    r = _pad_to(_cast_row(row, rule), 0, n_pad,
-                value=_row_pad_value(rule), bucket=False)
-    mk = _pad_to(mask.astype(F32), 0, c_pad, bucket=False)
+    r = _relayout(row, _pad_to(_cast_row(row, rule), 0, n_pad,
+                               value=_row_pad_value(rule), bucket=False))
+    mk = _relayout(mask, _pad_to(mask.astype(F32), 0, c_pad, bucket=False))
     bn = (plan.block_n if plan is not None else 0) or fused_block_n(
         n_pad, c_pad, mat.dtype.itemsize)
     assert bn, "fused_step called without a feasible plan (select_engine)"
     quant = isinstance(mat, QuantMatrix)
+    _launch("fused_step_pallas", (n, c), mat.q if quant else mat)
     new_row, best, gain = fused_step_pallas(
         mat.q if quant else mat, r, mk, prev, rule, block_n=bn,
         interpret=(b == "interpret"),
@@ -255,6 +296,7 @@ def fused_step(mat, row, mask, prev, rule: KernelRule, backend=None,
     return new_row[:n], best, gain
 
 
+@jax.named_scope("ops.greedy_loop")
 def greedy_loop(mat, row, mask, k: int, rule: KernelRule, backend=None,
                 plan: Optional[EnginePlan] = None):
     """STREAMING megakernel tier: the entire k-step greedy over an
@@ -270,14 +312,16 @@ def greedy_loop(mat, row, mask, k: int, rule: KernelRule, backend=None,
         return ref.greedy_loop(_dequant_mat(mat), _cast_row(row, rule),
                                mask.astype(F32), k, rule)
     n_pad, c_pad = mat.shape
-    r = _pad_to(_cast_row(row, rule), 0, n_pad,
-                value=_row_pad_value(rule),
-                bucket=False).reshape(1, n_pad)
-    mk = _pad_to(mask.astype(F32), 0, c_pad, bucket=False).reshape(1, c_pad)
+    r = _relayout(row, _pad_to(_cast_row(row, rule), 0, n_pad,
+                               value=_row_pad_value(rule),
+                               bucket=False)).reshape(1, n_pad)
+    mk = _relayout(mask, _pad_to(mask.astype(F32), 0, c_pad,
+                                 bucket=False)).reshape(1, c_pad)
     bn = (plan.loop_block_n if plan is not None else 0) or loop_block_n(
         n_pad, c_pad, mat.dtype.itemsize)
     assert bn, "greedy_loop called without a feasible streaming plan"
     quant = isinstance(mat, QuantMatrix)
+    _launch("greedy_loop_pallas", (n, c), mat.q if quant else mat)
     new_row, bests, gains_ = greedy_loop_pallas(
         mat.q if quant else mat, r, mk, k, rule, block_n=bn,
         interpret=(b == "interpret"),
@@ -287,6 +331,7 @@ def greedy_loop(mat, row, mask, k: int, rule: KernelRule, backend=None,
     return new_row[:n], bests, gains_
 
 
+@jax.named_scope("ops.greedy_loop_resident")
 def greedy_loop_resident(ground, cands, row, mask, k: int,
                          rule: KernelRule, backend=None,
                          cache_dtype: str = "float32",
@@ -332,22 +377,28 @@ def greedy_loop_resident(ground, cands, row, mask, k: int,
                                mask.astype(F32), k, rule, kq=kq_)
     if rule.is_bitmap:
         g = _dummy_ground()
-        cd = _pad_to(_pad_to(cands, 0, 128), 1, 128)
+        cd = _relayout(cands, _pad_to(_pad_to(cands, 0, 128), 1, 128))
         n_pad, c_pad = cd.shape[1], cd.shape[0]
         d_pad = None
-        r = _pad_to(_cast_row(row, rule), 0, 128).reshape(1, n_pad)
+        r = _relayout(row, _pad_to(_cast_row(row, rule), 0,
+                                   128)).reshape(1, n_pad)
     else:
-        g = _pad_to(_pad_to(ground, 0, RES_TILE_N), 1, 128, bucket=False)
-        cd = _pad_to(_pad_to(cands, 0, 128), 1, 128, bucket=False)
+        g = _relayout(ground, _pad_to(_pad_to(ground, 0, RES_TILE_N), 1,
+                                      128, bucket=False))
+        cd = _relayout(cands, _pad_to(_pad_to(cands, 0, 128), 1, 128,
+                                      bucket=False))
         n_pad, c_pad, d_pad = g.shape[0], cd.shape[0], g.shape[1]
-        r = _pad_to(_cast_row(row, rule), 0, RES_TILE_N,
-                    value=_row_pad_value(rule)).reshape(1, n_pad)
-    mk = _pad_to(mask.astype(F32), 0, 128).reshape(1, c_pad)
+        r = _relayout(row, _pad_to(_cast_row(row, rule), 0, RES_TILE_N,
+                                   value=_row_pad_value(rule))
+                      ).reshape(1, n_pad)
+    mk = _relayout(mask, _pad_to(mask.astype(F32), 0, 128)
+                   ).reshape(1, c_pad)
     ctl = jnp.stack([kq_, jnp.asarray(ln, jnp.int32),
                      jnp.asarray(lc, jnp.int32)]).reshape(1, 3)
     # the kernel holds its on-chip matrix in f32 whatever the plan's
     # storage dtype, so its working set is the f32 one
     need = plans.resident_need(n_pad, c_pad, d_pad, rule=rule)
+    _launch("greedy_loop_resident_pallas", cands.shape, cd)
     new_row, bests, gains_ = greedy_loop_resident_pallas(
         g, cd, r, mk, ctl, k, rule, interpret=(b == "interpret"),
         cache_dtype=cache_dtype, vmem_limit_bytes=plans.vmem_limit(need))
@@ -403,6 +454,7 @@ def count_pallas_dispatches(jaxpr) -> int:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("ops.stream_filter")
 def stream_filter(ground, batch, rows, row0, values, counts, expos, m_max,
                   bvalid, k: int, eps_log: float, rule: KernelRule,
                   backend=None, plan: Optional[dict] = None,
@@ -462,36 +514,42 @@ def stream_filter(ground, batch, rows, row0, values, counts, expos, m_max,
     pad_val = _row_pad_value(rule)
     if rule.is_bitmap:
         g = _dummy_ground()
-        bt = _pad_to(_pad_to(batch, 0, 128, bucket=False), 1, 128,
-                     bucket=False)
+        bt = _relayout(batch, _pad_to(_pad_to(batch, 0, 128, bucket=False),
+                                      1, 128, bucket=False))
         n_pad = bt.shape[1]
     else:
-        g = _pad_to(_pad_to(ground, 0, 128, bucket=False), 1, 128,
-                    bucket=False)
-        bt = _pad_to(_pad_to(batch, 0, 128, bucket=False), 1, 128,
-                     bucket=False)
+        g = _relayout(ground, _pad_to(_pad_to(ground, 0, 128,
+                                              bucket=False),
+                                      1, 128, bucket=False))
+        bt = _relayout(batch, _pad_to(_pad_to(batch, 0, 128, bucket=False),
+                                      1, 128, bucket=False))
         n_pad = g.shape[0]
     gscale = None
     if quant:
         g, gscale, _ = _quantized_ground(g.astype(F32))
-    r = _pad_to(_cast_row(rows, rule), 1, n_pad, value=pad_val,
-                bucket=False)
-    r0 = _pad_to(_cast_row(row0, rule), 0, n_pad, value=pad_val,
-                 bucket=False).reshape(1, n_pad)
+    r = _relayout(rows, _pad_to(_cast_row(rows, rule), 1, n_pad,
+                                value=pad_val, bucket=False))
+    r0 = _relayout(row0, _pad_to(_cast_row(row0, rule), 0, n_pad,
+                                 value=pad_val, bucket=False)
+                   ).reshape(1, n_pad)
     vals = values.astype(F32).reshape(l, 1)
     cnt = counts.astype(jnp.int32).reshape(l, 1)
     exp_ = expos.astype(jnp.int32).reshape(l, 1)
     m_ = m_max.astype(F32).reshape(1, 1)
-    bv = _pad_to(bvalid.astype(F32).reshape(1, b), 1, 128, bucket=False)
+    bv = _relayout(bvalid.reshape(1, b),
+                   _pad_to(bvalid.astype(F32).reshape(1, b), 1, 128,
+                           bucket=False))
     cost_kw = {}
     if has_cost:
         # pad arrivals carry bvalid = 0, so their (zero) pad cost is inert
         cost_kw = dict(
-            costs=_pad_to(costs.astype(F32).reshape(1, b), 1, 128,
-                          bucket=False),
+            costs=_relayout(costs.reshape(1, b),
+                            _pad_to(costs.astype(F32).reshape(1, b), 1,
+                                    128, bucket=False)),
             spent=spent.astype(F32).reshape(l, 1),
             budget=jnp.asarray(budget, F32).reshape(1, 1))
     need = plans.stream_need(n, l, b, d, plan["dtype"])
+    _launch("stream_filter_pallas", batch.shape, bt)
     out = stream_filter_pallas(g, bt, r, r0, vals, cnt, exp_, m_, bv, k,
                                eps_log, rule,
                                interpret=(bk == "interpret"),

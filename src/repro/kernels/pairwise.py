@@ -65,6 +65,7 @@ def pairwise_pallas(ground: jax.Array, cands: jax.Array, mode: str = "dist",
     grid = (n // TILE_N, c // TILE_C)
     return pl.pallas_call(
         functools.partial(_kernel, mode=mode),
+        name="pairwise_pallas",
         grid=grid,
         in_specs=[
             pl.BlockSpec((TILE_N, d), lambda ni, ci: (ni, 0)),
@@ -158,6 +159,7 @@ def gains_pallas(ground: jax.Array, row: jax.Array, cands: jax.Array,
             kernel = _gains_kernel_quant
     out = pl.pallas_call(
         functools.partial(kernel, rule=rule),
+        name="gains_pallas",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, TILE_C), lambda ci, ni: (0, ci)),

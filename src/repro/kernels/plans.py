@@ -35,10 +35,11 @@ import dataclasses
 import json
 import math
 import os
-from typing import Optional, Tuple
+import time
+from typing import List, Optional, Tuple
 
 from repro.kernels.rules import KernelRule, cache_itemsize
-from repro.runtime import flags
+from repro.runtime import flags, telemetry
 
 # resident-tier padding base: accumulation-node shapes drift level by
 # level, so the ground-row axis buckets from a small base to keep the
@@ -233,9 +234,17 @@ def resident_fits(n_pad: int, c_pad: int, d_pad: Optional[int],
     return need is not None and need <= flags.fused_vmem_mb() * 2 ** 20
 
 
+def _refuse(refused: Optional[list], tier: str, gate: str,
+            need: Optional[int], budget: float, **extra) -> None:
+    if refused is not None:
+        refused.append({"tier": tier, "gate": gate,
+                        "need": None if need is None else int(need),
+                        "budget": int(budget), **extra})
+
+
 def fused_plan(n: int, c: int, d: Optional[int] = None,
-               backend=None, rule: Optional[KernelRule] = None
-               ) -> Optional[dict]:
+               backend=None, rule: Optional[KernelRule] = None,
+               refused: Optional[list] = None) -> Optional[dict]:
     """Static (trace-time) three-way memory gate for the cached-matrix
     engines (DESIGN §Perf).
 
@@ -263,6 +272,12 @@ def fused_plan(n: int, c: int, d: Optional[int] = None,
                    per-row-scaled quantized entries, kernels rescale and
                    accumulate in f32 either way); bitmap rules always
                    store 'uint32'
+
+    ``refused``: when a list is given, every tier or storage dtype the
+    gates turn down is appended to it as ``{'tier', 'gate', 'need',
+    'budget'}`` in bytes. Gates: 'hbm_cache' (the (n, c) matrix, with
+    its 'dtype'), 'resident_vmem', 'fused_block_vmem' and
+    'loop_block_vmem' (the working set at the smallest row block).
     """
     b = resolve_backend(backend)
     bitmap = rule is not None and rule.is_bitmap
@@ -278,26 +293,29 @@ def fused_plan(n: int, c: int, d: Optional[int] = None,
         n_res = bucket_len(n, 128 if bitmap else RES_TILE_N)
         d_pad = -(-d // 128) * 128 if d else None
     cache = flags.fused_cache_mb() * 2 ** 20
+    vmem = flags.fused_vmem_mb() * 2 ** 20
     pref = flags.fused_cache_dtype()
     forced = {"f32": "float32", "bf16": "bfloat16",
               "int8": "int8"}.get(pref)
     dtype, itemsize = None, 4
-    if bitmap:
-        if n_pad * c_pad * 4 * _VMAP_REPLICAS <= cache:
-            dtype = "uint32"
-    else:
-        for cand in ("float32", "bfloat16", "int8"):
-            if forced is not None and cand != forced:
-                continue
-            size = cache_itemsize(cand)
-            if n_pad * c_pad * size * _VMAP_REPLICAS <= cache:
-                dtype, itemsize = cand, size
-                break
+    for cand in (("uint32",) if bitmap
+                 else ("float32", "bfloat16", "int8")):
+        if forced is not None and not bitmap and cand != forced:
+            continue
+        size = cache_itemsize(cand)
+        need = n_pad * c_pad * size * _VMAP_REPLICAS
+        if need <= cache:
+            dtype, itemsize = cand, size
+            break
+        _refuse(refused, "cached", "hbm_cache", need, cache, dtype=cand)
     if dtype is None:
         return None
-    resident = ((bitmap or d_pad is not None)
-                and resident_fits(n_res, c_pad, d_pad, rule=rule,
+    res_need = ((bitmap or d_pad is not None)
+                and resident_need(n_res, c_pad, d_pad, rule=rule,
                                   itemsize=itemsize))
+    resident = bool(res_need) and res_need <= vmem
+    if res_need and not resident:
+        _refuse(refused, "resident", "resident_vmem", res_need, vmem)
     if b == "ref":
         return {"tier": "resident" if resident else "streaming",
                 "block_n": 0, "loop_block_n": 0, "dtype": dtype}
@@ -305,9 +323,15 @@ def fused_plan(n: int, c: int, d: Optional[int] = None,
     if resident:
         return {"tier": "resident", "block_n": bn, "loop_block_n": 0,
                 "dtype": dtype}
-    if bn == 0:
-        return None
     bn_loop = loop_block_n(n_pad, c_pad, itemsize)
+    least = _block_min(itemsize)
+    if bn_loop == 0:
+        _refuse(refused, "streaming", "loop_block_vmem",
+                loop_need(least, n_pad, c_pad, itemsize), vmem)
+    if bn == 0:
+        _refuse(refused, "fused", "fused_block_vmem",
+                fused_need(least, n_pad, c_pad, itemsize), vmem)
+        return None
     return {"tier": "streaming" if bn_loop else "fused",
             "block_n": bn, "loop_block_n": bn_loop, "dtype": dtype}
 
@@ -390,7 +414,8 @@ def shard_bytes(n: int, d: int, lanes: int, tile_c: int) -> int:
 
 
 def shard_plan(rule: KernelRule, n: int, d: Optional[int], lanes: int,
-               backend=None) -> Optional[dict]:
+               backend=None, refused: Optional[list] = None
+               ) -> Optional[dict]:
     """Budget gate for the `sharded` engine tier, in the style of
     `fused_plan`: the widest candidate tile whose per-device working set
     (`shard_bytes`) fits the REPRO_FUSED_CACHE_MB per-device budget, or
@@ -402,14 +427,18 @@ def shard_plan(rule: KernelRule, n: int, d: Optional[int], lanes: int,
     Returns {'tile_c', 'bytes', 'dtype'} — the tier streams f32 features
     through the same rule-parameterized gains kernels as the solo tiers
     (the int8 ladder is a CACHE storage option; there is no cache here).
+    ``refused``: as in `fused_plan`, gate 'shard' (need None when the
+    tier does not apply at all).
     """
-    if rule.is_bitmap or lanes < 2 or not d:
-        return None
     budget = flags.fused_cache_mb() * 2 ** 20
+    if rule.is_bitmap or lanes < 2 or not d:
+        _refuse(refused, "sharded", "shard", None, budget)
+        return None
     for tile in _SHARD_TILES:
         need = shard_bytes(n, d, lanes, tile)
         if need <= budget:
             return {"tile_c": tile, "bytes": need, "dtype": "float32"}
+    _refuse(refused, "sharded", "shard", need, budget, tile_c=tile)
     return None
 
 
@@ -667,35 +696,62 @@ def select_engine(rule: KernelRule, n: int, c: int,
     the gate's tile_c instead of falling all the way to 'step'. Sampling
     and constrained selection stay on the solo paths (their per-step
     host logic has no cross-device protocol).
+
+    Every call leaves a ``plan`` record (`runtime.telemetry`): the
+    shape, the request, the resulting plan, where it came from
+    ('requested' | 'override' | 'tuned' | 'static'), the host seconds
+    the decision took, and each tier the budget gates refused, with the
+    bytes it needed and the budget (`fused_plan`'s ``refused``).
     """
     if requested not in ("auto", "mega", "fused", "step"):
         raise ValueError(f"unknown engine {requested!r}; "
                          "expected 'auto', 'mega', 'fused', or 'step'")
-    b = resolve_backend(backend)
+    t0 = time.perf_counter()
+    refused: List[dict] = []
+    plan, source = _select_engine(rule, n, c, d, requested, sampling,
+                                  constrained, resolve_backend(backend),
+                                  lanes, refused)
+    telemetry.record(
+        "plan", rule=rule.name, n=int(n), c=int(c),
+        d=None if d is None else int(d), requested=requested,
+        sampling=bool(sampling), constrained=bool(constrained),
+        lanes=int(lanes), source=source, engine=plan.engine,
+        tier=plan.tier, dtype=plan.dtype, block_n=plan.block_n,
+        loop_block_n=plan.loop_block_n, tile_c=plan.tile_c,
+        refused=refused, plan_s=time.perf_counter() - t0)
+    return plan
+
+
+def _select_engine(rule: KernelRule, n: int, c: int, d: Optional[int],
+                   requested: str, sampling: bool, constrained: bool,
+                   b: str, lanes: int, refused: list
+                   ) -> Tuple[EnginePlan, str]:
+    """`select_engine`'s decision, with where the plan came from."""
     step = EnginePlan("step", rule, b)
     if requested == "step":
-        return step
+        return step, "requested"
     # measured plans outrank the heuristics: an explicit override (the
     # autotuner timing one candidate), then a validated cache entry
-    fp = _PLAN_OVERRIDE
+    source, fp = "override", _PLAN_OVERRIDE
     if fp is None:
-        fp = _tuned_plan(rule, n, c, d, b)
+        source, fp = "tuned", _tuned_plan(rule, n, c, d, b)
     if fp is None:
-        fp = fused_plan(n, c, d=d, backend=b, rule=rule)
+        source = "static"
+        fp = fused_plan(n, c, d=d, backend=b, rule=rule, refused=refused)
     elif fp.get("tier") == "step":
-        return step
+        return step, source
     if fp is None:
         # paper's memory-capped regime: no cached tier fits one device —
         # escalate to the cross-device sharded tier when the caller
         # offered lanes and the shard gate admits the pool
         if (lanes > 1 and requested in ("auto", "mega")
                 and not sampling and not constrained):
-            sp = shard_plan(rule, n, d, lanes, backend=b)
+            sp = shard_plan(rule, n, d, lanes, backend=b, refused=refused)
             if sp is not None:
                 return EnginePlan("sharded", rule, b, tier="sharded",
-                                  dtype=sp["dtype"],
-                                  tile_c=sp["tile_c"], lanes=lanes)
-        return step
+                                  dtype=sp["dtype"], tile_c=sp["tile_c"],
+                                  lanes=lanes), source
+        return step, source
     mega_ok = (requested in ("auto", "mega") and not sampling
                and not constrained and fp["tier"] in ("resident",
                                                       "streaming"))
@@ -705,10 +761,11 @@ def select_engine(rule: KernelRule, n: int, c: int,
     elif requested in ("fused", "mega") or not sampling:
         engine = "fused"
     else:
-        return step                         # auto + sampling: step wins
+        return step, source                 # auto + sampling: step wins
     return EnginePlan(engine, rule, b, tier=fp["tier"],
                       block_n=fp["block_n"],
-                      loop_block_n=fp["loop_block_n"], dtype=fp["dtype"])
+                      loop_block_n=fp["loop_block_n"],
+                      dtype=fp["dtype"]), source
 
 
 # ---------------------------------------------------------------------------

@@ -62,7 +62,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.kernels import ops as kernel_ops
 from repro.kernels import plans
 from repro.kernels import rules as R
-from repro.runtime import flags
+from repro.runtime import flags, telemetry
 
 F32 = jnp.float32
 _BIG_IDX = jnp.int32(2 ** 30)
@@ -177,8 +177,9 @@ def shard_greedy(objective, ids: jax.Array, payloads: jax.Array,
         return (row, selected, evals + n_evals), out
 
     carry0 = (row0, jnp.zeros((n_s,), jnp.bool_), jnp.zeros((), jnp.int32))
-    (row, _, evals), (out_ids, out_pay, out_valid) = lax.scan(
-        step, carry0, None, length=k, unroll=flags.scan_unroll())
+    with telemetry.repeat(k):
+        (row, _, evals), (out_ids, out_pay, out_valid) = lax.scan(
+            step, carry0, None, length=k, unroll=flags.scan_unroll())
     tot = lax.psum(jnp.sum(jnp.where(valid, row, 0.0)), axis)
     value = base - tot / n_eff if rule.fold == "min" else tot / n_eff
     return Solution(out_ids, out_pay, out_valid, value, evals)

@@ -204,6 +204,7 @@ def stream_filter_pallas(ground: jax.Array, batch: jax.Array,
     return pl.pallas_call(
         functools.partial(_kernel, k=k, eps_log=eps_log, rule=rule,
                           quant=gscale is not None, has_cost=has_cost),
+        name="stream_filter_pallas",
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((nb, n), rule.dtype)],
         compiler_params=pltpu.CompilerParams(

@@ -28,9 +28,11 @@ every merged level, so recovery is a three-tier state machine
      (1605.09619) expected-quality term (tests assert a ≥0.95× band).
 
 Every failure/restore/checkpoint/reshard/straggler event lands in a
-structured recovery log (``events``: kind + level + lane + wall time),
-and `StragglerMonitor` observations of per-level wall times trigger
-pre-emptive checkpoints when the cadence would otherwise skip one. The
+structured recovery log (``events``: kind + level + lane + wall time;
+a ``checkpoint`` also carries the save's ``dur_s``, a ``dispatch`` the
+stage's ``lowerings``), and `StragglerMonitor` observations of
+per-level wall times trigger pre-emptive checkpoints when the cadence
+would otherwise skip one. The
 same supervision wraps the continuous streaming driver's periodic tree
 merges via `run_merge` (streaming/driver.stream_select_continuous): a
 transient merge failure replays from the in-memory lane states, a lost
@@ -55,6 +57,7 @@ from repro.core.greedy import Solution
 from repro.core.greedyml import (LevelDispatcher, empty_lane_solutions,
                                  root_solution, shard_lanes)
 from repro.runtime.elastic import plan_degraded_tree
+from repro.runtime import telemetry
 from repro.runtime.fault import WorkerFailure
 from repro.runtime.straggler import StragglerMonitor
 
@@ -147,6 +150,7 @@ class SelectionSupervisor:
         return delay
 
     # ------------------------------------------------------- selection runs
+    @telemetry.span("greedyml.select")
     def select(self, objective, ids: jax.Array, payloads: jax.Array,
                valid: jax.Array, k: int, *, lanes: int, branching: int = 0,
                mesh=None, tree_axes: Optional[Sequence[str]] = None,
@@ -172,7 +176,13 @@ class SelectionSupervisor:
         checkpoint (any tree epoch) and continues from the next level.
         Returns ``(solution, info)`` where info carries the recovery
         log, the initial and final tree shapes, and the surviving
-        worker set."""
+        worker set.
+
+        Host spans (`runtime.telemetry`): this call is one
+        ``greedyml.select``; each dispatched stage a ``greedyml.stage``
+        (attrs level, epoch) whose ``lowerings`` count says which stage
+        recompiled, with its ``greedyml.checkpoint`` inside; each
+        level replay a ``greedyml.restart``."""
         tile_c = 0
         if mesh is not None:
             tree_axes = tuple(tree_axes)
@@ -248,44 +258,57 @@ class SelectionSupervisor:
                 jnp.zeros((1,) + payloads.shape[1:], payloads.dtype))
             try:
                 while next_stage <= L:
-                    if self.injector is not None:
-                        self.injector.check(next_stage, alive=workers)
-                    t0 = self.clock()
-                    if next_stage == 0:
-                        new_state = disp.leaves(il, pl, vl)
-                    else:
-                        lvl = next_stage - 1
-                        aug_row = aug[lvl] if aug is not None else None
-                        new_state = disp.level(state, lvl, aug_row)
-                    new_state = jax.block_until_ready(new_state)
-                    wall = self.clock() - t0
-                    self._dispatches += 1
-                    self._log("dispatch", level=next_stage, epoch=epoch,
-                              wall_s=wall)
-                    preempt = False
-                    if self.monitor is not None:
-                        act = self.monitor.observe(self._dispatches, wall)
-                        if act:
-                            self._log("straggler", level=next_stage,
-                                      wall_s=wall, action=act)
-                            preempt = True
-                    state = new_state
-                    if (next_stage == 0 or next_stage == L or preempt
-                            or next_stage % self.ckpt_every_levels == 0):
-                        manager.save(
-                            self._epoch_dir(epoch), next_stage, state,
-                            extra={"stage": next_stage, "epoch": epoch,
-                                   "workers": workers,
-                                   "radices": list(disp.radices),
-                                   "branching": b, "k": k,
-                                   "shard": disp.shard,
-                                   "tile_c": disp.tile_c,
-                                   "preemptive": preempt},
-                            keep=self.keep)
-                        self._log("checkpoint", level=next_stage,
-                                  epoch=epoch, preemptive=preempt)
-                        restarts = 0
-                    next_stage += 1
+                    with telemetry.span("greedyml.stage",
+                                        level=next_stage,
+                                        epoch=epoch) as stage:
+                        if self.injector is not None:
+                            self.injector.check(next_stage, alive=workers)
+                        t0 = self.clock()
+                        if next_stage == 0:
+                            new_state = disp.leaves(il, pl, vl)
+                        else:
+                            lvl = next_stage - 1
+                            aug_row = aug[lvl] if aug is not None else None
+                            new_state = disp.level(state, lvl, aug_row)
+                        new_state = jax.block_until_ready(new_state)
+                        wall = self.clock() - t0
+                        self._dispatches += 1
+                        self._log("dispatch", level=next_stage,
+                                  epoch=epoch, wall_s=wall,
+                                  lowerings=int(stage.counts.get(
+                                      "lowerings", 0)))
+                        preempt = False
+                        if self.monitor is not None:
+                            act = self.monitor.observe(self._dispatches,
+                                                       wall)
+                            if act:
+                                self._log("straggler", level=next_stage,
+                                          wall_s=wall, action=act)
+                                preempt = True
+                        state = new_state
+                        if (next_stage == 0 or next_stage == L or preempt
+                                or next_stage % self.ckpt_every_levels
+                                == 0):
+                            t_ck = self.clock()
+                            with telemetry.span("greedyml.checkpoint",
+                                                level=next_stage):
+                                manager.save(
+                                    self._epoch_dir(epoch), next_stage,
+                                    state,
+                                    extra={"stage": next_stage,
+                                           "epoch": epoch,
+                                           "workers": workers,
+                                           "radices": list(disp.radices),
+                                           "branching": b, "k": k,
+                                           "shard": disp.shard,
+                                           "tile_c": disp.tile_c,
+                                           "preemptive": preempt},
+                                    keep=self.keep)
+                            self._log("checkpoint", level=next_stage,
+                                      epoch=epoch, preemptive=preempt,
+                                      dur_s=self.clock() - t_ck)
+                            restarts = 0
+                        next_stage += 1
                 sol = root_solution(state)
                 info = {"tree": tree0,
                         "final_tree": (disp.lanes, b, disp.num_levels),
@@ -317,8 +340,10 @@ class SelectionSupervisor:
                         aug = aug[:disp.num_levels]
                     restarts = 0
                     continue
-                delay = self._backoff(restarts)
-                state, next_stage = self._rewind(epoch, example)
+                with telemetry.span("greedyml.restart", level=next_stage,
+                                    epoch=epoch):
+                    delay = self._backoff(restarts)
+                    state, next_stage = self._rewind(epoch, example)
                 self._log("restart", level=next_stage, epoch=epoch,
                           lane=lane, backoff_s=delay)
 

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from repro.core import greedy as greedy_mod
 from repro.core.objective import make_objective
 from repro.kernels import ops, plans
-from repro.runtime import flags
+from repro.runtime import flags, telemetry
 from repro.serving.metrics import ServeMetrics
 
 F32 = jnp.float32
@@ -226,63 +226,69 @@ class QueryEngine:
         obj = self._objective(q)
         c = int(q.valid.shape[0])
         key = (jax.random.PRNGKey(q.seed) if 0 < q.sample < c else None)
-        sol = greedy_mod.greedy(obj, jnp.asarray(q.ids, jnp.int32),
-                                jnp.asarray(q.payloads),
-                                jnp.asarray(q.valid).astype(bool), q.k,
-                                sample=q.sample, key=key,
-                                constraint=q.constraint, engine=q.engine)
-        jax.block_until_ready(sol.ids)
+        with telemetry.span("serve.dispatch", batch=1):
+            sol = greedy_mod.greedy(obj, jnp.asarray(q.ids, jnp.int32),
+                                    jnp.asarray(q.payloads),
+                                    jnp.asarray(q.valid).astype(bool), q.k,
+                                    sample=q.sample, key=key,
+                                    constraint=q.constraint, engine=q.engine)
+        with telemetry.span("serve.wait", batch=1):
+            jax.block_until_ready(sol.ids)
         lat = self.metrics.completed(q.tenant, t0, batched=False)
         return QueryResult(qid, q.tenant, sol, False, 1, None, lat)
 
     def _run_batched(self, skey: str, sp: dict, group) -> List[QueryResult]:
         t_exec = time.monotonic()
-        plan = sp["plan"]
-        obj0 = self._objective(group[0][1])
-        rule = obj0.rule
-        c_bkt = plans.bucket_len(
-            max(int(q.valid.shape[0]) for _, q, _ in group), 128)
-        k_pad = plans.bucket_len(max(q.k for _, q, _ in group), 4)
-        b_pad = 1
-        while b_pad < len(group):
-            b_pad *= 2
-        b_pad = max(min(b_pad, sp["b_max"]), len(group))
-        pays, vals, ks, lims, padded = [], [], [], [], []
-        for _, q, _ in group:
-            c = int(q.valid.shape[0])
-            ids_p = _pad_axis0(jnp.asarray(q.ids, jnp.int32), c_bkt, -1)
-            pay_p = _pad_axis0(jnp.asarray(q.payloads), c_bkt, 0)
-            val_p = _pad_axis0(jnp.asarray(q.valid).astype(bool), c_bkt,
-                               False)
-            padded.append((ids_p, pay_p, val_p))
-            pays.append(pay_p)
-            vals.append(val_p)
-            ks.append(q.k)
-            lims.append((obj0.words if rule.is_bitmap else c, c))
-        while len(pays) < b_pad:        # inert fill queries: k=0, all-invalid
-            pays.append(jnp.zeros_like(pays[0]))
-            vals.append(jnp.zeros_like(vals[0]))
-            ks.append(0)
-            lims.append((0, 0))
-        fn, ndisp = self._executor(obj0, skey, plan, b_pad,
-                                   pays[0].shape, pays[0].dtype, k_pad)
-        states, bests, gains = fn(jnp.stack(pays), jnp.stack(vals),
-                                  jnp.asarray(ks, jnp.int32),
-                                  jnp.asarray(lims, jnp.int32))
-        jax.block_until_ready(bests)
+        with telemetry.span("serve.dispatch", batch=len(group)):
+            plan = sp["plan"]
+            obj0 = self._objective(group[0][1])
+            rule = obj0.rule
+            c_bkt = plans.bucket_len(
+                max(int(q.valid.shape[0]) for _, q, _ in group), 128)
+            k_pad = plans.bucket_len(max(q.k for _, q, _ in group), 4)
+            b_pad = 1
+            while b_pad < len(group):
+                b_pad *= 2
+            b_pad = max(min(b_pad, sp["b_max"]), len(group))
+            pays, vals, ks, lims, padded = [], [], [], [], []
+            for _, q, _ in group:
+                c = int(q.valid.shape[0])
+                ids_p = _pad_axis0(jnp.asarray(q.ids, jnp.int32), c_bkt, -1)
+                pay_p = _pad_axis0(jnp.asarray(q.payloads), c_bkt, 0)
+                val_p = _pad_axis0(jnp.asarray(q.valid).astype(bool), c_bkt,
+                                   False)
+                padded.append((ids_p, pay_p, val_p))
+                pays.append(pay_p)
+                vals.append(val_p)
+                ks.append(q.k)
+                lims.append((obj0.words if rule.is_bitmap else c, c))
+            # inert fill queries: k=0, all-invalid
+            while len(pays) < b_pad:
+                pays.append(jnp.zeros_like(pays[0]))
+                vals.append(jnp.zeros_like(vals[0]))
+                ks.append(0)
+                lims.append((0, 0))
+            fn, ndisp = self._executor(obj0, skey, plan, b_pad,
+                                       pays[0].shape, pays[0].dtype, k_pad)
+            states, bests, gains = fn(jnp.stack(pays), jnp.stack(vals),
+                                      jnp.asarray(ks, jnp.int32),
+                                      jnp.asarray(lims, jnp.int32))
+        with telemetry.span("serve.wait", batch=len(group)):
+            jax.block_until_ready(bests)
         self.metrics.batch_executed(skey, len(group), ndisp,
                                     time.monotonic() - t_exec)
-        out = []
-        for i, (qid, q, t0) in enumerate(group):
-            obj = self._objective(q)
-            st = jax.tree.map(lambda x: x[i], states)
-            mega = (st, bests[i, :q.k], gains[i, :q.k])
-            ids_p, pay_p, val_p = padded[i]
-            sol = greedy_mod._finalize_mega(obj, mega, ids_p, pay_p,
-                                            val_p, q.k)
-            lat = self.metrics.completed(q.tenant, t0, batched=True)
-            out.append(QueryResult(qid, q.tenant, sol, True, len(group),
-                                   skey, lat))
+        with telemetry.span("serve.unpack", batch=len(group)):
+            out = []
+            for i, (qid, q, t0) in enumerate(group):
+                obj = self._objective(q)
+                st = jax.tree.map(lambda x: x[i], states)
+                mega = (st, bests[i, :q.k], gains[i, :q.k])
+                ids_p, pay_p, val_p = padded[i]
+                sol = greedy_mod._finalize_mega(obj, mega, ids_p, pay_p,
+                                                val_p, q.k)
+                lat = self.metrics.completed(q.tenant, t0, batched=True)
+                out.append(QueryResult(qid, q.tenant, sol, True, len(group),
+                                       skey, lat))
         return out
 
     # -- the scheduler loop --------------------------------------------------
@@ -291,10 +297,14 @@ class QueryEngine:
         """Serve every pending query: repeatedly admit the head's
         compatible group and execute it as one batched dispatch (or run
         the head solo when it cannot co-batch). Returns {qid:
-        QueryResult} for everything served."""
+        QueryResult} for everything served. Each batch's host phases are
+        `runtime.telemetry` spans: ``serve.admit``, ``serve.dispatch``
+        (pad, stack, launch), ``serve.wait`` (the device) and
+        ``serve.unpack``."""
         out: Dict[int, QueryResult] = {}
         while self._pending:
-            skey, sp, group = self._admit()
+            with telemetry.span("serve.admit"):
+                skey, sp, group = self._admit()
             if skey is None:
                 results = [self._run_solo(e) for e in group]
             else:

@@ -8,6 +8,11 @@ through the `kernels/ops.py` wrappers (``backend="pallas"``) at real widths
 against a described ``v5e:2x2`` topology, and its compiled text must hold
 the Mosaic custom call.
 
+Each compiled kernel must also keep the instruction name the benchmark's
+trace reducer (`bench/lib/trace.py`) finds it by: `gains_pallas`,
+`pairwise_pallas`, `fused_step_pallas`, `greedy_loop_pallas`,
+`greedy_loop_resident_pallas`, `stream_filter_pallas`.
+
 The topology is described inside a module fixture only: one process at a
 time may load the TPU compiler library, so describing it while modules are
 imported would make parallel test workers collect different tests.
@@ -21,6 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from bench.lib import trace as bench_trace
 from repro.core.objective import make_objective
 from repro.kernels import ops, plans
 from repro.kernels import rules as R
@@ -59,9 +65,14 @@ def spec(topo):
         shape, dtype, sharding=one_chip)
 
 
-def _compiles(fn, *args):
+def _compiles(fn, *args, kernels):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert all(bench_trace.opcode(c) == "custom-call" for c in calls)
+    assert {bench_trace.base_name(c) for c in calls} == set(kernels)
 
 
 def _plan(rule, n, c, d, requested="mega"):
@@ -75,7 +86,7 @@ def test_pairwise(spec, name, dtype):
     rule = R.get(name)
     _compiles(lambda g, c: ops.pairwise_matrix(g, c, rule, backend="pallas",
                                                dtype=dtype),
-              spec((N, D)), spec((C, D)))
+              spec((N, D)), spec((C, D)), kernels=["pairwise_pallas"])
 
 
 @pytest.mark.parametrize("case", ["f32", "int8", "coverage"])
@@ -84,13 +95,14 @@ def test_gains(spec, case, monkeypatch):
         _compiles(lambda r, c, v: ops.gains(None, r, c, v, R.BITS_OR,
                                             backend="pallas"),
                   spec((WORDS,), U32), spec((C, WORDS), U32),
-                  spec((C,), jnp.bool_))
+                  spec((C,), jnp.bool_), kernels=["gains_pallas"])
         return
     if case == "int8":          # per-row-quantized ground, `gscale` operand
         monkeypatch.setenv("REPRO_FUSED_CACHE_DTYPE", "int8")
     _compiles(lambda g, r, c, v: ops.gains(g, r, c, v, R.DOT_MAX,
                                            backend="pallas"),
-              spec((N, D)), spec((N,)), spec((C, D)), spec((C,), jnp.bool_))
+              spec((N, D)), spec((N,)), spec((C, D)), spec((C,), jnp.bool_),
+              kernels=["gains_pallas"])
 
 
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
@@ -105,7 +117,8 @@ def test_fused_step(spec, dtype):
                               plan=plan)
 
     _compiles(step, spec((N, D)), spec((C, D)), spec((N,)),
-              spec((C,), jnp.bool_), spec((), I32))
+              spec((C,), jnp.bool_), spec((), I32),
+              kernels=["pairwise_pallas", "fused_step_pallas"])
 
 
 @pytest.mark.parametrize("name", ["kmedoid", "coverage"])
@@ -118,14 +131,15 @@ def test_greedy_loop_streaming(spec, name):
             ops.pairwise_matrix(None, c, rule, backend="pallas"), row, mask,
             K, rule, backend="pallas", plan=plan),
             spec((8 * C, WORDS), U32), spec((WORDS,), U32),
-            spec((8 * C,), jnp.bool_))
+            spec((8 * C,), jnp.bool_), kernels=["greedy_loop_pallas"])
         return
     plan = _plan(rule, N, C, D)
     assert plan.engine == "mega_stream"
     _compiles(lambda g, c, row, mask: ops.greedy_loop(
         ops.pairwise_matrix(g, c, rule, backend="pallas"), row, mask, K,
         rule, backend="pallas", plan=plan),
-        spec((N, D)), spec((C, D)), spec((N,)), spec((C,), jnp.bool_))
+        spec((N, D)), spec((C, D)), spec((N,)), spec((C,), jnp.bool_),
+        kernels=["pairwise_pallas", "greedy_loop_pallas"])
 
 
 @pytest.mark.parametrize("case", ["float32", "int8", "coverage"])
@@ -136,7 +150,8 @@ def test_greedy_loop_resident(spec, case):
         _compiles(lambda c, row, mask, kq: ops.greedy_loop_resident(
             None, c, row, mask, K, R.BITS_OR, backend="pallas", kq=kq),
             spec((NODE, WORDS), U32), spec((WORDS,), U32),
-            spec((NODE,), jnp.bool_), spec((), I32))
+            spec((NODE,), jnp.bool_), spec((), I32),
+            kernels=["greedy_loop_resident_pallas"])
         return
     rule = R.DOT_MAX
     assert plans.resident_fits(NODE, NODE, D, rule=rule)
@@ -144,7 +159,8 @@ def test_greedy_loop_resident(spec, case):
         g, g, row, mask, K, rule, backend="pallas", cache_dtype=case,
         kq=kq, logical=(ln, lc)),
         spec((NODE, D)), spec((NODE,)), spec((NODE,), jnp.bool_),
-        spec((), I32), spec((), I32), spec((), I32))
+        spec((), I32), spec((), I32), spec((), I32),
+        kernels=["greedy_loop_resident_pallas"])
 
 
 def test_greedy_loop_resident_serving_batch(spec):
@@ -154,7 +170,8 @@ def test_greedy_loop_resident_serving_batch(spec):
     b, c = 4, NODE
     assert plans.serve_plan(obj.rule, c, c, D, backend="pallas") is not None
     _compiles(lambda p, v, ks: obj.megakernel_loop_batched(p, v, ks, 16),
-              spec((b, c, D)), spec((b, c), jnp.bool_), spec((b,), I32))
+              spec((b, c, D)), spec((b, c), jnp.bool_), spec((b,), I32),
+              kernels=["greedy_loop_resident_pallas"])
 
 
 @pytest.mark.parametrize("case", ["facility", "knapsack", "coverage"])
@@ -186,7 +203,8 @@ def test_stream_filter(spec, case):
         rows, row0 = spec((lv, n)), spec((n,))
     _compiles(functools.partial(filt, ground) if ground is None else filt,
               *([] if ground is None else [ground]), batch, rows, row0,
-              spec((b,), jnp.bool_), spec((b,)), spec((lv,)))
+              spec((b,), jnp.bool_), spec((b,)), spec((lv,)),
+              kernels=["stream_filter_pallas"])
 
 
 def test_sharded_leaf_shard_map(topo):
@@ -201,4 +219,5 @@ def test_sharded_leaf_shard_map(topo):
         obj, ids, pay, val, 8, mesh, tile_c=256),
         jax.ShapeDtypeStruct((n,), I32, sharding=rows),
         jax.ShapeDtypeStruct((n, D), F32, sharding=rows),
-        jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=rows))
+        jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=rows),
+        kernels=["gains_pallas"])
