@@ -18,7 +18,10 @@ BUCKETED to the next power-of-two multiple of the tile so repeated calls
 hit the jit/pallas compile cache instead of retracing per shape (DESIGN
 §Perf); fixed axes (features D, the word axis as a lane dim) keep the
 plain next-multiple pad, and constant factors like 1/N are applied
-OUTSIDE the kernels so they never become static compile keys.
+OUTSIDE the kernels so they never become static compile keys. The one
+exception is the per-step bitmap gains: `gains` hands the kernel the
+(C, W) candidate bitmaps as they are, since it runs on every step of a
+scan already compiled for the logical pool shape.
 
 Every wrapper that launches a kernel counts, while it is traced
 (`runtime.telemetry`): one ``launches``, the ``relayout_bytes`` its pads
@@ -55,7 +58,7 @@ from repro.kernels import rules as rules_mod
 from repro.kernels.fused_step import fused_step_pallas
 from repro.kernels.greedy_loop import (greedy_loop_pallas,
                                        greedy_loop_resident_pallas)
-from repro.kernels.pairwise import (TILE_C, TILE_N, TILE_W, gains_pallas,
+from repro.kernels.pairwise import (TILE_C, TILE_N, gains_pallas,
                                     pairwise_pallas)
 from repro.kernels.plans import (EnginePlan, RES_TILE_N,  # noqa: F401
                                  fused_block_n, fused_plan, fused_replicas,
@@ -180,14 +183,21 @@ def gains(ground, row, cands, cand_valid, rule: KernelRule, backend=None):
                          rule)
     c = cands.shape[0]
     if rule.is_bitmap:
-        bits = _relayout(cands, _pad_to(_pad_to(cands, 0, TILE_C), 1,
-                                        TILE_W, bucket=False))
-        r = _relayout(row, _pad_to(_cast_row(row, rule), 0, TILE_W,
-                                   bucket=False))
-        _launch("gains_pallas", cands.shape, bits)
-        raw = gains_pallas(_dummy_ground(), r.reshape(1, -1), bits, rule,
-                           interpret=(b == "interpret"))[:c]
-        return jnp.where(cand_valid, raw, -jnp.inf)
+        # the bitmaps are read in place, in blocks of whole rows: no copy
+        # per step. The step runs inside the greedy's scan, compiled for
+        # the logical pool shape, so a bucketed pad would save no compile
+        w = cands.shape[1]
+        tc = plans.bitmap_block_c(w)
+        if not tc:
+            raise ValueError(
+                f"per-step bitmap gains: no block of 8 candidate rows over "
+                f"{w} words fits the {flags.fused_vmem_mb()} MB VMEM budget")
+        _launch("gains_pallas", cands.shape, cands)
+        raw = gains_pallas(
+            _dummy_ground(), _cast_row(row, rule).reshape(1, -1), cands,
+            rule, interpret=(b == "interpret"), block_c=tc,
+            vmem_limit_bytes=plans.vmem_limit(plans.bitmap_gains_need(tc, w)))
+        return jnp.where(cand_valid, raw[:c], -jnp.inf)
     # feature axis never drifts between calls → plain 128-multiple pad
     g = _relayout(ground, _pad_to(_pad_to(ground, 0, TILE_N), 1, 128,
                                   bucket=False))

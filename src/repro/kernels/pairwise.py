@@ -16,12 +16,14 @@ Two entry points, both driven by a `KernelRule` (kernels/rules.py):
     per-objective kernels (kmedoid_gains / facility_gains /
     coverage_gains) that predated the objective protocol: the rule picks
     the matrix op and the gain part, so feature rules tile
-    (TC candidates × TN ground rows) with an MXU matmul per block, and
-    bitmap rules tile (TC × TW words) with AND-NOT + popcount — partial
-    sums accumulate over the inner grid dimension in f32 either way.
+    (TC candidates × TN ground rows) with an MXU matmul per block,
+    partial sums accumulating over the inner grid dimension in f32, and
+    bitmap rules read the (C, W) candidate bitmaps in place, in blocks of
+    whole rows (TC × W words, TC from plans.bitmap_block_c) with AND-NOT
+    + popcount.
 
 VMEM per block: TN·D·4 + TC·D·4 + TN·TC·4 ≈ 1.9 MB at D=768 (feature
-rules) / TC·TW·4 ≈ 0.25 MB (bitmap rules).
+rules) / plans.bitmap_gains_need (bitmap rules: ≈ 7.9 MB at W=515).
 """
 from __future__ import annotations
 
@@ -38,8 +40,7 @@ from repro.kernels.rules import KernelRule, pairwise_block  # noqa: F401
 F32 = jnp.float32
 
 TILE_N = 256        # ground rows per block (feature rules)
-TILE_C = 128        # candidates per block
-TILE_W = 512        # universe words per block (bitmap rules)
+TILE_C = 128        # candidates per block (feature rules)
 
 
 def _kernel(ground_ref, cands_ref, out_ref, *, mode: str):
@@ -107,10 +108,22 @@ def _gains_kernel_quant(ground_ref, gscale_ref, row_ref, cands_ref,
     out_ref[...] += R.block_gains(g, cands_ref[...], row_ref[...], rule)
 
 
-@functools.partial(jax.jit, static_argnames=("rule", "interpret"))
+def _bitmap_gains_kernel(ground_ref, row_ref, cands_ref, out_ref, *,
+                         rule: KernelRule, tc: int):
+    # one (TC, W) block covers every word of its candidates: no
+    # accumulation; a block under 128 rows fills the front of its
+    # lane-dense output block
+    del ground_ref
+    out_ref[:, :tc] = R.block_gains(None, cands_ref[...], row_ref[...],
+                                    rule)
+
+
+@functools.partial(jax.jit, static_argnames=("rule", "interpret", "block_c",
+                                             "vmem_limit_bytes"))
 def gains_pallas(ground: jax.Array, row: jax.Array, cands: jax.Array,
                  rule: KernelRule, interpret: bool = False,
-                 gscale=None) -> jax.Array:
+                 gscale=None, block_c: int = 0,
+                 vmem_limit_bytes: int = 0) -> jax.Array:
     """RAW marginal-gain sums (C,) f32 for ANY registered rule (callers
     normalize outside the kernel so the logical N never becomes a static
     compile key).
@@ -124,39 +137,53 @@ def gains_pallas(ground: jax.Array, row: jax.Array, cands: jax.Array,
     f32 on-chip — quartering the dominant per-step HBM read.
 
     Bitmap rules: ground is an ignored (8, 128) placeholder, row (1, W)
-    covered words, cands (C, W) candidate bitmaps; grid (C/TC, W/TW).
-    Zero-padded bits/words contribute zero gain.
+    covered words, cands (C, W) candidate bitmaps of any shape, read in
+    place: each block is `block_c` candidate rows (all of them when C is
+    smaller) over all W words, grid (⌈C/TC⌉,). The last block may run
+    past C; its rows only reach entries past C, which the caller cuts.
+    `vmem_limit_bytes`: Mosaic's scoped-VMEM limit (plans.vmem_limit).
     """
     c = cands.shape[0]
-    kernel = _gains_kernel
     if rule.is_bitmap:
         w = cands.shape[1]
-        assert c % TILE_C == 0 and w % TILE_W == 0, (c, w)
-        assert row.shape == (1, w)
-        grid = (c // TILE_C, w // TILE_W)
-        in_specs = [
-            pl.BlockSpec(ground.shape, lambda ci, ni: (0, 0)),
-            pl.BlockSpec((1, TILE_W), lambda ci, ni: (0, ni)),
-            pl.BlockSpec((TILE_C, TILE_W), lambda ci, ni: (ci, ni)),
-        ]
-        operands = [ground, row, cands]
-    else:
-        n, d = ground.shape
-        assert n % TILE_N == 0 and c % TILE_C == 0 and d % 128 == 0
-        assert row.shape == (1, n) and cands.shape[1] == d
-        grid = (c // TILE_C, n // TILE_N)
-        in_specs = [
-            pl.BlockSpec((TILE_N, d), lambda ci, ni: (ni, 0)),
-            pl.BlockSpec((1, TILE_N), lambda ci, ni: (0, ni)),
-            pl.BlockSpec((TILE_C, d), lambda ci, ni: (ci, 0)),
-        ]
-        operands = [ground, row, cands]
-        if gscale is not None:
-            assert gscale.shape == (1, n), (gscale.shape, n)
-            in_specs.insert(1, pl.BlockSpec((1, TILE_N),
-                                            lambda ci, ni: (0, ni)))
-            operands.insert(1, gscale)
-            kernel = _gains_kernel_quant
+        assert row.shape == (1, w) and block_c > 0, (row.shape, block_c)
+        tc = min(block_c, c)
+        lanes = -(-tc // 128) * 128
+        blocks = pl.cdiv(c, tc)
+        out = pl.pallas_call(
+            functools.partial(_bitmap_gains_kernel, rule=rule, tc=tc),
+            name="gains_pallas",
+            grid=(blocks,),
+            in_specs=[
+                pl.BlockSpec(ground.shape, lambda ci: (0, 0)),
+                pl.BlockSpec((1, w), lambda ci: (0, 0)),
+                pl.BlockSpec((tc, w), lambda ci: (ci, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, lanes), lambda ci: (0, ci)),
+            out_shape=jax.ShapeDtypeStruct((1, blocks * lanes), F32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",),
+                vmem_limit_bytes=vmem_limit_bytes or None),
+            interpret=interpret,
+        )(ground, row, cands)
+        return out.reshape(blocks, lanes)[:, :tc].reshape(-1)
+    kernel = _gains_kernel
+    n, d = ground.shape
+    assert n % TILE_N == 0 and c % TILE_C == 0 and d % 128 == 0
+    assert row.shape == (1, n) and cands.shape[1] == d
+    grid = (c // TILE_C, n // TILE_N)
+    in_specs = [
+        pl.BlockSpec((TILE_N, d), lambda ci, ni: (ni, 0)),
+        pl.BlockSpec((1, TILE_N), lambda ci, ni: (0, ni)),
+        pl.BlockSpec((TILE_C, d), lambda ci, ni: (ci, 0)),
+    ]
+    operands = [ground, row, cands]
+    if gscale is not None:
+        assert gscale.shape == (1, n), (gscale.shape, n)
+        in_specs.insert(1, pl.BlockSpec((1, TILE_N),
+                                        lambda ci, ni: (0, ni)))
+        operands.insert(1, gscale)
+        kernel = _gains_kernel_quant
     out = pl.pallas_call(
         functools.partial(kernel, rule=rule),
         name="gains_pallas",
@@ -165,7 +192,7 @@ def gains_pallas(ground: jax.Array, row: jax.Array, cands: jax.Array,
         out_specs=pl.BlockSpec((1, TILE_C), lambda ci, ni: (0, ci)),
         out_shape=jax.ShapeDtypeStruct((1, c), F32),
         # candidate blocks are independent (parallel); the inner
-        # ground/word dim accumulates into the revisited output block
+        # ground dim accumulates into the revisited output block
         # (arbitrary), which Mosaic can still software-pipeline
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),

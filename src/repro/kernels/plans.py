@@ -176,6 +176,31 @@ def loop_block_n(n_pad: int, c_pad: int, itemsize: int = 4) -> int:
                          lambda bn: loop_need(bn, n_pad, c_pad, itemsize))
 
 
+# candidate rows per block of the per-step bitmap gains kernel, at most
+# (a v5e reading, PERF §6)
+BITMAP_BLOCK_C_MAX = 1024
+
+
+def bitmap_gains_need(tc: int, w: int) -> int:
+    """VMEM bytes of one per-step bitmap-gains grid cell: the (TC, W)
+    uint32 candidate block, double-buffered, and the kernel's (TC, W) f32
+    popcount temporary, each row padded to whole 128-lane vregs."""
+    lanes = -(-w // 128) * 128
+    return 3 * tc * lanes * 4
+
+
+def bitmap_block_c(w: int) -> int:
+    """Candidate rows per block for the per-step bitmap gains over all
+    `w` words of a row: the largest multiple of 128 up to
+    `BITMAP_BLOCK_C_MAX`, else of 64, 32, 16 or 8 rows, whose
+    `bitmap_gains_need` fits the fused VMEM budget; 0 if none fits."""
+    vmem = flags.fused_vmem_mb() * 2 ** 20
+    for tc in [*range(BITMAP_BLOCK_C_MAX, 0, -128), 64, 32, 16, 8]:
+        if bitmap_gains_need(tc, w) <= vmem:
+            return tc
+    return 0
+
+
 # Mosaic's scoped-VMEM limit for a kernel that names none (v5e: 16 MiB)
 _SCOPED_VMEM_DEFAULT = 16 * 2 ** 20
 

@@ -110,7 +110,10 @@ def test_driver_launches_match_the_jaxpr(monkeypatch, name, want, engine,
     assert rec["engine"] == want and rec["k"] == K
     assert rec["logical"] == ([4, N] if name == "coverage" else [N, N])
     assert rec["launches"] == ops.count_pallas_dispatches(jx.jaxpr) > 0
-    assert rec["relayout_bytes"] > 0 and rec["streams"]
+    # every engine pads what it streams but the per-step bitmap gains,
+    # which read the candidate bitmaps in place
+    in_place = want == "step" and name == "coverage"
+    assert rec["streams"] and (rec["relayout_bytes"] == 0) == in_place
     text = jax.jit(f).lower(ids, pay, valid).as_text()
     assert "callback" not in text.lower()
 
@@ -137,9 +140,10 @@ def test_driver_launches_per_lane(lanes):
         ops.count_pallas_dispatches(jx.jaxpr)
 
 
-def test_step_engine_counts_the_per_step_pad():
-    """Bitmap per-step gains: every step copies the candidate bitmaps
-    into the (bucketed rows, 512-word) operand the kernel streams."""
+def test_step_engine_streams_the_bitmaps_in_place():
+    """Bitmap per-step gains: the kernel streams the candidate bitmaps as
+    they are, so the streamed operand's padded shape is its logical shape
+    and no step writes a copy."""
     obj = make_objective("coverage", universe=128, backend="interpret")
     jax.make_jaxpr(lambda i, p, v: greedy(obj, i, p, v, K, engine="step"))(
         jnp.arange(N, dtype=jnp.int32), _pool("coverage"),
@@ -147,10 +151,9 @@ def test_step_engine_counts_the_per_step_pad():
     rec = telemetry.records("greedy")[-1]
     (s,) = rec["streams"]
     assert s == {"span": s["span"], "kernel": "gains_pallas",
-                 "logical": [N, 4], "padded": [512, 512],
-                 "bytes": 512 * 512 * 4, "repeat": K}
-    row = 512 * 4                    # the covered-words row, to 512 words
-    assert rec["relayout_bytes"] == K * (512 * 512 * 4 + row)
+                 "logical": [N, 4], "padded": [N, 4],
+                 "bytes": N * 4 * 4, "repeat": K}
+    assert rec["relayout_bytes"] == 0
 
 
 def test_ref_backend_launches_nothing():

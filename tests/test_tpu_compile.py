@@ -18,6 +18,7 @@ time may load the TPU compiler library, so describing it while modules are
 imported would make parallel test workers collect different tests.
 """
 import functools
+import re
 
 import pytest
 
@@ -27,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from bench.lib import trace as bench_trace
+from repro.core.greedy import greedy
 from repro.core.objective import make_objective
 from repro.kernels import ops, plans
 from repro.kernels import rules as R
@@ -89,13 +91,20 @@ def test_pairwise(spec, name, dtype):
               spec((N, D)), spec((C, D)), kernels=["pairwise_pallas"])
 
 
-@pytest.mark.parametrize("case", ["f32", "int8", "coverage"])
+# per-step bitmap gains read (C, W) in place: FIMI retail's and kosarak's
+# transaction counts over their item words, off every alignment
+BITMAPS = {"coverage": (C, WORDS), "retail": (88_162, 515),
+           "kosarak": (990_002, 1_290)}
+
+
+@pytest.mark.parametrize("case", ["f32", "int8", *BITMAPS])
 def test_gains(spec, case, monkeypatch):
-    if case == "coverage":
-        _compiles(lambda r, c, v: ops.gains(None, r, c, v, R.BITS_OR,
+    if case in BITMAPS:
+        c, words = BITMAPS[case]
+        _compiles(lambda r, b, v: ops.gains(None, r, b, v, R.BITS_OR,
                                             backend="pallas"),
-                  spec((WORDS,), U32), spec((C, WORDS), U32),
-                  spec((C,), jnp.bool_), kernels=["gains_pallas"])
+                  spec((words,), U32), spec((c, words), U32),
+                  spec((c,), jnp.bool_), kernels=["gains_pallas"])
         return
     if case == "int8":          # per-row-quantized ground, `gscale` operand
         monkeypatch.setenv("REPRO_FUSED_CACHE_DTYPE", "int8")
@@ -103,6 +112,47 @@ def test_gains(spec, case, monkeypatch):
                                            backend="pallas"),
               spec((N, D)), spec((N,)), spec((C, D)), spec((C,), jnp.bool_),
               kernels=["gains_pallas"])
+
+
+def _loop_bodies(text):
+    """The compiled text of every computation a while loop's body reaches
+    (fusions, reductions and nested loops included)."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    seen, todo = set(), re.findall(r"body=%([\w.\-]+)", text)
+    while todo:
+        n = todo.pop()
+        if n in comps and n not in seen:
+            seen.add(n)
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                "\n".join(comps[n]))
+    return "\n".join(line for n in seen for line in comps[n])
+
+
+def test_step_engine_greedy_streams_retail_in_place(spec):
+    """The whole k-cover greedy at FIMI retail's shape takes the per-step
+    engine; its loop body copies nothing: no pad, and no bitmap array of
+    power-of-two rows (131,072) or whole 512-word tiles (1,024 words)."""
+    (n, words), k = BITMAPS["retail"], 64
+    obj = make_objective("coverage", universe=16_470, backend="pallas")
+    fn = lambda i, p, v: greedy(obj, i, p, v, k, engine="auto")
+    text = jax.jit(fn).lower(spec((n,), I32), spec((n, words), U32),
+                             spec((n,), jnp.bool_)).compile().as_text()
+    assert plans.select_engine(obj.rule, words, n, None,
+                               backend="pallas").engine == "step"
+    body = _loop_bodies(text)
+    assert "gains_pallas" in body and f"u32[{n},{words}]" in body
+    assert not re.search(r"\bpad\(", body)
+    shapes = re.findall(r"u32\[(\d+),(\d+)\]", body)
+    assert shapes and not [s for s in shapes
+                           if s[0] == "131072" or s[1] == "1024"]
 
 
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
