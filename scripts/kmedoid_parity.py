@@ -2,6 +2,7 @@
 """Where k-medoid selections of the kernels and the reference part ways.
 
     PYTHONPATH=src python scripts/kmedoid_parity.py            # on a TPU
+    PYTHONPATH=src python scripts/kmedoid_parity.py --part noise --dim 12288
     JAX_PLATFORMS=cpu REPRO_KERNEL_BACKEND=interpret \
         PYTHONPATH=src python scripts/kmedoid_parity.py --small
 
@@ -177,11 +178,15 @@ def main(argv=None) -> int:
     ap.add_argument("--small", action="store_true",
                     help="CPU-sized shapes (n=2048, d=64, k=50)")
     ap.add_argument("--backend", default=None)
+    ap.add_argument("--dim", type=int, default=0,
+                    help="feature width (default: the config's, or 64 "
+                         "with --small); Tiny ImageNet's pixels are 12288")
     args = ap.parse_args(argv)
     backend = resolve_backend(args.backend)
     cfg = paper_kmedoid.CONFIG
     n, d, k = (2048, 64, 50) if args.small else (cfg.n, cfg.feature_dim,
                                                   cfg.k)
+    d = args.dim or d
     print(f"kmedoid_parity: {jax.devices()[0].device_kind}, kernel "
           f"backend {backend}", flush=True)
     if args.part in ("noise", "all"):

@@ -140,6 +140,7 @@ def greedy(objective, ids: jax.Array, payloads: jax.Array, valid: jax.Array,
     streams = [r for r in telemetry.records("stream") if r["span"] == sp.id]
     rec.update(launches=int(sp.counts.get("launches", 0)),
                relayout_bytes=int(sp.counts.get("relayout_bytes", 0)),
+               build_bytes=int(sp.counts.get("build_bytes", 0)),
                streams=streams)
     return sol
 

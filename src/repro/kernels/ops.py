@@ -26,8 +26,10 @@ scan already compiled for the logical pool shape.
 Every wrapper that launches a kernel counts, while it is traced
 (`runtime.telemetry`): one ``launches``, the ``relayout_bytes`` its pads
 write to reshape operands, and a ``stream`` record of the operand the
-kernel streams (logical shape, padded shape, bytes). The greedy driver
-gathers them into its per-invocation record.
+kernel streams (logical shape, padded shape, bytes); the pairwise build
+also counts the HBM bytes its planned tiling moves (``build_bytes``,
+plans.feature_bytes). The greedy driver gathers them into its
+per-invocation record.
 
 Engine planning (memory gates, tier selection, backend resolution) lives
 in kernels/plans.py; the legacy names (`fused_plan`, `stream_plan`,
@@ -58,9 +60,9 @@ from repro.kernels import rules as rules_mod
 from repro.kernels.fused_step import fused_step_pallas
 from repro.kernels.greedy_loop import (greedy_loop_pallas,
                                        greedy_loop_resident_pallas)
-from repro.kernels.pairwise import (TILE_C, TILE_N, gains_pallas,
-                                    pairwise_pallas)
-from repro.kernels.plans import (EnginePlan, RES_TILE_N,  # noqa: F401
+from repro.kernels.pairwise import gains_pallas, pairwise_pallas
+from repro.kernels.plans import (EnginePlan, FEATURE_TILE_C,  # noqa: F401
+                                 FEATURE_TILE_N, RES_TILE_N,
                                  fused_block_n, fused_plan, fused_replicas,
                                  loop_block_n, resident_fits,
                                  resolve_backend, select_engine, stream_plan)
@@ -198,19 +200,25 @@ def gains(ground, row, cands, cand_valid, rule: KernelRule, backend=None):
             rule, interpret=(b == "interpret"), block_c=tc,
             vmem_limit_bytes=plans.vmem_limit(plans.bitmap_gains_need(tc, w)))
         return jnp.where(cand_valid, raw[:c], -jnp.inf)
-    # feature axis never drifts between calls → plain 128-multiple pad
-    g = _relayout(ground, _pad_to(_pad_to(ground, 0, TILE_N), 1, 128,
-                                  bucket=False))
-    r = _relayout(row, _pad_to(_cast_row(row, rule), 0, TILE_N,
+    n_pad = plans.bucket_len(ground.shape[0], FEATURE_TILE_N)
+    c_pad = plans.bucket_len(c, FEATURE_TILE_C)
+    tiles = plans.feature_tiles("gains", n_pad, c_pad, ground.shape[1],
+                                itemsize=1 if quant else 4)
+    # feature axis never drifts between calls → plain pad to whole tiles
+    g = _relayout(ground, _pad_to(_pad_to(ground, 0, FEATURE_TILE_N), 1,
+                                  tiles.td, bucket=False))
+    r = _relayout(row, _pad_to(_cast_row(row, rule), 0, FEATURE_TILE_N,
                                value=_row_pad_value(rule)))  # ⇒ zero gain
-    cd = _relayout(cands, _pad_to(_pad_to(cands, 0, TILE_C), 1, 128,
-                                  bucket=False))
+    cd = _relayout(cands, _pad_to(_pad_to(cands, 0, FEATURE_TILE_C), 1,
+                                  tiles.td, bucket=False))
     gscale = None
     if quant:
         g, gscale, _ = _quantized_ground(g.astype(F32))
     _launch("gains_pallas", cands.shape, cd)
     raw = gains_pallas(g, r.reshape(1, -1), cd, rule,
-                       interpret=(b == "interpret"), gscale=gscale)[:c]
+                       interpret=(b == "interpret"), gscale=gscale,
+                       tiles=(tiles.tn, tiles.tc, tiles.td),
+                       vmem_limit_bytes=tiles.limit)[:c]
     return jnp.where(cand_valid, raw, -jnp.inf)
 
 
@@ -248,11 +256,23 @@ def pairwise_matrix(ground, cands, rule: KernelRule, backend=None,
         if dtype == "int8":
             return QuantMatrix(*rules_mod.quantize_rows(m))
         return m if dtype == "float32" else m.astype(jnp.dtype(dtype))
-    g = _relayout(ground, _pad_to(_pad_to(ground, 0, 256), 1, 128,
-                                  bucket=False))
-    cd = _relayout(cands, _pad_to(_pad_to(cands, 0, 128), 1, 128,
-                                  bucket=False))
+    # the int8 cache is quantized from the f32 kernel output
+    out_dtype = "float32" if dtype == "int8" else dtype
+    n_pad = plans.bucket_len(ground.shape[0], FEATURE_TILE_N)
+    c_pad = plans.bucket_len(cands.shape[0], FEATURE_TILE_C)
+    tiles = plans.feature_tiles("pairwise", n_pad, c_pad, ground.shape[1],
+                                itemsize=ground.dtype.itemsize,
+                                out_itemsize=jnp.dtype(out_dtype).itemsize)
+    g = _relayout(ground, _pad_to(_pad_to(ground, 0, FEATURE_TILE_N), 1,
+                                  tiles.td, bucket=False))
+    cd = _relayout(cands, _pad_to(_pad_to(cands, 0, FEATURE_TILE_C), 1,
+                                  tiles.td, bucket=False))
     _launch("pairwise_pallas", cands.shape, cd)
+    telemetry.count("build_bytes", tiles.hbm_bytes)
+    m = pairwise_pallas(g, cd, mode=rule.pairwise, out_dtype=out_dtype,
+                        interpret=(b == "interpret"),
+                        tiles=(tiles.tn, tiles.tc, tiles.td),
+                        vmem_limit_bytes=tiles.limit)
     if dtype == "int8":
         # quantization is a cheap jnp epilogue on the f32 kernel output
         # (one pass, fuses under jit) — zero extra dispatches. Pad
@@ -260,15 +280,11 @@ def pairwise_matrix(ground, cands, rule: KernelRule, backend=None,
         # logical columns, or the padded and the ref (logical) caches
         # would round differently and int8 selections could drift
         # between backends
-        m = pairwise_pallas(g, cd, mode=rule.pairwise,
-                            out_dtype="float32",
-                            interpret=(b == "interpret"))
         logical = ((jnp.arange(m.shape[0]) < ground.shape[0])[:, None]
                    & (jnp.arange(m.shape[1]) < cands.shape[0])[None, :])
         return QuantMatrix(*rules_mod.quantize_rows(
             jnp.where(logical, m, 0.0)))
-    return pairwise_pallas(g, cd, mode=rule.pairwise, out_dtype=dtype,
-                           interpret=(b == "interpret"))
+    return m
 
 
 @jax.named_scope("ops.fused_step")

@@ -8,22 +8,30 @@ Two entry points, both driven by a `KernelRule` (kernels/rules.py):
     invocation for the feature rules ('dist' k-medoid, 'dot'
     facility/satcover). Bitmap rules never reach it — their matrix is a
     transpose of the candidate payloads, built by ops.py without a
-    dispatch. Grid: (N/TN, C/TC); each block is one MXU matmul over the
-    full feature dim.
+    dispatch. Grid: (N/TN, C/TC, D/TD), the features innermost: each
+    step adds one (TN, TD) × (TC, TD) MXU product to an f32 (TN, TC)
+    cross-term accumulator (and, for 'dist', the blocks' squared norms to
+    (TN, 1) and (1, TC) accumulators), and the last feature tile finishes
+    the block with `rules.finish_block`.
 
   * ``gains_pallas`` — the per-step (uncached) marginal-gains pass, the
     paper's memory-capped regime. This single kernel replaces the three
     per-objective kernels (kmedoid_gains / facility_gains /
     coverage_gains) that predated the objective protocol: the rule picks
-    the matrix op and the gain part, so feature rules tile
-    (TC candidates × TN ground rows) with an MXU matmul per block,
-    partial sums accumulating over the inner grid dimension in f32, and
-    bitmap rules read the (C, W) candidate bitmaps in place, in blocks of
-    whole rows (TC × W words, TC from plans.bitmap_block_c) with AND-NOT
-    + popcount.
+    the matrix op and the gain part, so feature rules tile (TC
+    candidates × TN ground rows × TD features), accumulating the matrix
+    block over the features as the pairwise build does and folding its
+    gains into the revisited (1, TC) output on the last feature tile,
+    and bitmap rules read the (C, W) candidate bitmaps in place, in
+    blocks of whole rows (TC × W words, TC from plans.bitmap_block_c)
+    with AND-NOT + popcount.
 
-VMEM per block: TN·D·4 + TC·D·4 + TN·TC·4 ≈ 1.9 MB at D=768 (feature
-rules) / plans.bitmap_gains_need (bitmap rules: ≈ 7.9 MB at W=515).
+Tiles come from `plans.feature_tiles` (feature rules), which picks the
+tiling that moves the fewest HBM bytes under the VMEM budget, with its
+VMEM model `plans.feature_need` (≈ 7.6 MB at TN = TC = 512, TD = 384);
+bitmap blocks from `plans.bitmap_gains_need` (≈ 7.9 MB at W = 515).
+Where the features fit one tile, each kernel computes exactly the
+full-feature product it did before the contraction axis was tiled.
 """
 from __future__ import annotations
 
@@ -35,77 +43,118 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import rules as R
-from repro.kernels.rules import KernelRule, pairwise_block  # noqa: F401
+from repro.kernels.rules import KernelRule
 
 F32 = jnp.float32
 
-TILE_N = 256        # ground rows per block (feature rules)
-TILE_C = 128        # candidates per block (feature rules)
+
+def _add_tile(ref, value, first) -> None:
+    """Sum `value` into the accumulator `ref` over the feature tiles: the
+    first tile stores it, so one tile gives the value itself."""
+    @pl.when(first)
+    def _store():
+        ref[...] = value
+
+    @pl.when(jnp.logical_not(first))
+    def _add():
+        ref[...] += value
 
 
-def _kernel(ground_ref, cands_ref, out_ref, *, mode: str):
-    g = ground_ref[...].astype(F32)                    # (TN, D)
-    c = cands_ref[...].astype(F32)                     # (TC, D)
-    out_ref[...] = pairwise_block(g, c, mode).astype(out_ref.dtype)
+def _accumulate(g, c, acc_ref, gn_ref, cn_ref, first, mode: str) -> None:
+    """One feature tile of a (TN, TC) matrix block: its cross term and,
+    for 'dist', the squared norms, summed in f32 scratch."""
+    _add_tile(acc_ref, R.cross_block(g, c), first)
+    if mode == "dist":
+        gn, cn = R.sq_norms(g, c)
+        _add_tile(gn_ref, gn, first)
+        _add_tile(cn_ref, cn, first)
+
+
+def _finish(acc_ref, gn_ref, cn_ref, mode: str):
+    return R.finish_block(acc_ref[...], gn_ref[...], cn_ref[...], mode)
+
+
+def _scratch(tn: int, tc: int):
+    return [pltpu.VMEM((tn, tc), F32), pltpu.VMEM((tn, 1), F32),
+            pltpu.VMEM((1, tc), F32)]
+
+
+def _kernel(ground_ref, cands_ref, out_ref, acc_ref, gn_ref, cn_ref, *,
+            mode: str):
+    di = pl.program_id(2)
+    last = pl.num_programs(2) - 1
+    _accumulate(ground_ref[...].astype(F32), cands_ref[...].astype(F32),
+                acc_ref, gn_ref, cn_ref, di == 0, mode)
+
+    @pl.when(di == last)
+    def _write():
+        out_ref[...] = _finish(acc_ref, gn_ref, cn_ref,
+                               mode).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("mode", "out_dtype", "interpret"))
+                   static_argnames=("mode", "out_dtype", "interpret", "tiles",
+                                    "vmem_limit_bytes"))
 def pairwise_pallas(ground: jax.Array, cands: jax.Array, mode: str = "dist",
-                    out_dtype: str = "float32",
-                    interpret: bool = False) -> jax.Array:
+                    out_dtype: str = "float32", interpret: bool = False, *,
+                    tiles: tuple, vmem_limit_bytes: int = 0) -> jax.Array:
     """ground: (N, D), cands: (C, D) → (N, C) matrix in ``out_dtype``
     (compute always f32; 'bfloat16' halves the cache's HBM footprint).
 
-    N, C, D must be padded to tile multiples by the ops.py wrapper (zero
-    padding: pad rows/cols produce ‖·‖ / 0 entries that callers mask).
+    ``tiles``: (TN, TC, TD) blocks (plans.feature_tiles); N, C and D must
+    be padded to multiples of them by the ops.py wrapper (zero padding:
+    pad rows/cols produce ‖·‖ / 0 entries that callers mask, pad features
+    add nothing). `vmem_limit_bytes`: Mosaic's scoped-VMEM limit
+    (plans.vmem_limit).
     """
+    tn, tc, td = tiles
     n, d = ground.shape
     c = cands.shape[0]
-    assert n % TILE_N == 0 and c % TILE_C == 0 and d % 128 == 0, (n, c, d)
-    grid = (n // TILE_N, c // TILE_C)
+    assert n % tn == 0 and c % tc == 0 and d % td == 0 and td % 128 == 0, \
+        (n, c, d, tiles)
     return pl.pallas_call(
         functools.partial(_kernel, mode=mode),
         name="pairwise_pallas",
-        grid=grid,
+        grid=(n // tn, c // tc, d // td),
         in_specs=[
-            pl.BlockSpec((TILE_N, d), lambda ni, ci: (ni, 0)),
-            pl.BlockSpec((TILE_C, d), lambda ni, ci: (ci, 0)),
+            pl.BlockSpec((tn, td), lambda ni, ci, di: (ni, di)),
+            pl.BlockSpec((tc, td), lambda ni, ci, di: (ci, di)),
         ],
-        out_specs=pl.BlockSpec((TILE_N, TILE_C), lambda ni, ci: (ni, ci)),
+        out_specs=pl.BlockSpec((tn, tc), lambda ni, ci, di: (ni, ci)),
         out_shape=jax.ShapeDtypeStruct((n, c), jnp.dtype(out_dtype)),
-        # every block is independent — Mosaic may pipeline/reorder both dims
+        scratch_shapes=_scratch(tn, tc),
+        # matrix blocks are independent — Mosaic may pipeline/reorder
+        # both; the innermost feature axis accumulates in scratch
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes or None),
         interpret=interpret,
     )(ground, cands)
 
 
-def _gains_kernel(ground_ref, row_ref, cands_ref, out_ref, *,
-                  rule: KernelRule):
-    ni = pl.program_id(1)
+def _gains_kernel(*refs, rule: KernelRule, quant: bool):
+    if quant:
+        # int8 rescale-accumulate: the (TN, TD) ground block is 1-byte
+        # storage; rescale it against the (1, TN) per-row scales on-chip,
+        # then the identical f32 algebra
+        ground_ref, gscale_ref, row_ref, cands_ref, out_ref, *acc = refs
+        g = R.dequant(ground_ref[...], gscale_ref[...])
+    else:
+        ground_ref, row_ref, cands_ref, out_ref, *acc = refs
+        g = ground_ref[...].astype(F32)
+    ni, di = pl.program_id(1), pl.program_id(2)
+    last = pl.num_programs(2) - 1
 
-    @pl.when(ni == 0)
+    @pl.when((ni == 0) & (di == 0))
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += R.block_gains(ground_ref[...], cands_ref[...],
-                                  row_ref[...], rule)
+    _accumulate(g, cands_ref[...].astype(F32), *acc, di == 0, rule.pairwise)
 
-
-def _gains_kernel_quant(ground_ref, gscale_ref, row_ref, cands_ref,
-                        out_ref, *, rule: KernelRule):
-    ni = pl.program_id(1)
-
-    @pl.when(ni == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    # int8 rescale-accumulate: the (TN, D) ground block is 1-byte
-    # storage; rescale it against the (1, TN) per-row scales on-chip,
-    # then the identical f32 gain algebra
-    g = R.dequant(ground_ref[...], gscale_ref[...])
-    out_ref[...] += R.block_gains(g, cands_ref[...], row_ref[...], rule)
+    @pl.when(di == last)
+    def _fold():
+        m = _finish(*acc, rule.pairwise)                # (TN, TC)
+        out_ref[...] += R.partial_gains(row_ref[...], m, rule)
 
 
 def _bitmap_gains_kernel(ground_ref, row_ref, cands_ref, out_ref, *,
@@ -114,27 +163,30 @@ def _bitmap_gains_kernel(ground_ref, row_ref, cands_ref, out_ref, *,
     # accumulation; a block under 128 rows fills the front of its
     # lane-dense output block
     del ground_ref
-    out_ref[:, :tc] = R.block_gains(None, cands_ref[...], row_ref[...],
-                                    rule)
+    out_ref[:, :tc] = R.bitmap_gains(cands_ref[...], row_ref[...], rule)
 
 
 @functools.partial(jax.jit, static_argnames=("rule", "interpret", "block_c",
-                                             "vmem_limit_bytes"))
+                                             "tiles", "vmem_limit_bytes"))
 def gains_pallas(ground: jax.Array, row: jax.Array, cands: jax.Array,
                  rule: KernelRule, interpret: bool = False,
-                 gscale=None, block_c: int = 0,
+                 gscale=None, block_c: int = 0, tiles: tuple = (),
                  vmem_limit_bytes: int = 0) -> jax.Array:
     """RAW marginal-gain sums (C,) f32 for ANY registered rule (callers
     normalize outside the kernel so the logical N never becomes a static
     compile key).
 
     Feature rules: ground (N, D), row (1, N) state (mind/curmax/cursum),
-    cands (C, D); grid (C/TC, N/TN), N innermost (output-block revisiting
-    accumulation). Padded ground rows must carry row = rule.row_pad (⇒
-    zero contribution); the ops.py wrapper guarantees this. When
-    `gscale` (1, N) f32 is given, `ground` is int8 per-row-quantized
-    storage (rules.quantize_rows) and the kernel rescales each block to
-    f32 on-chip — quartering the dominant per-step HBM read.
+    cands (C, D), in (TN, TC, TD) ``tiles`` (plans.feature_tiles) that
+    divide the padded shapes; grid (C/TC, N/TN, D/TD), the features
+    innermost: each (candidate block, ground block) sums its matrix block
+    over the feature tiles and, on the last, adds its gains to the
+    revisited output block. Padded ground rows must carry row =
+    rule.row_pad (⇒ zero contribution), padded features are zero; the
+    ops.py wrapper guarantees both. When `gscale` (1, N) f32 is given,
+    `ground` is int8 per-row-quantized storage (rules.quantize_rows) and
+    the kernel rescales each block to f32 on-chip — quartering the
+    dominant per-step HBM read.
 
     Bitmap rules: ground is an ignored (8, 128) placeholder, row (1, W)
     covered words, cands (C, W) candidate bitmaps of any shape, read in
@@ -167,35 +219,37 @@ def gains_pallas(ground: jax.Array, row: jax.Array, cands: jax.Array,
             interpret=interpret,
         )(ground, row, cands)
         return out.reshape(blocks, lanes)[:, :tc].reshape(-1)
-    kernel = _gains_kernel
+    tn, tc, td = tiles
     n, d = ground.shape
-    assert n % TILE_N == 0 and c % TILE_C == 0 and d % 128 == 0
+    assert n % tn == 0 and c % tc == 0 and d % td == 0 and td % 128 == 0, \
+        (n, c, d, tiles)
     assert row.shape == (1, n) and cands.shape[1] == d
-    grid = (c // TILE_C, n // TILE_N)
     in_specs = [
-        pl.BlockSpec((TILE_N, d), lambda ci, ni: (ni, 0)),
-        pl.BlockSpec((1, TILE_N), lambda ci, ni: (0, ni)),
-        pl.BlockSpec((TILE_C, d), lambda ci, ni: (ci, 0)),
+        pl.BlockSpec((tn, td), lambda ci, ni, di: (ni, di)),
+        pl.BlockSpec((1, tn), lambda ci, ni, di: (0, ni)),
+        pl.BlockSpec((tc, td), lambda ci, ni, di: (ci, di)),
     ]
     operands = [ground, row, cands]
     if gscale is not None:
         assert gscale.shape == (1, n), (gscale.shape, n)
-        in_specs.insert(1, pl.BlockSpec((1, TILE_N),
-                                        lambda ci, ni: (0, ni)))
+        in_specs.insert(1, pl.BlockSpec((1, tn),
+                                        lambda ci, ni, di: (0, ni)))
         operands.insert(1, gscale)
-        kernel = _gains_kernel_quant
     out = pl.pallas_call(
-        functools.partial(kernel, rule=rule),
+        functools.partial(_gains_kernel, rule=rule,
+                          quant=gscale is not None),
         name="gains_pallas",
-        grid=grid,
+        grid=(c // tc, n // tn, d // td),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, TILE_C), lambda ci, ni: (0, ci)),
+        out_specs=pl.BlockSpec((1, tc), lambda ci, ni, di: (0, ci)),
         out_shape=jax.ShapeDtypeStruct((1, c), F32),
-        # candidate blocks are independent (parallel); the inner
-        # ground dim accumulates into the revisited output block
-        # (arbitrary), which Mosaic can still software-pipeline
+        scratch_shapes=_scratch(tn, tc),
+        # candidate blocks are independent (parallel); the ground and
+        # feature axes accumulate into the revisited output block and
+        # the scratch (arbitrary), which Mosaic can still pipeline
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes or None),
         interpret=interpret,
     )(*operands)
     return out[0]

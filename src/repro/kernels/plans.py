@@ -213,6 +213,140 @@ def vmem_limit(need: int) -> int:
     return max(_SCOPED_VMEM_DEFAULT, 2 * int(need))
 
 
+@dataclasses.dataclass(frozen=True)
+class FeatureTiles:
+    """Blocks of one contraction-tiled feature kernel (kernels/pairwise.py):
+    `tn` ground rows × `tc` candidates, summed over the features `td` at a
+    time (the innermost grid axis), the features zero-padded to `d_pad`,
+    a whole number of tiles."""
+    kernel: str         # 'pairwise' | 'gains'
+    tn: int
+    tc: int
+    td: int
+    d_pad: int
+    need: int           # VMEM bytes of one grid cell (`feature_need`)
+    hbm_bytes: int      # HBM bytes one call moves (`feature_bytes`)
+
+    @property
+    def limit(self) -> int:
+        return vmem_limit(self.need)
+
+    def as_record(self) -> dict:
+        return {**dataclasses.asdict(self), "limit": self.limit}
+
+
+# the smallest blocks of the feature kernels, and the pad bases of their
+# ground and candidate axes (ops.py buckets N and C on them)
+FEATURE_TILE_N = 256
+FEATURE_TILE_C = 128
+
+
+def feature_need(kernel: str, tn: int, tc: int, td: int,
+                 itemsize: int = 4) -> int:
+    """VMEM bytes of one grid cell of a contraction-tiled feature kernel:
+    the (TN, TD) ground block at `itemsize` and the (TC, TD) f32
+    candidate block; their f32 squares (or int8 rescale); the (TN, TC)
+    f32 cross-term accumulator with the tile's product and the finished
+    block beside it; the (TN, 1) and (1, TC) squared-norm accumulators,
+    padded to whole vregs; and the output block — (TN, TC) for the
+    pairwise build, the (1, TC) gains row and (1, TN) state row (and
+    int8 scale row) for the gains kernel."""
+    feat = tn * td * itemsize + tc * td * 4 + (tn + tc) * td * 4
+    acc = 3 * tn * tc * 4 + 4 * (128 * tn + 8 * tc)
+    if kernel == "pairwise":
+        return feat + acc + tn * tc * 4
+    rows = 2 if itemsize == 1 else 1
+    return feat + acc + 4 * 8 * (tc + rows * tn)
+
+
+def _moves(grid: Tuple[int, ...], axes: Tuple[int, ...]) -> int:
+    """Times a block whose index follows grid `axes` is moved over a
+    row-major walk of `grid`: the pipeline fetches (or writes back) it
+    whenever its index changes, so once per step of the innermost such
+    axis that has more than one step."""
+    live = [a for a in axes if grid[a] > 1]
+    return math.prod(grid[:max(live) + 1]) if live else 1
+
+
+def feature_bytes(kernel: str, n_pad: int, c_pad: int, tn: int, tc: int,
+                  td: int, d_pad: int, itemsize: int = 4,
+                  out_itemsize: int = 4) -> int:
+    """HBM bytes one call of a contraction-tiled feature kernel moves.
+
+    'pairwise': grid (N/TN, C/TC, D/TD), ground (N, D) and candidates
+    (C, D) in, the (N, C) matrix out at `out_itemsize`: ground · ⌈C/TC⌉
+    + candidates · ⌈N/TN⌉ + output once, where one feature tile lets a
+    block that stays put be read once. 'gains': grid (C/TC, N/TN, D/TD),
+    the ground and its (1, N) state row re-read per candidate block, the
+    candidates per ground block when the features are tiled, the (1, C)
+    gains written once."""
+    if kernel == "pairwise":
+        grid = (n_pad // tn, c_pad // tc, d_pad // td)
+        return (_moves(grid, (0, 2)) * tn * td * itemsize
+                + _moves(grid, (1, 2)) * tc * td * itemsize
+                + _moves(grid, (0, 1)) * tn * tc * out_itemsize)
+    grid = (c_pad // tc, n_pad // tn, d_pad // td)
+    rows = 2 if itemsize == 1 else 1
+    return (_moves(grid, (1, 2)) * tn * td * itemsize
+            + rows * _moves(grid, (1,)) * tn * 4
+            + _moves(grid, (0, 2)) * tc * td * 4
+            + _moves(grid, (0,)) * tc * 4)
+
+
+def feature_tiles(kernel: str, n_pad: int, c_pad: int, d: int,
+                  itemsize: int = 4, out_itemsize: int = 4,
+                  budget: Optional[int] = None) -> FeatureTiles:
+    """Blocks for a contraction-tiled feature kernel ('pairwise' or
+    'gains') over ground rows `n_pad` and candidates `c_pad` (each a
+    power-of-two multiple of its `FEATURE_TILE_*`) and `d` features, the
+    ground stored at `itemsize` bytes (the pairwise build's matrix written
+    at `out_itemsize`).
+
+    Where the smallest blocks hold every feature within `budget` bytes
+    of VMEM (default: the fused VMEM budget), they are taken: one tile,
+    the blocks the kernels had before the features were tiled, so their
+    output is what it was, bit for bit (d ≤ 2,432 at the default 8 MiB).
+    Wider features are tiled: among the tilings whose `feature_need`
+    fits, the one that moves the fewest HBM bytes (`feature_bytes`),
+    then the one with the fewest grid steps. TN and TC run over powers
+    of two from the smallest tile up to the padded axis; TD splits the
+    128-lane feature axis into 1, 2, 3, … tiles as evenly as whole vregs
+    allow. When none fits, the smallest blocks, whose `limit` still
+    covers them."""
+    if budget is None:
+        budget = int(flags.fused_vmem_mb() * 2 ** 20)
+    lanes = -(-d // 128)
+    tds = sorted({-(-lanes // t) * 128 for t in range(1, lanes + 1)})
+
+    def tiling(tn, tc, td):
+        d_pad = -(-d // td) * td
+        return FeatureTiles(
+            kernel, tn, tc, td, d_pad,
+            feature_need(kernel, tn, tc, td, itemsize),
+            feature_bytes(kernel, n_pad, c_pad, tn, tc, td, d_pad,
+                          itemsize, out_itemsize))
+
+    whole = tiling(FEATURE_TILE_N, FEATURE_TILE_C, lanes * 128)
+    if whole.need <= budget:
+        return whole
+    best, best_key = tiling(FEATURE_TILE_N, FEATURE_TILE_C, 128), None
+    tn = FEATURE_TILE_N
+    while tn <= n_pad:
+        tc = FEATURE_TILE_C
+        while tc <= c_pad:
+            for td in tds:
+                if feature_need(kernel, tn, tc, td, itemsize) > budget:
+                    continue
+                t = tiling(tn, tc, td)
+                key = (t.hbm_bytes,
+                       (n_pad // tn) * (c_pad // tc) * (t.d_pad // td))
+                if best_key is None or key < best_key:
+                    best, best_key = t, key
+            tc *= 2
+        tn *= 2
+    return best
+
+
 def resident_need(n_pad: int, c_pad: int, d_pad: Optional[int],
                    rule: Optional[KernelRule] = None,
                    itemsize: int = 4) -> Optional[int]:
@@ -725,8 +859,9 @@ def select_engine(rule: KernelRule, n: int, c: int,
     Every call leaves a ``plan`` record (`runtime.telemetry`): the
     shape, the request, the resulting plan, where it came from
     ('requested' | 'override' | 'tuned' | 'static'), the host seconds
-    the decision took, and each tier the budget gates refused, with the
-    bytes it needed and the budget (`fused_plan`'s ``refused``).
+    the decision took, each tier the budget gates refused, with the
+    bytes it needed and the budget (`fused_plan`'s ``refused``), and the
+    ``tiles`` of the feature kernel the plan runs (`plan_tiles`).
     """
     if requested not in ("auto", "mega", "fused", "step"):
         raise ValueError(f"unknown engine {requested!r}; "
@@ -743,8 +878,34 @@ def select_engine(rule: KernelRule, n: int, c: int,
         lanes=int(lanes), source=source, engine=plan.engine,
         tier=plan.tier, dtype=plan.dtype, block_n=plan.block_n,
         loop_block_n=plan.loop_block_n, tile_c=plan.tile_c,
-        refused=refused, plan_s=time.perf_counter() - t0)
+        refused=refused, tiles=plan_tiles(plan, n, c, d),
+        plan_s=time.perf_counter() - t0)
     return plan
+
+
+def plan_tiles(plan: EnginePlan, n: int, c: int,
+               d: Optional[int]) -> Optional[dict]:
+    """`FeatureTiles.as_record()` of the contraction-tiled kernel `plan`
+    runs on f32 features, as the ops.py wrappers plan it: the pairwise
+    build of the HBM-cached tiers ('fused', 'mega_stream'), the per-step
+    gains of 'step'. None for bitmap rules, the ref backend, and the
+    resident and sharded tiers."""
+    if plan.rule.is_bitmap or plan.backend == "ref" or not d:
+        return None
+    n_pad = bucket_len(n, FEATURE_TILE_N)
+    c_pad = bucket_len(c, FEATURE_TILE_C)
+    if plan.engine in ("fused", "mega_stream"):
+        # an int8 cache is quantized from the kernel's f32 output
+        out = 4 if plan.dtype == "int8" else cache_itemsize(plan.dtype)
+        tiles = feature_tiles("pairwise", n_pad, c_pad, d,
+                              out_itemsize=out)
+    elif plan.engine == "step":
+        quant = flags.fused_cache_dtype() == "int8"
+        tiles = feature_tiles("gains", n_pad, c_pad, d,
+                              itemsize=1 if quant else 4)
+    else:
+        return None
+    return tiles.as_record()
 
 
 def _select_engine(rule: KernelRule, n: int, c: int, d: Optional[int],

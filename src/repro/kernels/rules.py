@@ -304,23 +304,45 @@ def dequant(q, scale):
 # ---------------------------------------------------------------------------
 
 
-def pairwise_block(g, c, mode: str):
-    """(TN, D) × (TC, D) feature blocks → (TN, TC) matrix block, f32.
-
-    The single source of the ‖g‖²+‖c‖²−2⟨g,c⟩ expansion — shared by the
-    pairwise kernel, the resident megakernel, and the stream filter so
-    every engine sees bit-identical matrix entries."""
+def cross_block(g, c):
+    """(TN, TD) × (TC, TD) f32 feature blocks → their (TN, TC) inner
+    products ⟨g, c⟩ over this slice of the features, f32."""
     # explicit f32 precision: XLA on the TPU would otherwise run this in
     # one bf16 pass while Mosaic does not, and the ref backend and the
     # kernels must see the same matrix
-    cross = jax.lax.dot_general(g, c, (((1,), (1,)), ((), ())),
-                                precision=jax.lax.Precision.HIGHEST,
-                                preferred_element_type=F32)   # (TN, TC)
+    return jax.lax.dot_general(g, c, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=F32)
+
+
+def sq_norms(g, c):
+    """Squared norms of the same blocks over this slice of the features:
+    (TN, 1) for the ground rows, (1, TC) for the candidates."""
+    return (jnp.sum(g * g, axis=1, keepdims=True),
+            jnp.sum(c * c, axis=1, keepdims=True).T)
+
+
+def finish_block(cross, gn, cn, mode: str):
+    """The (TN, TC) matrix block from inner products and squared norms
+    summed over every feature: 'dot' is the cross term itself; 'dist'
+    the ‖g‖²+‖c‖²−2⟨g,c⟩ expansion, its rounding noise cut to 0."""
     if mode == "dot":
         return cross
-    gn = jnp.sum(g * g, axis=1, keepdims=True)         # (TN, 1)
-    cn = jnp.sum(c * c, axis=1, keepdims=True).T       # (1, TC)
     return _dist_from_sq(gn + cn - 2.0 * cross, gn + cn)
+
+
+def pairwise_block(g, c, mode: str):
+    """(TN, D) × (TC, D) feature blocks → (TN, TC) matrix block, f32.
+
+    The single source of the matrix entries — the pairwise and gains
+    kernels (which sum `cross_block` and `sq_norms` over feature tiles,
+    then call `finish_block`), the resident megakernel, the stream filter
+    and the ref backend all finish the same way, so every engine sees
+    the same expansion and the same noise cut."""
+    cross = cross_block(g, c)
+    if mode == "dot":
+        return cross
+    return finish_block(cross, *sq_norms(g, c), mode)
 
 
 # Squared distances of the expansion below this share of ‖g‖²+‖c‖² are
@@ -347,15 +369,13 @@ def matrix_block(g, c, rule: KernelRule):
     return pairwise_block(g.astype(F32), c.astype(F32), rule.pairwise)
 
 
-def block_gains(g, cands, row, rule: KernelRule):
-    """Per-step gains kernel body: one (candidate-block × ground-block)
-    partial-gain slab → (1, TC) f32. For 'bits', cands-major layout
-    avoids the block transpose: part works elementwise either way."""
-    if rule.is_bitmap:
-        part = gain_part(row, cands, rule)             # (TC, TW)
-        return jnp.sum(part, axis=1, keepdims=True).T  # (1, TC)
-    m = matrix_block(g, cands, rule)                   # (TN, TC)
-    return partial_gains(row, m, rule)
+def bitmap_gains(cands, row, rule: KernelRule):
+    """Per-step bitmap gains kernel body: (TC, W) candidate bitmaps
+    against the (1, W) covered words → (1, TC) f32. The cands-major
+    layout avoids the block transpose: part works elementwise either
+    way. (Feature rules finish a matrix block, then `partial_gains`.)"""
+    part = gain_part(row, cands, rule)                 # (TC, W)
+    return jnp.sum(part, axis=1, keepdims=True).T      # (1, TC)
 
 
 # ---------------------------------------------------------------------------

@@ -257,6 +257,72 @@ def test_stream_filter(spec, case):
               kernels=["stream_filter_pallas"])
 
 
+# Tiny ImageNet's pixel width (64 × 64 × 3) and the pool one chip holds
+# at it (bench/configs/kmedoid_tinyimg.json)
+WIDE = 12_288
+LEAF = 16_384
+
+
+def test_pairwise_at_pixel_width(spec):
+    """The build tiles the features: full-width blocks of 256 rows would
+    need about 38 MB of VMEM."""
+    tiles = plans.feature_tiles("pairwise", LEAF, LEAF, WIDE)
+    assert tiles.d_pad // tiles.td > 1 and tiles.limit <= 32 * 2 ** 20
+    _compiles(lambda g, c: ops.pairwise_matrix(g, c, R.DIST_MIN,
+                                               backend="pallas"),
+              spec((LEAF, WIDE)), spec((LEAF, WIDE)),
+              kernels=["pairwise_pallas"])
+
+
+@pytest.mark.parametrize("case", ["f32", "int8"])
+def test_gains_at_pixel_width(spec, case, monkeypatch):
+    if case == "int8":
+        monkeypatch.setenv("REPRO_FUSED_CACHE_DTYPE", "int8")
+    _compiles(lambda g, r, c, v: ops.gains(g, r, c, v, R.DIST_MIN,
+                                           backend="pallas"),
+              spec((LEAF, WIDE)), spec((LEAF,)), spec((LEAF, WIDE)),
+              spec((LEAF,), jnp.bool_), kernels=["gains_pallas"])
+
+
+@pytest.mark.parametrize("engine,want,kernels", [
+    ("auto", "mega_stream", ["pairwise_pallas", "greedy_loop_pallas"]),
+    ("mega", "mega_stream", ["pairwise_pallas", "greedy_loop_pallas"]),
+    ("fused", "fused", ["pairwise_pallas", "fused_step_pallas"]),
+    ("step", "step", ["gains_pallas"]),
+])
+def test_kmedoid_tiers_at_pixel_width(spec, engine, want, kernels):
+    """Every tier the planner admits for k-medoid on one chip's pool at
+    Tiny ImageNet's width compiles as a whole greedy (k = 200); the
+    resident tier, which would build the matrix on chip over every
+    feature, is refused by its gate."""
+    from repro.runtime import telemetry
+    plan = plans.select_engine(R.DIST_MIN, LEAF, LEAF, WIDE,
+                               requested=engine, backend="pallas")
+    assert plan.engine == want
+    gates = {r["gate"] for r in telemetry.records("plan")[-1]["refused"]}
+    assert engine == "step" or "resident_vmem" in gates
+    obj = make_objective("kmedoid", backend="pallas")
+    _compiles(lambda i, p, v: greedy(obj, i, p, v, 200, engine=engine),
+              spec((LEAF,), I32), spec((LEAF, WIDE)),
+              spec((LEAF,), jnp.bool_), kernels=kernels)
+
+
+def test_full_width_on_chip_builds_compile_where_gated_in(spec):
+    """The resident megakernel and the stream filter build their matrix
+    on chip over every feature. At Tiny ImageNet's width the largest pool
+    the resident gate admits compiles, and the stream gate admits no
+    batch at all."""
+    n = 32                          # 64 rows need 9.5 MB: refused
+    assert plans.resident_fits(n, 128, WIDE, rule=R.DIST_MIN)
+    assert not plans.resident_fits(2 * n, 128, WIDE, rule=R.DIST_MIN)
+    _compiles(lambda g, row, mask: ops.greedy_loop_resident(
+        g, g, row, mask, K, R.DIST_MIN, backend="pallas"),
+        spec((n, WIDE)), spec((n,)), spec((n,), jnp.bool_),
+        kernels=["greedy_loop_resident_pallas"])
+    assert plans.stream_plan(1, 8, 1, WIDE, backend="pallas",
+                             rule=R.DOT_MAX) is None
+
+
 def test_sharded_leaf_shard_map(topo):
     """The sharded leaf tier over a real 4-device mesh: the pool's ground
     axis is split over the `shard` axis, candidate tiles are all-gathered
