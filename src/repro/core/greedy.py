@@ -141,6 +141,7 @@ def greedy(objective, ids: jax.Array, payloads: jax.Array, valid: jax.Array,
     rec.update(launches=int(sp.counts.get("launches", 0)),
                relayout_bytes=int(sp.counts.get("relayout_bytes", 0)),
                build_bytes=int(sp.counts.get("build_bytes", 0)),
+               mirrored_blocks=int(sp.counts.get("mirrored_blocks", 0)),
                streams=streams)
     return sol
 

@@ -28,8 +28,9 @@ Every wrapper that launches a kernel counts, while it is traced
 write to reshape operands, and a ``stream`` record of the operand the
 kernel streams (logical shape, padded shape, bytes); the pairwise build
 also counts the HBM bytes its planned tiling moves (``build_bytes``,
-plans.feature_bytes). The greedy driver gathers them into its
-per-invocation record.
+plans.feature_bytes, the full rectangular walk) and the blocks the
+symmetric build's mirror fills (``mirrored_blocks``). The greedy driver
+gathers them into its per-invocation record.
 
 Engine planning (memory gates, tier selection, backend resolution) lives
 in kernels/plans.py; the legacy names (`fused_plan`, `stream_plan`,
@@ -60,7 +61,8 @@ from repro.kernels import rules as rules_mod
 from repro.kernels.fused_step import fused_step_pallas
 from repro.kernels.greedy_loop import (greedy_loop_pallas,
                                        greedy_loop_resident_pallas)
-from repro.kernels.pairwise import gains_pallas, pairwise_pallas
+from repro.kernels.pairwise import (gains_pallas, pairwise_mirror,
+                                    pairwise_pallas)
 from repro.kernels.plans import (EnginePlan, FEATURE_TILE_C,  # noqa: F401
                                  FEATURE_TILE_N, RES_TILE_N,
                                  fused_block_n, fused_plan, fused_replicas,
@@ -244,6 +246,20 @@ def pairwise_matrix(ground, cands, rule: KernelRule, backend=None,
     (padding rows/cols carry junk that downstream masks neutralize); the
     ref backend returns the logical (N, C). `fused_step` /
     `apply_column` / `masked_col_reduce` accept either.
+
+    When the matrix is symmetric, as it is where `greedy` selects from
+    its own ground set, the build does half the work: when ``cands is
+    ground`` (the same array at trace time), their padded extents are
+    equal, the planned tiles are square and there are at least 2 block
+    rows, the one padded array feeds both sides, `pairwise_pallas`
+    computes only the blocks on and above the diagonal (its skipped
+    steps keep their index maps on the next computed step's blocks, so
+    they move nothing), and `pairwise_mirror` fills each block below
+    the diagonal with the transpose of its partner, in place (counted as
+    ``mirrored_blocks``). Every other call — distinct arrays (accumulation
+    nodes, `replay_batch`), the one-tile (256, 128) blocks, non-square
+    tiles — takes the full build. The int8 cache is quantized from the
+    mirrored f32 matrix.
     """
     b = _backend(backend)
     if rule.is_bitmap:
@@ -263,16 +279,30 @@ def pairwise_matrix(ground, cands, rule: KernelRule, backend=None,
     tiles = plans.feature_tiles("pairwise", n_pad, c_pad, ground.shape[1],
                                 itemsize=ground.dtype.itemsize,
                                 out_itemsize=jnp.dtype(out_dtype).itemsize)
+    # the candidates are the ground rows, over one padded extent, on
+    # square tiles of at least 2 block rows: the matrix is symmetric
+    rows = n_pad // tiles.tn
+    symmetric = (ground is cands and n_pad == c_pad
+                 and tiles.tn == tiles.tc and rows >= 2)
     g = _relayout(ground, _pad_to(_pad_to(ground, 0, FEATURE_TILE_N), 1,
                                   tiles.td, bucket=False))
-    cd = _relayout(cands, _pad_to(_pad_to(cands, 0, FEATURE_TILE_C), 1,
-                                  tiles.td, bucket=False))
+    cd = g if symmetric else _relayout(
+        cands, _pad_to(_pad_to(cands, 0, FEATURE_TILE_C), 1, tiles.td,
+                       bucket=False))
     _launch("pairwise_pallas", cands.shape, cd)
     telemetry.count("build_bytes", tiles.hbm_bytes)
     m = pairwise_pallas(g, cd, mode=rule.pairwise, out_dtype=out_dtype,
                         interpret=(b == "interpret"),
                         tiles=(tiles.tn, tiles.tc, tiles.td),
-                        vmem_limit_bytes=tiles.limit)
+                        vmem_limit_bytes=tiles.limit, symmetric=symmetric)
+    if symmetric:
+        _launch("pairwise_mirror", (ground.shape[0], cands.shape[0]), m)
+        telemetry.count("mirrored_blocks", rows * (rows - 1) // 2)
+        # VMEM: the block read and the block written at the matrix's
+        # width, and the block's f32 transpose
+        m = pairwise_mirror(m, tile=tiles.tn, interpret=(b == "interpret"),
+                            vmem_limit_bytes=plans.vmem_limit(
+                                tiles.tn ** 2 * (2 * m.dtype.itemsize + 4)))
     if dtype == "int8":
         # quantization is a cheap jnp epilogue on the f32 kernel output
         # (one pass, fuses under jit) — zero extra dispatches. Pad

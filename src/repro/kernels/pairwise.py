@@ -1,7 +1,8 @@
 """Pallas TPU kernels: pairwise matrix materialization + the ONE
 rule-parameterized per-step gains kernel.
 
-Two entry points, both driven by a `KernelRule` (kernels/rules.py):
+Three entry points, the first and last driven by a `KernelRule`
+(kernels/rules.py):
 
   * ``pairwise_pallas`` — the fused engine's `prepare()` stage (DESIGN
     §Perf): compute the (N, C) ground×candidate matrix ONCE per greedy
@@ -12,7 +13,15 @@ Two entry points, both driven by a `KernelRule` (kernels/rules.py):
     step adds one (TN, TD) × (TC, TD) MXU product to an f32 (TN, TC)
     cross-term accumulator (and, for 'dist', the blocks' squared norms to
     (TN, 1) and (1, TC) accumulators), and the last feature tile finishes
-    the block with `rules.finish_block`.
+    the block with `rules.finish_block`. Where the candidates are the
+    ground rows on square tiles (ops.pairwise_matrix decides), the matrix
+    is symmetric and the build runs ``symmetric``: same grid and blocks,
+    but only the blocks on and above the diagonal are computed (bit for
+    bit what the full build writes), and the steps below it move nothing.
+
+  * ``pairwise_mirror`` — fills the blocks below the diagonal of that
+    symmetric build, in place, with the transposes of the blocks above
+    it: one block read and one written per grid step.
 
   * ``gains_pallas`` — the per-step (uncached) marginal-gains pass, the
     paper's memory-capped regime. This single kernel replaces the three
@@ -80,24 +89,53 @@ def _scratch(tn: int, tc: int):
 
 
 def _kernel(ground_ref, cands_ref, out_ref, acc_ref, gn_ref, cn_ref, *,
-            mode: str):
+            mode: str, symmetric: bool):
     di = pl.program_id(2)
     last = pl.num_programs(2) - 1
-    _accumulate(ground_ref[...].astype(F32), cands_ref[...].astype(F32),
-                acc_ref, gn_ref, cn_ref, di == 0, mode)
 
-    @pl.when(di == last)
-    def _write():
-        out_ref[...] = _finish(acc_ref, gn_ref, cn_ref,
-                               mode).astype(out_ref.dtype)
+    def block():
+        _accumulate(ground_ref[...].astype(F32), cands_ref[...].astype(F32),
+                    acc_ref, gn_ref, cn_ref, di == 0, mode)
+
+        @pl.when(di == last)
+        def _write():
+            out_ref[...] = _finish(acc_ref, gn_ref, cn_ref,
+                                   mode).astype(out_ref.dtype)
+
+    if symmetric:
+        # only the blocks on and above the diagonal; `pairwise_mirror`
+        # fills the rest
+        pl.when(pl.program_id(1) >= pl.program_id(0))(block)
+    else:
+        block()
+
+
+def _index_maps(symmetric: bool):
+    """Block indices of ground, candidates and output at grid step
+    (ni, ci, di). A skipped step of the symmetric build (ci < ni) stays
+    on the blocks of row ni's first computed step, the diagonal's at
+    feature tile 0, so the pipeline neither fetches nor writes back for
+    it, and the diagonal block is written back once, when computed."""
+    if not symmetric:
+        return ((lambda ni, ci, di: (ni, di)),
+                (lambda ni, ci, di: (ci, di)),
+                (lambda ni, ci, di: (ni, ci)))
+
+    def feat(ni, ci, di):
+        return jnp.where(ci < ni, 0, di)
+
+    return ((lambda ni, ci, di: (ni, feat(ni, ci, di))),
+            (lambda ni, ci, di: (jnp.maximum(ni, ci), feat(ni, ci, di))),
+            (lambda ni, ci, di: (ni, jnp.maximum(ni, ci))))
 
 
 @functools.partial(jax.jit,
                    static_argnames=("mode", "out_dtype", "interpret", "tiles",
-                                    "vmem_limit_bytes"))
+                                    "vmem_limit_bytes", "symmetric"))
 def pairwise_pallas(ground: jax.Array, cands: jax.Array, mode: str = "dist",
                     out_dtype: str = "float32", interpret: bool = False, *,
-                    tiles: tuple, vmem_limit_bytes: int = 0) -> jax.Array:
+                    tiles: tuple, vmem_limit_bytes: int = 0,
+                    symmetric: bool = False) -> jax.Array:
     """ground: (N, D), cands: (C, D) → (N, C) matrix in ``out_dtype``
     (compute always f32; 'bfloat16' halves the cache's HBM footprint).
 
@@ -106,21 +144,32 @@ def pairwise_pallas(ground: jax.Array, cands: jax.Array, mode: str = "dist",
     pad rows/cols produce ‖·‖ / 0 entries that callers mask, pad features
     add nothing). `vmem_limit_bytes`: Mosaic's scoped-VMEM limit
     (plans.vmem_limit).
+
+    ``symmetric``: ``cands`` is ``ground`` on square tiles (TN = TC), so
+    the matrix is symmetric. The grid and blocks stay as they are, but
+    only the steps with ci ≥ ni compute, in the same arithmetic and
+    feature order as the full build, so those blocks are bit for bit
+    what it writes; the steps below the diagonal keep their index maps
+    on the blocks the next computed step needs, so they move nothing
+    (`_index_maps`). The blocks below the diagonal are left unwritten
+    for `pairwise_mirror` to fill.
     """
     tn, tc, td = tiles
     n, d = ground.shape
     c = cands.shape[0]
     assert n % tn == 0 and c % tc == 0 and d % td == 0 and td % 128 == 0, \
         (n, c, d, tiles)
+    assert not symmetric or (n, tn) == (c, tc), (n, c, tiles)
+    g_map, c_map, o_map = _index_maps(symmetric)
     return pl.pallas_call(
-        functools.partial(_kernel, mode=mode),
+        functools.partial(_kernel, mode=mode, symmetric=symmetric),
         name="pairwise_pallas",
         grid=(n // tn, c // tc, d // td),
         in_specs=[
-            pl.BlockSpec((tn, td), lambda ni, ci, di: (ni, di)),
-            pl.BlockSpec((tc, td), lambda ni, ci, di: (ci, di)),
+            pl.BlockSpec((tn, td), g_map),
+            pl.BlockSpec((tc, td), c_map),
         ],
-        out_specs=pl.BlockSpec((tn, tc), lambda ni, ci, di: (ni, ci)),
+        out_specs=pl.BlockSpec((tn, tc), o_map),
         out_shape=jax.ShapeDtypeStruct((n, c), jnp.dtype(out_dtype)),
         scratch_shapes=_scratch(tn, tc),
         # matrix blocks are independent — Mosaic may pipeline/reorder
@@ -130,6 +179,57 @@ def pairwise_pallas(ground: jax.Array, cands: jax.Array, mode: str = "dist",
             vmem_limit_bytes=vmem_limit_bytes or None),
         interpret=interpret,
     )(ground, cands)
+
+
+def _mirror_kernel(upper_ref, out_ref):
+    # through f32: Mosaic transposes 32-bit tiles; the round trip of a
+    # narrower float is exact
+    out_ref[...] = upper_ref[...].astype(F32).T.astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret",
+                                             "vmem_limit_bytes"))
+def pairwise_mirror(mat: jax.Array, *, tile: int, interpret: bool = False,
+                    vmem_limit_bytes: int = 0) -> jax.Array:
+    """Fill the blocks below the diagonal of a symmetric (N, N) matrix,
+    in place, from the blocks above it that `pairwise_pallas(...,
+    symmetric=True)` wrote: block (i, j), i > j, becomes the transpose
+    of block (j, i).
+
+    The matrix is aliased to the output, so no second (N, N) buffer
+    exists. Grid (T/2, T − 1) over T = N/tile block rows (a power of
+    two, so even), rows paired: step (p, q) writes block (p, q) when
+    q < p, else block (T − 1 − p, q − p). Every step writes exactly one
+    block below the diagonal, and none above it is written.
+    """
+    n = mat.shape[0]
+    t = n // tile
+    assert mat.shape == (n, n) and n % tile == 0 and t % 2 == 0, \
+        (mat.shape, tile)
+
+    def lower(p, q):
+        first = q < p
+        return jnp.where(first, p, t - 1 - p), jnp.where(first, q, q - p)
+
+    def upper(p, q):
+        i, j = lower(p, q)
+        return j, i
+
+    return pl.pallas_call(
+        _mirror_kernel,
+        name="pairwise_mirror",
+        grid=(t // 2, t - 1),
+        in_specs=[pl.BlockSpec((tile, tile), upper)],
+        out_specs=pl.BlockSpec((tile, tile), lower),
+        out_shape=jax.ShapeDtypeStruct(mat.shape, mat.dtype),
+        input_output_aliases={0: 0},
+        # every step reads one block above the diagonal and writes one
+        # below it: no step touches another's block
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=vmem_limit_bytes or None),
+        interpret=interpret,
+    )(mat)
 
 
 def _gains_kernel(*refs, rule: KernelRule, quant: bool):

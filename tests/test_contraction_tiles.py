@@ -9,6 +9,11 @@ NumPy; a point sits at distance exactly 0 from itself; with one tile
 they must give, bit for bit, the full-feature kernels they replaced; and
 a whole greedy under tiles forced small by a tight VMEM budget must
 select the reference backend's ids.
+
+Where the candidates are the ground rows on square tiles, the build
+computes the blocks on and above the diagonal and `pairwise_mirror`
+fills the rest: those blocks must be the full build's bit for bit, the
+rest their exact transpose; every other call must take the full build.
 """
 import functools
 
@@ -21,9 +26,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.greedy import greedy
 from repro.core.objective import make_objective
-from repro.kernels import plans, ref
+from repro.kernels import ops, plans, ref
 from repro.kernels import rules as R
-from repro.kernels.pairwise import gains_pallas, pairwise_pallas
+from repro.kernels.pairwise import (gains_pallas, pairwise_mirror,
+                                    pairwise_pallas)
 from repro.runtime import telemetry
 
 F32 = jnp.float32
@@ -192,20 +198,147 @@ def test_one_tile_gains_are_the_full_feature_gains(name, store):
 
 
 # ---------------------------------------------------------------------------
+# the symmetric build: the blocks on and above the diagonal, mirrored
+# ---------------------------------------------------------------------------
+
+
+def _force_tiles(monkeypatch, tn, tc, td):
+    """Make the planner hand every feature kernel (tn, tc, td) tiles."""
+    def forced(kernel, n_pad, c_pad, d, itemsize=4, out_itemsize=4,
+               budget=None):
+        d_pad = -(-d // td) * td
+        return plans.FeatureTiles(
+            kernel, tn, tc, td, d_pad,
+            plans.feature_need(kernel, tn, tc, td, itemsize),
+            plans.feature_bytes(kernel, n_pad, c_pad, tn, tc, td, d_pad,
+                                itemsize, out_itemsize))
+
+    monkeypatch.setattr(plans, "feature_tiles", forced)
+
+
+def _block(m, t, i, j):
+    return m[i * t:(i + 1) * t, j * t:(j + 1) * t]
+
+
+def _blocks(m, t):
+    rows = m.shape[0] // t
+    for i in range(rows):
+        for j in range(rows):
+            yield i, j, _block(m, t, i, j)
+
+
+def _build(x, mode, dtype, cands=None):
+    """pairwise_matrix(x, cands or x itself) in interpret mode, and the
+    blocks the mirror filled."""
+    rule = R.DIST_MIN if mode == "dist" else R.DOT_MAX
+    with telemetry.span("build") as sp:
+        m = ops.pairwise_matrix(x, x if cands is None else cands, rule,
+                                backend="interpret", dtype=dtype)
+    return m, sp.counts.get("mirrored_blocks", 0)
+
+
+@pytest.mark.parametrize("rows", [2, 4])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("mode", ["dist", "dot"])
+def test_symmetric_build_mirrors_the_upper_blocks(mode, dtype, rows,
+                                                  monkeypatch):
+    """T × T square tiles over two feature tiles: the blocks on and above
+    the diagonal are the full build's bit for bit, those below it their
+    exact transpose, and the whole matrix the reference's."""
+    t, d = 256, 2 * TD
+    _force_tiles(monkeypatch, t, t, TD)
+    x = jax.random.normal(jax.random.PRNGKey(8), (rows * t, d), F32)
+    x = x / jnp.linalg.norm(x, axis=1, keepdims=True)
+    m, mirrored = _build(x, mode, dtype)
+    assert mirrored == rows * (rows - 1) // 2
+    full = pairwise_pallas(x, x, mode, dtype, interpret=True,
+                           tiles=(t, t, TD))
+    got = np.asarray(m.astype(F32))
+    want = np.asarray(full.astype(F32))
+    for i, j, block in _blocks(got, t):
+        if j >= i:
+            np.testing.assert_array_equal(block, _block(want, t, i, j))
+        else:
+            np.testing.assert_array_equal(block, _block(got, t, j, i).T)
+    np.testing.assert_allclose(
+        got, np.asarray(R.pairwise_block(x, x, mode)), atol=R.DIST_REL_TOL,
+        rtol=0 if dtype == "float32" else 2.0 ** -8)
+    if mode == "dist":
+        assert np.all(np.diag(got) == 0.0)
+
+
+def test_symmetric_int8_cache_quantizes_the_mirrored_matrix(monkeypatch):
+    t, rows, d = 256, 4, 2 * TD
+    _force_tiles(monkeypatch, t, t, TD)
+    x = jax.random.normal(jax.random.PRNGKey(9), (rows * t, d), F32)
+    x = x / jnp.linalg.norm(x, axis=1, keepdims=True)
+    q, mirrored = _build(x, "dist", "int8")
+    m, _ = _build(x, "dist", "float32")
+    assert mirrored == rows * (rows - 1) // 2
+    want = R.quantize_rows(m)
+    np.testing.assert_array_equal(np.asarray(q.q), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(q.scale), np.asarray(want[1]))
+    np.testing.assert_allclose(
+        np.asarray(R.dequant(q.q, q.scale)),
+        np.asarray(R.pairwise_block(x, x, "dist")), rtol=0, atol=2e-2)
+
+
+@pytest.mark.parametrize("case", ["distinct", "non_square", "one_row"])
+def test_other_builds_stay_rectangular(case, monkeypatch):
+    """Distinct arrays, non-square tiles and a single block row take the
+    full build, unchanged: one kernel, no block mirrored."""
+    n, tiles = {"distinct": (512, (256, 256, TD)),
+                "non_square": (512, (256, 128, TD)),
+                "one_row": (256, (256, 256, TD))}[case]
+    _force_tiles(monkeypatch, *tiles)
+    x = jax.random.normal(jax.random.PRNGKey(10), (n, 2 * TD), F32)
+    other = x + 0.0 if case == "distinct" else None
+    m, mirrored = _build(x, "dist", "float32", cands=other)
+    assert mirrored == 0
+    full = pairwise_pallas(x, x, "dist", interpret=True, tiles=tiles)
+    np.testing.assert_array_equal(np.asarray(m), np.asarray(full))
+    jx = jax.make_jaxpr(lambda a: ops.pairwise_matrix(
+        a, a if other is None else a + 0.0, R.DIST_MIN,
+        backend="interpret"))(x)
+    assert ops.count_pallas_dispatches(jx.jaxpr) == 1
+
+
+def test_mirror_writes_only_below_the_diagonal():
+    """Every block below the diagonal is written once, from the block
+    above it; the blocks on and above it keep what the build wrote."""
+    t, rows = 128, 8
+    upper = jnp.triu(jnp.arange(float((rows * t) ** 2), dtype=F32)
+                     .reshape(rows * t, rows * t))
+    got = np.asarray(pairwise_mirror(upper, tile=t, interpret=True))
+    up = np.asarray(upper)
+    for i, j, block in _blocks(got, t):
+        want = _block(up, t, i, j) if j >= i else _block(up, t, j, i).T
+        np.testing.assert_array_equal(block, want)
+
+
+# ---------------------------------------------------------------------------
 # a whole greedy under tiles forced small
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("engine", ["auto", "step"])
-def test_greedy_with_small_tiles_selects_the_reference_ids(engine,
+@pytest.mark.parametrize("engine,square", [
+    pytest.param("auto", False, id="auto"),
+    pytest.param("step", False, id="step"),
+    pytest.param("auto", True, id="auto-square")])
+def test_greedy_with_small_tiles_selects_the_reference_ids(engine, square,
                                                            monkeypatch):
     """n = 256 pixel-like points wide enough (d = 4,200) that the
     resident tier is refused: 'auto' builds the cache with the pairwise
     kernel, 'step' runs the gains kernel every step, both on tiles a
-    1.5 MiB budget forces to split the features."""
-    n, d, k = 256, 4200, 8
-    small = functools.partial(plans.feature_tiles, budget=3 * 2 ** 19)
-    monkeypatch.setattr(plans, "feature_tiles", small)
+    1.5 MiB budget forces to split the features. With square tiles
+    forced over n = 512, 'auto' builds the blocks on and above the
+    diagonal and mirrors them."""
+    n, d, k = (512 if square else 256), 4200, 8
+    if square:
+        _force_tiles(monkeypatch, 256, 256, 1152)
+    else:
+        small = functools.partial(plans.feature_tiles, budget=3 * 2 ** 19)
+        monkeypatch.setattr(plans, "feature_tiles", small)
     x = jax.random.normal(jax.random.PRNGKey(4), (16, d), F32)
     lbl = jax.random.randint(jax.random.PRNGKey(5), (n,), 0, 16)
     x = x[lbl] + 0.35 * jax.random.normal(jax.random.PRNGKey(6), (n, d))
@@ -219,8 +352,11 @@ def test_greedy_with_small_tiles_selects_the_reference_ids(engine,
         picks[backend] = np.asarray(sol.ids)
         if backend == "interpret":
             plan = telemetry.records("plan")[-1]
+            mirrored = telemetry.records("greedy")[-1]["mirrored_blocks"]
     assert plan["engine"] == ("mega_stream" if engine == "auto" else "step")
     tiles = plan["tiles"]
     assert tiles["d_pad"] // tiles["td"] >= 2, tiles
-    assert tiles["need"] <= 3 * 2 ** 19
+    assert mirrored == (1 if square else 0)
+    if not square:
+        assert tiles["need"] <= 3 * 2 ** 19
     np.testing.assert_array_equal(picks["interpret"], picks["ref"])

@@ -166,6 +166,47 @@ def test_ref_backend_launches_nothing():
     assert rec["streams"] == []
 
 
+# the k-medoid cell: one chip's pool at Tiny ImageNet's pixel width, and
+# the k-cover cell: FIMI retail's sets over its item words
+LEAF, WIDE = 16_384, 12_288
+RETAIL, WORDS = 88_162, 515
+
+
+@pytest.mark.parametrize("case", ["own_pool", "distinct_ground",
+                                  "kcover"])
+def test_greedy_record_counts_the_mirrored_blocks(case):
+    """Traced for the Pallas backend at the cells' shapes: a k-medoid
+    greedy over its own pool builds the blocks on and above the diagonal
+    of the 32 × 32 grid of (512, 512) tiles and mirrors the other 496,
+    while its counted build bytes stay the full walk's; given a distinct
+    ground it mirrors nothing, nor does k-cover, which builds no
+    matrix."""
+    S = jax.ShapeDtypeStruct
+    if case == "kcover":
+        obj = make_objective("coverage", universe=32 * WORDS,
+                             backend="pallas")
+        n, pool, k = RETAIL, S((RETAIL, WORDS), jnp.uint32), 64
+    else:
+        obj = make_objective("kmedoid", backend="pallas")
+        n, pool, k = LEAF, S((LEAF, WIDE), jnp.float32), 200
+    args = [S((n,), jnp.int32), pool, S((n,), jnp.bool_)]
+    if case == "distinct_ground":
+        args += [pool, S((n,), jnp.bool_)]
+    jx = jax.make_jaxpr(lambda i, p, v, *g: greedy(obj, i, p, v, k, *g))(
+        *args)
+    rec = telemetry.records("greedy")[-1]
+    assert rec["launches"] == ops.count_pallas_dispatches(jx.jaxpr) > 0
+    if case == "kcover":
+        assert rec["mirrored_blocks"] == rec["build_bytes"] == 0
+        return
+    tiles = plans.feature_tiles("pairwise", LEAF, LEAF, WIDE)
+    assert (tiles.tn, tiles.tc) == (512, 512)
+    assert rec["build_bytes"] == tiles.hbm_bytes
+    assert rec["mirrored_blocks"] == (496 if case == "own_pool" else 0)
+    kernels = [s["kernel"] for s in rec["streams"]]
+    assert ("pairwise_mirror" in kernels) == (case == "own_pool")
+
+
 # ---------------------------------------------------------------------------
 # the planner
 # ---------------------------------------------------------------------------

@@ -10,8 +10,9 @@ the Mosaic custom call.
 
 Each compiled kernel must also keep the instruction name the benchmark's
 trace reducer (`bench/lib/trace.py`) finds it by: `gains_pallas`,
-`pairwise_pallas`, `fused_step_pallas`, `greedy_loop_pallas`,
-`greedy_loop_resident_pallas`, `stream_filter_pallas`.
+`pairwise_pallas`, `pairwise_mirror`, `fused_step_pallas`,
+`greedy_loop_pallas`, `greedy_loop_resident_pallas`,
+`stream_filter_pallas`.
 
 The topology is described inside a module fixture only: one process at a
 time may load the TPU compiler library, so describing it while modules are
@@ -274,6 +275,22 @@ def test_pairwise_at_pixel_width(spec):
               kernels=["pairwise_pallas"])
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_symmetric_pairwise_at_pixel_width(spec, dtype):
+    """Ground and candidates one array, on the cell's square tiles: the
+    build computes the blocks on and above the diagonal, and the mirror
+    fills the rest in place, with no second (n, n) buffer."""
+    tiles = plans.feature_tiles("pairwise", LEAF, LEAF, WIDE,
+                                out_itemsize=jnp.dtype(dtype).itemsize)
+    assert tiles.tn == tiles.tc and LEAF // tiles.tn >= 2
+    fn = lambda x: ops.pairwise_matrix(x, x, R.DIST_MIN, backend="pallas",
+                                       dtype=dtype)
+    _compiles(fn, spec((LEAF, WIDE)),
+              kernels=["pairwise_pallas", "pairwise_mirror"])
+    mem = jax.jit(fn).lower(spec((LEAF, WIDE))).compile().memory_analysis()
+    assert mem.temp_size_in_bytes == 0
+
+
 @pytest.mark.parametrize("case", ["f32", "int8"])
 def test_gains_at_pixel_width(spec, case, monkeypatch):
     if case == "int8":
@@ -285,9 +302,12 @@ def test_gains_at_pixel_width(spec, case, monkeypatch):
 
 
 @pytest.mark.parametrize("engine,want,kernels", [
-    ("auto", "mega_stream", ["pairwise_pallas", "greedy_loop_pallas"]),
-    ("mega", "mega_stream", ["pairwise_pallas", "greedy_loop_pallas"]),
-    ("fused", "fused", ["pairwise_pallas", "fused_step_pallas"]),
+    ("auto", "mega_stream", ["pairwise_pallas", "pairwise_mirror",
+                             "greedy_loop_pallas"]),
+    ("mega", "mega_stream", ["pairwise_pallas", "pairwise_mirror",
+                             "greedy_loop_pallas"]),
+    ("fused", "fused", ["pairwise_pallas", "pairwise_mirror",
+                        "fused_step_pallas"]),
     ("step", "step", ["gains_pallas"]),
 ])
 def test_kmedoid_tiers_at_pixel_width(spec, engine, want, kernels):
