@@ -5,8 +5,7 @@ level-by-level runtime (runtime.supervisor.SelectionSupervisor) under
 three regimes on the same instance:
 
   * ``clean``     — no failures: the price of supervision itself
-                    (host round-trips + per-level checkpoints) over the
-                    monolithic one-dispatch driver,
+                    (host round-trips + per-level checkpoints),
   * ``replay``    — one transient mid-tree failure: restore + re-dispatch
                     of the failed level,
   * ``degrade``   — a permanently dead lane: reshard onto the largest
@@ -27,7 +26,6 @@ import time
 import jax.numpy as jnp
 
 from repro.core.functions import make_objective
-from repro.core.greedyml import greedyml_shmap_fn  # noqa: F401 (doc ref)
 from repro.data import synthetic
 from repro.runtime.supervisor import (LaneFailureInjector,
                                       SelectionSupervisor)

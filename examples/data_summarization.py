@@ -1,10 +1,11 @@
-"""Data summarization with the distributed GreedyML driver.
+"""Data summarization with the distributed GreedyML tree.
 
-Runs the actual shard_map implementation over every device there is: the
-chips of an accelerator host, or 8 simulated host devices on the CPU; it
-fails with fewer than two. Selects k diverse exemplars from a
-mixture-of-Gaussians image set with the k-medoid objective, then shows the
-facility-location coreset used by the training pipeline.
+Runs `LevelDispatcher` on a mesh with one lane per device, over every
+device there is: the chips of an accelerator host, or 8 simulated host
+devices on the CPU; it fails with fewer than two. Selects k diverse
+exemplars from a mixture-of-Gaussians image set with the k-medoid
+objective, then shows the facility-location coreset used by the training
+pipeline.
 
     JAX_PLATFORMS=cpu PYTHONPATH=src python examples/data_summarization.py
     PYTHONPATH=src python examples/data_summarization.py    # on a TPU host
@@ -19,7 +20,7 @@ import numpy as np
 
 from repro.core.functions import make_objective
 from repro.core.greedy import greedy
-from repro.core.greedyml import greedyml_distributed
+from repro.core.greedyml import LevelDispatcher
 from repro.core.simulate import global_value
 from repro.data import synthetic
 from repro.launch.mesh import make_machine_mesh
@@ -47,9 +48,9 @@ print(f"  sequential Greedy     : "
       f"{global_value('kmedoid', imgs, ref_sel):.4f}")
 
 mesh = make_machine_mesh(machines, 2)              # T(m, L=log2 m, b=2)
-tree_axes = tuple(reversed(mesh.axis_names))
-sol = greedyml_distributed(obj, ids, jnp.asarray(imgs), jnp.ones(N, bool),
-                           K, mesh, tree_axes=tree_axes)
+radices = (2,) * len(mesh.axis_names)
+sol = LevelDispatcher(obj, K, radices, mesh=mesh).run(
+    ids, jnp.asarray(imgs), jnp.ones(N, bool))
 sel = np.asarray(sol.ids)[np.asarray(sol.valid)]
 print(f"GreedyML over {mesh.devices.size} devices "
       f"(axes {mesh.axis_names}): picked {len(sel)} exemplars")
@@ -57,8 +58,8 @@ print(f"  global k-medoid value: "
       f"{global_value('kmedoid', imgs, sel):.4f}")
 
 # facility-location coreset (what --data-selection greedyml:facility uses)
-sol_f = greedyml_distributed(fac, ids, jnp.asarray(imgs), jnp.ones(N, bool),
-                             K, mesh, tree_axes=tree_axes)
+sol_f = LevelDispatcher(fac, K, radices, mesh=mesh).run(
+    ids, jnp.asarray(imgs), jnp.ones(N, bool))
 sel_f = np.asarray(sol_f.ids)[np.asarray(sol_f.valid)]
 print(f"facility-location coreset: {len(sel_f)} docs, "
       f"coverage={global_value('facility', imgs, sel_f):.4f}")

@@ -1,33 +1,41 @@
-"""Distributed GreedyML via shard_map — the paper's Algorithm 3.1 mapped
-TPU-natively onto mesh collectives (DESIGN §4).
+"""GreedyML, the paper's Algorithm 3.1, driven one stage at a time by
+`LevelDispatcher` (DESIGN §4).
 
-The m machines are the devices of an L-dimensional mesh factorization
-(b_1, …, b_L), innermost level first; machine id digits follow the paper's
-``parent(id, ℓ) = b^ℓ·⌊id/b^ℓ⌋`` arithmetic. Then
+The m machines are lanes of an L-level mixed-radix tree (b_1, …, b_L),
+innermost level first; machine id digits follow the paper's
+``parent(id, ℓ) = b^ℓ·⌊id/b^ℓ⌋`` arithmetic. Stage 0 is the leaf Greedy
+on each lane's pool; stage ℓ is one accumulation round
+(`accumulate_one_level`):
 
-    level-ℓ accumulation  ≡  lax.all_gather(S_prev, axis=tree_axes[ℓ-1])
-                             + a redundant local Greedy on the b·k union
-                             in every member of the group.
+    lax.all_gather(S_prev, axis=tree_axes[ℓ-1])
+    + a local Greedy on the b·k union, redundantly in every group member
+    + argmax{f(S), f(S_prev)}, S_prev scored by ``replay_value`` on the
+      node-local evaluation set (line 15).
 
-After the level-ℓ gather+greedy all b^ℓ devices of a subtree hold identical
-solutions, so the next gather collects exactly one representative per child
-subtree — the recurrence of Fig. 3. ``argmax{f(S), f(S_prev)}`` (line 15)
-uses ``replay_value`` to score S_prev under the node-local evaluation set.
-RandGreedi is the single-axis special case; the sequential Greedy baseline
-is `core.greedy.greedy` on an unsharded array.
+Lane b^ℓ·j then holds node (ℓ, b^ℓ·j), so the next gather collects one
+representative per child subtree — the recurrence of Fig. 3 — and the
+answer is machine 0's solution.
 
-Every Greedy call here (leaves AND accumulation nodes) runs through the
-fastest fitting engine (greedy(engine='auto'), DESIGN §Perf): the leaf
-cache is (n/m)×(n/m) — streaming megakernel (2 dispatches) when it fits
-the HBM budget, per-step kernels when not — while the accumulation-node
-working set is only (b·k + augment)×(b·k), which fits VMEM whole, so
-internal nodes default to the RESIDENT megakernel tier: the entire
-node-local greedy (pairwise matrix built on-chip + all k steps) is ONE
-kernel dispatch, where launch overhead would otherwise dominate the tiny
-matrix. Huge leaf partitions degrade gracefully via the ops.fused_plan
-memory gate — the paper's whole point is respecting per-machine memory
-limits (§6.1/§6.4). ``node_engine`` overrides the accumulation-node
-engine independently of the leaves.
+- ``LevelDispatcher.leaves`` / ``.level`` run the stages over stacked
+  (lanes, …) state, through shard_map on a mesh with one device per lane,
+  or through nested vmap over the same named axes on one device.
+- ``LevelDispatcher.run`` chains them. RandGreedi is its one-level case,
+  radices ``(m,)``.
+- The supervisor (runtime/supervisor.py) runs the same stages and
+  checkpoints the state between them.
+- ``core.simulate.run_tree_dense`` deals the paper's random partition and
+  runs ragged trees through the dispatcher without a mesh;
+  ``run_tree_lazy`` is the call-count reference.
+
+Every Greedy call (leaves AND accumulation nodes) runs through the fastest
+fitting engine (greedy(engine='auto'), DESIGN §Perf): the leaf cache is
+(n/m)×(n/m), while the accumulation-node working set is only
+(b·k + augment)×(b·k), which fits VMEM whole, so internal nodes default
+to the RESIDENT megakernel tier — one kernel dispatch per node. Huge leaf
+partitions degrade gracefully via the memory gate — the paper's whole
+point is respecting per-machine memory limits (§6.1/§6.4).
+``node_engine`` overrides the accumulation-node engine independently of
+the leaves.
 """
 from __future__ import annotations
 
@@ -114,8 +122,8 @@ def accumulate_one_level(objective, s_prev: Solution, k: int,
 
     This is the unit the supervised runtime (runtime/supervisor.py)
     dispatches once per level, checkpointing the per-lane state in
-    between; `accumulate_levels` keeps the monolithic whole-tree SPMD
-    program by looping over it.
+    between; `accumulate_levels` loops over it inside the streaming
+    driver's own shard_map.
 
     ``constraint``: optional hereditary constraint SPEC (e.g.
     core.constraints.KnapsackSpec) — ``constraint.bind(u_ids)`` aligns the
@@ -195,114 +203,23 @@ def accumulate_levels(objective, s_prev: Solution, k: int,
     return s_prev
 
 
-def greedyml_shmap_fn(objective, k: int, tree_axes: Sequence[str],
-                      radices: Sequence[int],
-                      augment: Optional[jax.Array] = None,
-                      sample_leaf: int = 0, sample_level: int = 0,
-                      engine: str = "auto",
-                      node_engine: Optional[str] = None,
-                      seed: Optional[int] = None,
-                      constraint=None):
-    """Returns the per-lane SPMD function (for use inside shard_map).
-
-    ``sample_leaf`` / ``sample_level``: stochastic-greedy sampling at the
-    leaves / accumulation nodes (Mirzasoleiman et al. 2015).
-    ``engine``: inner-loop selection engine for the leaf Greedy calls
-    ('auto' = fastest fitting tier per plans.select_engine).
-    ``node_engine``: engine for the accumulation-node Greedy calls;
-    default None inherits ``engine`` — with 'auto' the (b·k + A)×(b·k)
-    node shape lands on the VMEM-resident megakernel tier, one dispatch
-    per node.
-    ``seed``: static int reseeding the stochastic draws (leaves AND
-    levels); None keeps the legacy fixed tape.
-    ``constraint``: optional hereditary constraint spec with
-    ``bind(ids)`` (core.constraints.KnapsackSpec) applied at the leaves
-    AND every accumulation node."""
-    node_engine = node_engine or engine
-
-    def fn(ids, payloads, valid, *aug):
-        # ---- leaves: Greedy on the local random partition ------------------
-        leaf_key = None
-        if sample_leaf:
-            leaf_key = jax.random.fold_in(
-                _leaf_key(seed),
-                _machine_flat_id(tree_axes, radices))
-        s_prev = greedy(objective, ids, payloads, valid, k,
-                        sample=sample_leaf, key=leaf_key, engine=engine,
-                        constraint=(constraint.bind(ids)
-                                    if constraint is not None else None))
-
-        # ---- accumulation levels ------------------------------------------
-        s_prev = accumulate_levels(objective, s_prev, k, tree_axes, radices,
-                                   aug_levels=aug[0] if aug else None,
-                                   sample_level=sample_level,
-                                   node_engine=node_engine, seed=seed,
-                                   constraint=constraint)
-        return _broadcast_from_root(s_prev, tree_axes, radices)
-
-    return fn
-
-
-def greedyml_distributed(objective, ids: jax.Array, payloads: jax.Array,
-                         valid: jax.Array, k: int, mesh: Mesh,
-                         tree_axes: Sequence[str],
-                         augment: Optional[jax.Array] = None,
-                         sample_leaf: int = 0, sample_level: int = 0,
-                         engine: str = "auto",
-                         node_engine: Optional[str] = None,
-                         seed: Optional[int] = None,
-                         constraint=None) -> Solution:
-    """Run distributed GreedyML over `mesh`.
-
-    ids/payloads/valid: leading dim n sharded over `tree_axes` (outermost
-    mesh axis first in the PartitionSpec so lane i gets block i). `augment`:
-    optional (L, A, …) per-level extra evaluation elements (k-medoid §6.4),
-    replicated. ``seed``: static int reseeding the stochastic-greedy
-    draws; None keeps the legacy fixed tape, so unseeded runs reproduce
-    older results bit-for-bit. ``constraint``: optional hereditary
-    constraint spec (core.constraints.KnapsackSpec) bound per pool at the
-    leaves and every accumulation node (replicated on every lane).
-    """
-    radices = [mesh.shape[a] for a in tree_axes]
-    data_spec = P(tuple(reversed(tree_axes)))
-    in_specs = [data_spec, data_spec, data_spec]
-    args = [ids, payloads, valid]
-    if augment is not None:
-        in_specs.append(P())
-        args.append(augment)
-    fn = greedyml_shmap_fn(objective, k, tree_axes, radices,
-                           sample_leaf=sample_leaf,
-                           sample_level=sample_level, engine=engine,
-                           node_engine=node_engine, seed=seed,
-                           constraint=constraint)
-    out = jax.shard_map(fn, mesh=mesh,
-                        in_specs=tuple(in_specs),
-                        out_specs=Solution(P(), P(), P(), P(), P()),
-                        check_vma=False)(*args)
-    return out
-
-
 # ---------------------------------------------------------------------------
 # Level-by-level dispatch — the supervised runtime's unit of work
 # ---------------------------------------------------------------------------
 #
-# The monolithic drivers above compile the whole recurrence into ONE SPMD
-# program: a lost lane kills the dispatch and every level of progress with
-# it. The supervised runtime (runtime/supervisor.py) instead drives the
-# SAME Algorithm 3.1 rounds level-by-level from the host — each level is
-# one dispatch over the per-lane Solution state, which round-trips through
-# host memory between levels and is checkpointed there. `LevelDispatcher`
-# is the dispatch layer: identical lane-local bodies run either over a
-# real mesh (shard_map, one device per lane) or single-device (nested
-# vmap with the same named axes, core.simulate-style), so the recovery
-# logic is testable on one CPU and deployable on a pod unchanged.
+# Each stage is one dispatch over the per-lane Solution state, which
+# round-trips through host memory between stages: the supervised runtime
+# (runtime/supervisor.py) checkpoints it there, so a lost lane costs one
+# level, not the whole tree. Identical lane-local bodies run either over a
+# real mesh (shard_map, one device per lane) or on one device (nested vmap
+# with the same named axes), so the recovery logic is testable on one CPU
+# and deployable on a pod unchanged.
 
 
 def shard_lanes(ids: jax.Array, payloads: jax.Array, valid: jax.Array,
                 lanes: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Split flat (n, …) candidate arrays into stacked (lanes, n/lanes, …)
-    blocks — lane i gets contiguous block i, the same layout the
-    monolithic driver's PartitionSpec produces."""
+    blocks — lane i gets contiguous block i."""
     n = ids.shape[0]
     if n % lanes:
         raise ValueError(f"n={n} must divide over {lanes} lanes")
@@ -327,8 +244,9 @@ def empty_lane_solutions(lanes: int, k: int,
 
 def root_solution(lane_sols: Solution) -> Solution:
     """Extract the final answer from the stacked state after the last
-    level: the paper returns machine 0's solution (all lanes agree unless
-    stochastic node sampling diverged them — row 0 IS S_0 either way)."""
+    level: the paper returns machine 0's solution, row 0 (another lane
+    may hold a different one: each keeps its own S_prev when that scores
+    higher)."""
     return jax.tree.map(lambda x: x[0], lane_sols)
 
 
@@ -424,6 +342,20 @@ class LevelDispatcher:
                        lambda: self._build_level(lvl, aug_row is not None))
         return fn(lane_sols, aug_row) if aug_row is not None \
             else fn(lane_sols)
+
+    def run(self, ids: jax.Array, payloads: jax.Array, valid: jax.Array,
+            augment: Optional[jax.Array] = None) -> Solution:
+        """Algorithm 3.1 in one call over flat (n, …) candidates: lane i
+        takes contiguous block i, then the leaf Greedy and every level
+        run in turn, and machine 0's solution is returned. ``augment``:
+        optional (L, A, …) evaluation rows (paper §6.4); row ℓ joins the
+        ground of every node at level ℓ. The supervisor runs the same
+        stages with checkpoints between them."""
+        state = self.leaves(*shard_lanes(ids, payloads, valid, self.lanes))
+        for lvl in range(self.num_levels):
+            state = self.level(state, lvl,
+                               None if augment is None else augment[lvl])
+        return root_solution(state)
 
     # ------------------------------------------------------------- builders
     def _get(self, key, build):
@@ -547,66 +479,3 @@ class LevelDispatcher:
         return jax.jit(jax.shard_map(shbody, mesh=self.mesh,
                                      in_specs=in_specs,
                                      out_specs=sol_spec, check_vma=False))
-
-
-def randgreedi_distributed(objective, ids, payloads, valid, k, mesh,
-                           machine_axes: Sequence[str],
-                           augment=None, engine: str = "auto",
-                           node_engine: Optional[str] = None,
-                           sample_leaf: int = 0,
-                           seed: Optional[int] = None,
-                           constraint=None) -> Solution:
-    """RandGreedi = GreedyML with a single accumulation level: all machine
-    axes form ONE level (gather everything to every lane, one global
-    Greedy). Implemented by flattening the axes tuple into one level.
-    ``sample_leaf``/``seed`` enable reseedable stochastic greedy at the
-    leaves (as in greedyml_distributed). ``constraint``: a spec with
-    ``bind(ids)`` (e.g. KnapsackSpec) — bound to the lane's global ids at
-    the leaf and to the gathered union at the accumulation node, exactly
-    as in greedyml_distributed."""
-    radices = [math.prod(mesh.shape[a] for a in machine_axes)]
-    node_eng = node_engine or engine
-
-    def fn(ids_, payloads_, valid_, *aug):
-        leaf_key = None
-        if sample_leaf:
-            leaf_key = jax.random.fold_in(
-                _leaf_key(seed),
-                _machine_flat_id(machine_axes,
-                                 [mesh.shape[a] for a in machine_axes]))
-        s_leaf = greedy(objective, ids_, payloads_, valid_, k,
-                        sample=sample_leaf, key=leaf_key, engine=engine,
-                        constraint=(constraint.bind(ids_)
-                                    if constraint is not None else None))
-        u_ids, u_pay, u_val = s_leaf.ids, s_leaf.payloads, s_leaf.valid
-        for ax in machine_axes:
-            u_ids = lax.all_gather(u_ids, ax, axis=0, tiled=True)
-            u_pay = lax.all_gather(u_pay, ax, axis=0, tiled=True)
-            u_val = lax.all_gather(u_val, ax, axis=0, tiled=True)
-        ground, ground_valid = u_pay, u_val
-        if aug:
-            ground = jnp.concatenate([u_pay, aug[0][0]], axis=0)
-            ground_valid = jnp.concatenate(
-                [u_val, jnp.ones(aug[0][0].shape[0], bool)], axis=0)
-        s_new = greedy(objective, u_ids, u_pay, u_val, k,
-                       ground=ground, ground_valid=ground_valid,
-                       engine=node_eng,
-                       constraint=(constraint.bind(u_ids)
-                                   if constraint is not None else None))
-        prev_score = replay_value(objective, s_leaf.payloads, s_leaf.valid,
-                                  ground, ground_valid)
-        s_prev = select_better(
-            s_new, Solution(s_leaf.ids, s_leaf.payloads, s_leaf.valid,
-                            prev_score, s_leaf.evals))
-        return _broadcast_from_root(s_prev, machine_axes,
-                                    [mesh.shape[a] for a in machine_axes])
-
-    data_spec = P(tuple(reversed(machine_axes)))
-    in_specs = [data_spec, data_spec, data_spec]
-    args = [ids, payloads, valid]
-    if augment is not None:
-        in_specs.append(P())
-        args.append(augment)
-    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
-                         out_specs=Solution(P(), P(), P(), P(), P()),
-                         check_vma=False)(*args)

@@ -24,11 +24,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.functions import make_objective
-from repro.core.greedy import Solution, greedy, replay_value, select_better
+from repro.core.greedy import greedy
+from repro.core.greedyml import LevelDispatcher, root_solution
 from repro.core.tree import AccumulationTree
-from repro.kernels import ops as kernel_ops
-
-F32 = jnp.float32
 
 
 @dataclasses.dataclass
@@ -97,10 +95,17 @@ def run_tree_dense(objective_name: str, payloads: np.ndarray, k: int,
                    engine: str = "auto",
                    node_engine: Optional[str] = None,
                    drop_leaves: Sequence[int] = ()) -> SimResult:
-    """``engine`` drives the leaf Greedy calls; ``node_engine`` (default:
-    inherit) the accumulation nodes — under 'auto' the (b·k + A)×(b·k)
-    node shape lands on the megakernel's VMEM-resident tier, one kernel
-    dispatch per internal node (DESIGN §Perf).
+    """The paper's tree on one device: the random tape deals the elements
+    to padded per-machine pools, and `LevelDispatcher` (no mesh) runs the
+    leaf Greedy and every accumulation level. A ragged tree (m < b^L) runs
+    as b^L lanes whose machines m … b^L−1 hold all-invalid pools; their
+    slots sort after every real child's in each union, so each node's
+    Greedy sees the candidates the paper's node sees, in the same order.
+
+    ``engine`` drives the leaf Greedy calls; ``node_engine`` (default:
+    inherit) the accumulation nodes. ``augment`` > 0 adds, for k-medoid
+    and facility location, one sample of that many elements per level to
+    every node's ground (paper §6.4), drawn from the ``seed + 1`` tape.
 
     ``drop_leaves``: machine ids whose partitions are LOST (their pools
     are invalidated, so they contribute empty leaf solutions) — the
@@ -109,102 +114,47 @@ def run_tree_dense(objective_name: str, payloads: np.ndarray, k: int,
     costs only the Barbosa et al. (1502.02606) / Lucic et al.
     (1605.09619) expected-quality term, which tests assert as a
     tolerance band against the failure-free run."""
-    node_engine = node_engine or engine
     n = payloads.shape[0]
     m, b, L = tree.m, tree.b, tree.num_levels
+    lanes = b ** L
     obj = make_objective(objective_name, universe=universe, backend=backend)
     assign = partition(n, m, seed)
     counts = np.bincount(assign, minlength=m)
-    n_max = int(counts.max())
 
-    # build padded per-machine pools
-    pool_ids = np.full((m, n_max), -1, np.int32)
-    pool_valid = np.zeros((m, n_max), bool)
-    pool_pay = np.zeros((m, n_max) + payloads.shape[1:], payloads.dtype)
-    cursor = np.zeros(m, np.int64)
-    for e in range(n):
-        mi = assign[e]
-        j = cursor[mi]
-        pool_ids[mi, j] = e
-        pool_valid[mi, j] = True
-        pool_pay[mi, j] = payloads[e]
-        cursor[mi] += 1
-    for mi in drop_leaves:
-        pool_valid[mi] = False          # lost partition → empty leaf
+    # padded per-machine pools, each in ascending element id
+    order = np.argsort(assign, kind="stable")
+    slot = np.arange(n) - np.repeat(np.cumsum(counts) - counts, counts)
+    pool_ids = np.full((lanes, int(counts.max())), -1, np.int32)
+    pool_ids[assign[order], slot] = order
+    pool_valid = pool_ids >= 0
+    pool_pay = np.zeros(pool_ids.shape + payloads.shape[1:], payloads.dtype)
+    pool_pay[pool_valid] = payloads[pool_ids[pool_valid]]
+    pool_valid[list(drop_leaves)] = False   # lost partition → empty leaf
 
-    rng = np.random.default_rng(seed + 1)
+    aug = None
+    if augment > 0 and objective_name in ("kmedoid", "facility"):
+        rng = np.random.default_rng(seed + 1)
+        aug = jnp.asarray(payloads[rng.integers(0, n, size=(L, augment))])
 
-    def leaf_fn(ids, pay, val):
-        return greedy(obj, ids, pay, val, k, engine=engine)
-
-    # m leaf caches live at once under vmap → scale the fused budget gate
-    with kernel_ops.fused_replicas(m):
-        sols = jax.jit(jax.vmap(leaf_fn))(
-            jnp.asarray(pool_ids), jnp.asarray(pool_pay),
-            jnp.asarray(pool_valid))
+    disp = LevelDispatcher(obj, k, (b,) * L, engine=engine,
+                           node_engine=node_engine)
+    sols = disp.leaves(jnp.asarray(pool_ids), jnp.asarray(pool_pay),
+                       jnp.asarray(pool_valid))
+    evals = np.asarray(sols.evals)
     per_node: Dict[Tuple[int, int], int] = {
-        (0, i): int(sols.evals[i]) for i in range(m)}
+        (0, i): int(evals[i]) for i in range(m)}
     comm = 0
-
-    # index map: machine id → row in the current solution stack
-    level_ids = list(range(m))
-
     for lvl in range(1, L + 1):
-        nodes = tree.nodes_at_level(lvl)
-        bk = b * k
-        u_ids = np.full((len(nodes), bk), -1, np.int32)
-        u_val = np.zeros((len(nodes), bk), bool)
-        u_pay = np.zeros((len(nodes), bk) + payloads.shape[1:], payloads.dtype)
-        sol_ids = np.asarray(sols.ids)
-        sol_val = np.asarray(sols.valid)
-        sol_pay = np.asarray(sols.payloads)
-        prev_rows = []
-        for r, nid in enumerate(nodes):
-            ch = tree.children_of(lvl, nid)
-            for j, cid in enumerate(ch):
-                row = level_ids.index(cid)
-                u_ids[r, j * k:(j + 1) * k] = sol_ids[row]
-                u_val[r, j * k:(j + 1) * k] = sol_val[row]
-                u_pay[r, j * k:(j + 1) * k] = sol_pay[row]
-                comm += int(sol_val[row].sum())
-            prev_rows.append(level_ids.index(nid))
+        valid = np.asarray(sols.valid)
+        sols = disp.level(sols, lvl - 1, None if aug is None else aug[lvl - 1])
+        # select_better chains evals: a node's own are the increment
+        new_evals = np.asarray(sols.evals)
+        for nid in tree.nodes_at_level(lvl):
+            per_node[(lvl, nid)] = int(new_evals[nid] - evals[nid])
+            comm += int(valid[tree.children_of(lvl, nid)].sum())
+        evals = new_evals
 
-        aug_arr = None
-        if augment > 0 and objective_name in ("kmedoid", "facility"):
-            idx = rng.integers(0, n, size=(len(nodes), augment))
-            aug_arr = payloads[idx]
-
-        def node_fn(ids, pay, val, *aug):
-            if aug:
-                ground = jnp.concatenate([pay, aug[0]], axis=0)
-                gval = jnp.concatenate(
-                    [val, jnp.ones(aug[0].shape[0], bool)])
-            else:
-                ground, gval = pay, val
-            s_new = greedy(obj, ids, pay, val, k, ground=ground,
-                           ground_valid=gval, engine=node_engine)
-            return s_new, ground, gval
-
-        args = [jnp.asarray(u_ids), jnp.asarray(u_pay), jnp.asarray(u_val)]
-        if aug_arr is not None:
-            args.append(jnp.asarray(aug_arr))
-        with kernel_ops.fused_replicas(len(nodes)):
-            new_sols, grounds, gvals = jax.jit(jax.vmap(node_fn))(*args)
-
-        # argmax{f(S), f(S_prev)} — S_prev is the same-id child's solution
-        prev = jax.tree.map(lambda x: x[np.asarray(prev_rows)], sols)
-        prev_scores = jax.jit(jax.vmap(
-            lambda p, v, g, gv: replay_value(obj, p, v, g, gv)))(
-                prev.payloads, prev.valid, grounds, gvals)
-        prev = Solution(prev.ids, prev.payloads, prev.valid, prev_scores,
-                        prev.evals)
-        # select_better chains evals (prev chain + this node's own greedy)
-        sols = jax.jit(jax.vmap(select_better))(new_sols, prev)
-        for r, nid in enumerate(nodes):
-            per_node[(lvl, nid)] = int(new_sols.evals[r])
-        level_ids = nodes
-
-    final = jax.tree.map(lambda x: x[0], sols)
+    final = root_solution(sols)
     evals_critical = sum(per_node[(lvl, 0)] for lvl in range(L + 1))
     ids_out = np.asarray(final.ids)[np.asarray(final.valid)]
     gval = global_value(objective_name, payloads, ids_out, universe)

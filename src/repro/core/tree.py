@@ -5,8 +5,8 @@ Node ids follow the paper exactly: leaves are machine ids at level 0;
 child id; the root is (L, 0) with L = ceil(log_b m). Ragged trees (m not a
 power of b) have at most one node with arity < b per level.
 
-``MixedRadixTree`` generalizes to per-level branching (b_1, …, b_L) — the
-shard_map driver uses it to map tree levels onto physical mesh axes
+``MixedRadixTree`` generalizes to per-level branching (b_1, …, b_L) —
+`core.greedyml.LevelDispatcher` maps such levels onto physical mesh axes
 (e.g. 512 devices = 16 × 16 × 2). Theorem 4.4 only counts levels, so the
 α/(L+1) guarantee holds unchanged.
 """

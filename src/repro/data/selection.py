@@ -5,9 +5,10 @@ Given per-document embeddings (from the model's own encoder, a proxy
 embedder, or precomputed), select a maximally-diverse coreset with
 facility-location (or exemplars with k-medoid) via:
 
-  * the **distributed** driver (core.greedyml) when a mesh is available —
-    embeddings stay sharded across the data axis exactly as training shards
-    documents; the accumulation tree reuses the mesh axes;
+  * the **distributed** tree (core.greedyml.LevelDispatcher) when a mesh
+    is available — embeddings stay sharded across the data axis exactly as
+    training shards documents; the accumulation tree reuses the mesh axes,
+    and RandGreedi gathers over all of them in one level;
   * the **simulator** (core.simulate) on a single device;
   * the **streaming engine** (repro.streaming) for ``stream:*`` specs —
     documents arrive in batches through a sieve instead of running an
@@ -21,6 +22,7 @@ facility-location (or exemplars with k-medoid) via:
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -30,10 +32,10 @@ from jax.sharding import Mesh
 
 from repro.core.functions import make_objective
 from repro.core.greedy import greedy
-from repro.core.greedyml import greedyml_distributed, randgreedi_distributed
+from repro.core.greedyml import LevelDispatcher
 from repro.core.simulate import run_tree_dense, run_greedy_dense
 from repro.core.tree import AccumulationTree, randgreedi_tree
-from repro.launch.mesh import factor_tree_axes
+from repro.launch.mesh import factor_tree_axes, make_tree_mesh
 
 
 def parse_spec(spec: str) -> Tuple[str, str]:
@@ -96,10 +98,14 @@ def select_coreset(embeddings: np.ndarray, k: int, spec: str = "greedyml:facilit
         ids = jnp.arange(n, dtype=jnp.int32)
         pay = jnp.asarray(embeddings)
         valid = jnp.ones((n,), bool)
+        radices = tuple(mesh.shape[a] for a in axes)
         if algo == "greedyml":
-            sol = greedyml_distributed(obj, ids, pay, valid, k, mesh, axes)
+            sol = LevelDispatcher(obj, k, radices, mesh=mesh,
+                                  tree_axes=axes).run(ids, pay, valid)
         elif algo == "randgreedi":
-            sol = randgreedi_distributed(obj, ids, pay, valid, k, mesh, axes)
+            m = math.prod(radices)
+            sol = LevelDispatcher(obj, k, (m,), mesh=make_tree_mesh((m,))
+                                  ).run(ids, pay, valid)
         elif algo == "greedy":
             sol = greedy(obj, ids, pay, valid, k)
         else:

@@ -17,10 +17,9 @@ FLOP/collective accounting: XLA's HloCostAnalysis counts while-loop bodies
 ONCE, so rolled layer/microbatch scans under-count by the trip count. The
 dry-run therefore compiles two small UNROLLED probe variants (1× and 2× the
 layer period, one microbatch) per cell and fits cost = intercept + slope·R,
-extrapolating to the full depth and microbatch count (quadratic 3-point fit
-in k for the GreedyML technique cells, whose internal-node greedy is
-O(b·k²)). The full-size compile still provides memory_analysis (fits-check)
-and the real collective schedule.
+extrapolating to the full depth and microbatch count. The full-size
+compile still provides memory_analysis (fits-check) and the real
+collective schedule.
 """
 import argparse
 import os
@@ -28,16 +27,12 @@ import json
 import re
 import time
 import traceback
-from typing import Any, Callable, Dict, List, Optional, Tuple
-
-import jax
-import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from typing import Any, Callable, Dict, List, Tuple
 
 from repro.configs import registry
 from repro.configs.base import OptimConfig, ShapeConfig, TrainConfig
 from repro.launch import steps
-from repro.launch.mesh import factor_tree_axes, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import transformer as T
 from repro.runtime import flags
 
@@ -140,18 +135,6 @@ def _linfit(pts, r_full: int):
                  for v1, v2 in zip(p1[1:], p2[1:]))
 
 
-def _quadfit(pts, r_full: int):
-    import numpy as np
-    rs = np.array([p[0] for p in pts], dtype=float)
-    vander = np.vander(rs, 3)
-    x = float(r_full)
-    out = []
-    for j in range(1, len(pts[0])):
-        cs = np.linalg.solve(vander, np.array([p[j] for p in pts]))
-        out.append(float(cs[0] * x * x + cs[1] * x + cs[2]))
-    return tuple(out)
-
-
 def _opt_flops_per_device(cfg, devices: int) -> float:
     # AdamW (~10 flops/param) + global-norm clip (~2) on sharded params
     return 12.0 * cfg.param_count() / devices
@@ -247,69 +230,6 @@ def probe_lm_cell(arch: str, shape_name: str, mesh, devices: int
 
 
 # ---------------------------------------------------------------------------
-# Technique cells (the paper's own workload on the production mesh)
-# ---------------------------------------------------------------------------
-
-TECHNIQUE_CELLS = {
-    "greedyml-facility": dict(objective="facility", n=1 << 20, d=256, k=256),
-    "greedyml-kcover": dict(objective="kcover", n=1 << 19,
-                            universe=1 << 18, k=256),
-}
-
-
-def lower_technique(name: str, mesh, k_override: Optional[int] = None):
-    from repro.core.functions import make_objective
-    from repro.core.greedyml import greedyml_distributed
-
-    spec = TECHNIQUE_CELLS[name]
-    axes = factor_tree_axes(mesh, tuple(mesh.axis_names))
-    n = spec["n"]
-    k = k_override or spec["k"]
-    if spec["objective"] == "facility":
-        pay = jax.ShapeDtypeStruct((n, spec["d"]),
-                                   jnp.dtype(spec.get("dtype", "float32")))
-        obj = make_objective("facility", backend="ref")
-    else:
-        w = spec["universe"] // 32
-        pay = jax.ShapeDtypeStruct((n, w), jnp.uint32)
-        obj = make_objective("kcover", universe=spec["universe"],
-                             backend="ref")
-    ids = jax.ShapeDtypeStruct((n,), jnp.int32)
-    valid = jax.ShapeDtypeStruct((n,), jnp.bool_)
-    data_spec = NamedSharding(mesh, P(tuple(reversed(axes))))
-
-    def fn(ids_, pay_, valid_):
-        return greedyml_distributed(obj, ids_, pay_, valid_, k, mesh, axes,
-                                    sample_leaf=spec.get("sample", 0),
-                                    sample_level=spec.get("sample_level", 0))
-
-    return jax.jit(fn, in_shardings=(data_spec, data_spec, data_spec)
-                   ).lower(ids, pay, valid)
-
-
-def probe_technique_cell(name: str, mesh) -> Dict[str, Any]:
-    k_full = TECHNIQUE_CELLS[name]["k"]
-    # tiny unrolled probes: XLA optimization time explodes superlinearly on
-    # long unrolled chains (k=16: 4 s → k=32: >3 min), but the greedy cost
-    # model is EXACTLY quadratic in k — k steps over O(n/m) leaf candidates
-    # (linear) + L·k steps over O(b·k) union candidates + k-long replays
-    # (quadratic) — so a 3-point quadratic fit at small k extrapolates
-    # soundly to the full k
-    ks = (4, 8, 16)
-    # quadratic in k: leaf greedy is O(n/m·k); node greedy is O(b·k·k)
-    pts = _probe(lambda k: lower_technique(name, mesh, k_override=k), ks)
-    f_full, c_full, b_full = _quadfit(pts, k_full)
-    return {
-        "method": "unrolled 3-point quadratic fit in k",
-        "points": pts,
-        "n_micro": 1,
-        "flops": f_full,
-        "collective_moved_bytes": c_full,
-        "bytes_accessed": b_full,
-    }
-
-
-# ---------------------------------------------------------------------------
 # Driver
 # ---------------------------------------------------------------------------
 
@@ -324,12 +244,9 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
                            "mesh": mesh_kind, "devices": devices}
     try:
         with mesh:
-            if arch in TECHNIQUE_CELLS:
-                lowered = lower_technique(arch, mesh)
-            else:
-                cfg, ocfg = _cell_cfgs(arch)
-                shape = registry.get_shape(shape_name)
-                lowered = lower_cell(cfg, ocfg, shape, mesh)
+            cfg, ocfg = _cell_cfgs(arch)
+            shape = registry.get_shape(shape_name)
+            lowered = lower_cell(cfg, ocfg, shape, mesh)
             t_lower = time.time() - t0
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
@@ -339,10 +256,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
             rec["compile_s"] = round(t_compile, 1)
             if probe and mesh_kind == "single":
                 t1 = time.time()
-                est = (probe_technique_cell(arch, mesh)
-                       if arch in TECHNIQUE_CELLS else
-                       probe_lm_cell(arch, shape_name, mesh, devices))
-                rec["estimated"] = est
+                rec["estimated"] = probe_lm_cell(arch, shape_name, mesh,
+                                                 devices)
                 rec["probe_s"] = round(time.time() - t1, 1)
             rec["ok"] = True
             ma = rec["per_device"]["memory"]
@@ -375,14 +290,10 @@ def main(argv=None) -> None:
     ap.add_argument("--out", default="results/dryrun")
     ap.add_argument("--skip-existing", action="store_true")
     ap.add_argument("--no-probe", action="store_true")
-    ap.add_argument("--technique", action="store_true",
-                    help="also lower the GreedyML selection cells")
     args = ap.parse_args(argv)
 
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
     cells = [(a, s) for a, s, skip in registry.cells() if skip is None]
-    if args.technique:
-        cells += [(t, "selection") for t in TECHNIQUE_CELLS]
     if args.only:
         keep = set(args.only.split(","))
         cells = [(a, s) for a, s in cells

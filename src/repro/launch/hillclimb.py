@@ -24,10 +24,9 @@ import jax
 from repro.configs import registry
 from repro.configs.base import OptimConfig, ShapeConfig, TrainConfig
 from repro.launch import steps
-from repro.launch.dryrun import (TECHNIQUE_CELLS, _cell_cfgs, _linfit,
+from repro.launch.dryrun import (_cell_cfgs, _linfit,
                                  _opt_flops_per_device, _probe, _shrink,
-                                 analyze, lower_cell, lower_technique,
-                                 probe_technique_cell)
+                                 analyze, lower_cell)
 from repro.launch.mesh import make_production_mesh
 from repro.models import transformer as T
 from repro.sharding import axes as AX
@@ -197,89 +196,6 @@ def smollm_iter2_no_remat():
                          profile="dp_only", remat="none")
     report("smollm train_4k dp_only+no_remat", var)
     return {"dp_only_no_remat": var}
-
-
-@exp("facility_bf16")
-def facility_bf16():
-    """Hypothesis: the selection step is memory-term-bound on the ground-set
-    payload reads (f32). bf16 payloads halve the bytes term at negligible
-    quality cost (gains reduce in f32 anyway). Predicted: memory ↓ 2×."""
-    mesh = make_production_mesh(multi_pod=False)
-    with mesh:
-        base = probe_technique_cell("greedyml-facility", mesh)
-        compiled = lower_technique("greedyml-facility", mesh).compile()
-        rec_b = analyze(compiled, 256)
-        rec_b["estimated"] = base
-    report("greedyml-facility BASELINE", rec_b)
-
-    import repro.launch.dryrun as DR
-    old = DR.TECHNIQUE_CELLS["greedyml-facility"]
-    DR.TECHNIQUE_CELLS["greedyml-facility"] = dict(old, dtype="bfloat16")
-    try:
-        with mesh:
-            var = probe_technique_cell("greedyml-facility", mesh)
-            compiled = lower_technique("greedyml-facility", mesh).compile()
-            rec_v = analyze(compiled, 256)
-            rec_v["estimated"] = var
-        report("greedyml-facility +bf16 payloads", rec_v)
-    finally:
-        DR.TECHNIQUE_CELLS["greedyml-facility"] = old
-    return {"baseline": rec_b, "bf16": rec_v}
-
-
-@exp("facility_stochastic")
-def facility_stochastic():
-    """Iteration 2 (facility). Hypothesis: the selection step is
-    memory-term-bound on the per-step re-scan of the hoisted leaf similarity
-    matrix (k × n/m·n/m reads). Stochastic greedy (Mirzasoleiman et al.
-    2015) samples s=64 candidates per step — (1−1/e−ε) guarantee with
-    s ≈ (n/k)ln(1/ε) — cutting the leaf gains reads by n/(m·s) = 64×.
-    Measured quality on this instance: 0.997 of exact (see
-    tests/test_core_properties.py). Predicted: memory term ↓ ≫5×."""
-    mesh = make_production_mesh(multi_pod=False)
-    import repro.launch.dryrun as DR
-    with mesh:
-        base = probe_technique_cell("greedyml-facility", mesh)
-        compiled = lower_technique("greedyml-facility", mesh).compile()
-        rec_b = analyze(compiled, 256)
-        rec_b["estimated"] = base
-    report("greedyml-facility BASELINE", rec_b)
-    old = DR.TECHNIQUE_CELLS["greedyml-facility"]
-    DR.TECHNIQUE_CELLS["greedyml-facility"] = dict(old, sample=64)
-    try:
-        with mesh:
-            var = probe_technique_cell("greedyml-facility", mesh)
-            compiled = lower_technique("greedyml-facility", mesh).compile()
-            rec_v = analyze(compiled, 256)
-            rec_v["estimated"] = var
-        report("greedyml-facility +stochastic(s=64)", rec_v)
-    finally:
-        DR.TECHNIQUE_CELLS["greedyml-facility"] = old
-    return {"baseline": rec_b, "stochastic": rec_v}
-
-
-@exp("facility_stochastic_levels")
-def facility_stochastic_levels():
-    """Iteration 3 (facility). After leaf sampling, the remaining memory
-    term is the EXACT accumulation-node greedies re-scanning their b·k=4096
-    union similarity rows every step. Sample there too (s=64; the union is
-    already a pre-screened high-quality pool, so quality risk is lower than
-    at leaves). Predicted: memory ↓ another ~3×."""
-    mesh = make_production_mesh(multi_pod=False)
-    import repro.launch.dryrun as DR
-    old = DR.TECHNIQUE_CELLS["greedyml-facility"]
-    DR.TECHNIQUE_CELLS["greedyml-facility"] = dict(old, sample=64,
-                                                   sample_level=64)
-    try:
-        with mesh:
-            var = probe_technique_cell("greedyml-facility", mesh)
-            compiled = lower_technique("greedyml-facility", mesh).compile()
-            rec_v = analyze(compiled, 256)
-            rec_v["estimated"] = var
-        report("greedyml-facility +stochastic(leaf+level)", rec_v)
-    finally:
-        DR.TECHNIQUE_CELLS["greedyml-facility"] = old
-    return {"stochastic_levels": rec_v}
 
 
 def main(argv=None):

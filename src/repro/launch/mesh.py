@@ -59,7 +59,7 @@ def device_lanes(requested: Optional[int], flag: str) -> int:
 
 def auto_mesh(shape: Sequence[int], names: Sequence[str]) -> "Mesh":
     """`jax.make_mesh` with every axis in Auto mode: the compiler places
-    the collectives, as the shard_map drivers here expect (the installed
+    the collectives, as the shard_map stages here expect (the installed
     JAX makes Explicit axes by default, under which plain indexing of a
     lane-sharded result is refused)."""
     import jax

@@ -15,8 +15,7 @@ import glob
 import json
 import time
 
-from repro.launch.dryrun import (TECHNIQUE_CELLS, probe_lm_cell,
-                                 probe_technique_cell)
+from repro.launch.dryrun import probe_lm_cell
 from repro.launch.mesh import make_production_mesh
 
 
@@ -43,10 +42,8 @@ def main(argv=None) -> None:
         t0 = time.time()
         try:
             with mesh:
-                est = (probe_technique_cell(rec["arch"], mesh)
-                       if rec["arch"] in TECHNIQUE_CELLS else
-                       probe_lm_cell(rec["arch"], rec["shape"], mesh,
-                                     rec["devices"]))
+                est = probe_lm_cell(rec["arch"], rec["shape"], mesh,
+                                    rec["devices"])
             rec["estimated"] = est
             rec["probe_s"] = round(time.time() - t0, 1)
             with open(path, "w") as f:

@@ -1,13 +1,11 @@
 """Supervised, round-resumable distributed GreedyML selection.
 
-The monolithic shard_map drivers (core.greedyml) compile Algorithm 3.1
-into ONE SPMD program — a lost lane kills the whole dispatch and every
-level of progress with it. This module drives the SAME recurrence
-level-by-level from the host through `core.greedyml.LevelDispatcher`
-(each level = one gather + node-Greedy + argmax dispatch), checkpointing
-the stacked per-lane Solution state through checkpoint.manager after
-every merged level, so recovery is a three-tier state machine
-(DESIGN §Fault tolerance):
+`core.greedyml.LevelDispatcher` runs Algorithm 3.1 one stage per
+dispatch (each level = one gather + node-Greedy + argmax). This module
+drives those stages from the host, as `LevelDispatcher.run` does, but
+checkpoints the stacked per-lane Solution state through
+checkpoint.manager after every merged level, so recovery is a three-tier
+state machine (DESIGN §Fault tolerance):
 
   1. **Level replay** — a transient ``WorkerFailure`` (injected in tests,
      a real device error in deployment) restores the last merged level's

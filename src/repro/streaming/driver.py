@@ -245,9 +245,10 @@ def stream_select_continuous(objective, stream: Iterable, k: int, *,
     fixed evaluation set — and the root is select_better'd against the
     last merged solution, so the served answer is monotone between rounds.
     The merge IS core.greedyml.accumulate_levels — the same Algorithm 3.1
-    rounds the shard_map driver runs — executed under nested vmap axes
-    (one named axis per tree level), so continuous and distributed modes
-    cannot drift semantically. ``lanes`` must equal branching^levels.
+    rounds `LevelDispatcher` dispatches one at a time — executed under
+    nested vmap axes (one named axis per tree level), so continuous and
+    distributed modes cannot drift semantically. ``lanes`` must equal
+    branching^levels.
     ``sample_level``/``seed`` enable reseedable stochastic greedy at the
     merge nodes (threaded to accumulate_levels; seed None keeps the
     legacy fixed tape).

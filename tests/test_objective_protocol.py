@@ -433,32 +433,30 @@ def test_planner_is_the_single_gate(monkeypatch):
 def test_distributed_seed_threading():
     """Explicit seeds reproduce and reseed the stochastic draws; None
     keeps the legacy fixed tape."""
-    from repro.core.greedyml import greedyml_distributed, \
-        randgreedi_distributed
-    mesh = jax.make_mesh((1,), ("m",))
+    from repro.core.greedyml import LevelDispatcher
+    from repro.launch.mesh import make_tree_mesh
+    mesh = make_tree_mesh((1,))
     ids, pay, valid = _pool("facility", n=96)
     obj = _make("facility", "ref")
     kw = dict(sample_leaf=24, sample_level=24)
-    legacy = greedyml_distributed(obj, ids, pay, valid, 6, mesh, ("m",),
-                                  **kw)
-    legacy2 = greedyml_distributed(obj, ids, pay, valid, 6, mesh, ("m",),
-                                   **kw)
-    s5a = greedyml_distributed(obj, ids, pay, valid, 6, mesh, ("m",),
-                               seed=5, **kw)
-    s5b = greedyml_distributed(obj, ids, pay, valid, 6, mesh, ("m",),
-                               seed=5, **kw)
+
+    def tree(**kw):
+        return LevelDispatcher(obj, 6, (1,), mesh=mesh, **kw).run(
+            ids, pay, valid)
+
+    legacy = tree(**kw)
+    legacy2 = tree(**kw)
+    s5a = tree(seed=5, **kw)
+    s5b = tree(seed=5, **kw)
     np.testing.assert_array_equal(np.asarray(legacy.ids),
                                   np.asarray(legacy2.ids))
     np.testing.assert_array_equal(np.asarray(s5a.ids), np.asarray(s5b.ids))
-    seeds = {tuple(np.asarray(
-        greedyml_distributed(obj, ids, pay, valid, 6, mesh, ("m",),
-                             seed=s, **kw).ids).tolist())
-        for s in range(4)}
+    seeds = {tuple(np.asarray(tree(seed=s, **kw).ids).tolist())
+             for s in range(4)}
     assert len(seeds) > 1, "reseeding never changes the draws"
-    rg = randgreedi_distributed(obj, ids, pay, valid, 6, mesh, ("m",),
-                                sample_leaf=24, seed=3)
-    rg2 = randgreedi_distributed(obj, ids, pay, valid, 6, mesh, ("m",),
-                                 sample_leaf=24, seed=3)
+    # RandGreedi: the one-level tree, sampling at the leaves only
+    rg = tree(sample_leaf=24, seed=3)
+    rg2 = tree(sample_leaf=24, seed=3)
     np.testing.assert_array_equal(np.asarray(rg.ids), np.asarray(rg2.ids))
 
 

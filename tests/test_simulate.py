@@ -73,7 +73,7 @@ import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.functions import make_objective
-from repro.core.greedyml import greedyml_distributed
+from repro.core.greedyml import LevelDispatcher
 from repro.core.simulate import run_tree_dense
 from repro.core.tree import AccumulationTree
 from repro.data import synthetic
@@ -83,9 +83,8 @@ sets = synthetic.gen_kcover(256, 512, seed=2)
 bm = synthetic.pack_bitmaps(sets, 512)
 obj = make_objective('kcover', universe=512)
 mesh = make_machine_mesh(8, 2)
-sol = greedyml_distributed(obj, jnp.arange(256, dtype=jnp.int32),
-                           jnp.asarray(bm), jnp.ones(256, bool), 8, mesh,
-                           tree_axes=('lvl0', 'lvl1', 'lvl2'))
+sol = LevelDispatcher(obj, 8, (2, 2, 2), mesh=mesh).run(
+    jnp.arange(256, dtype=jnp.int32), jnp.asarray(bm), jnp.ones(256, bool))
 sim = run_tree_dense('kcover', bm, 8, AccumulationTree(8, 2), seed=0,
                      universe=512)
 print('DIST', float(sol.value), int(sol.valid.sum()))
@@ -98,7 +97,7 @@ print('OK')
 
 
 def test_distributed_driver_matches_simulator_quality():
-    """Runs the shard_map driver on 8 forced host devices in a subprocess
+    """Runs the mesh dispatcher on 8 forced host devices in a subprocess
     (the in-process test session must keep the single real device)."""
     proc = subprocess.run(
         [sys.executable, "-c", DISTRIBUTED_SNIPPET],
