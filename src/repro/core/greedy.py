@@ -170,6 +170,14 @@ def _greedy(objective, state, ids, payloads, valid, k, plan, sample, key,
                              k, constraint,
                              cand_idx if use_sampling else None)
 
+    # a bitmap pool is held in the orientation its gains stream in
+    # (plans.bitmap_orient): the (W, C) view is formed here, once, and the
+    # scan reads the gains' operand and the winner's words from it alone,
+    # so the (C, W) pool is never relaid out for the loop
+    orient = (plans.bitmap_orient(*payloads.shape)
+              if objective.rule.is_bitmap else "cw")
+    pool, axis = (payloads.T, 1) if orient == "wc" else (payloads, 0)
+
     @jax.named_scope("greedy.step")
     def step(carry, xs):
         state, selected, evals, ccounts = carry
@@ -177,22 +185,22 @@ def _greedy(objective, state, ids, payloads, valid, k, plan, sample, key,
                 else jnp.ones((n,), bool))
         if use_sampling:
             idx = xs
-            sub_pay = jnp.take(payloads, idx, axis=0)
+            sub_pay = jnp.take(pool, idx, axis=axis)
             sub_valid = jnp.take(valid & feas & jnp.logical_not(selected),
                                  idx)
-            gains = objective.gains(state, sub_pay, sub_valid)
+            gains = objective.gains(state, sub_pay, sub_valid, orient)
             best_local = jnp.argmax(gains)
             gain = gains[best_local]
             best = idx[best_local]
             n_evals = jnp.sum(sub_valid.astype(jnp.int32))
         else:
             cand_valid = valid & feas & jnp.logical_not(selected)
-            gains = objective.gains(state, payloads, cand_valid)
+            gains = objective.gains(state, pool, cand_valid, orient)
             best = jnp.argmax(gains)
             gain = gains[best]
             n_evals = jnp.sum(cand_valid.astype(jnp.int32))
         accept = jnp.isfinite(gain) & (gain > 0)
-        payload = jax.tree.map(lambda p: p[best], payloads)
+        payload = lax.dynamic_index_in_dim(pool, best, axis, keepdims=False)
         new_state = objective.update(state, payload)
         state = jax.tree.map(
             lambda a, b: jnp.where(accept, a, b), new_state, state)

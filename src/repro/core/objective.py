@@ -11,7 +11,10 @@ conformance test (tests/test_objective_protocol.py), CI sweep
 
 Interface (all methods jit-safe, fixed shapes):
   init_state(ground, ground_valid) → RuleState    state of an EMPTY solution
-  gains(state, cands, cand_valid)  → (C,) marginal gains (−inf if invalid)
+  gains(state, cands, cand_valid[, orient])
+                                   → (C,) marginal gains (−inf if invalid);
+                                     bitmap cands may be the (W, C) view
+                                     (orient 'wc')
   update(state, payload)           → state after adding one element
   value(state)                     → f(S) under this node's evaluation set
   plan_dims(state, cands)          → (n, c, d) for plans.select_engine
@@ -134,9 +137,9 @@ class RuleObjective:
 
     # -- per-step engine (the memory-capped path) ----------------------------
 
-    def gains(self, state: RuleState, cands, cand_valid):
+    def gains(self, state: RuleState, cands, cand_valid, orient="cw"):
         raw = ops.gains(state.ground, state.row, cands, cand_valid,
-                        self.rule, backend=self.backend)
+                        self.rule, backend=self.backend, orient=orient)
         return jnp.where(jnp.isfinite(raw), raw / state.n_eff, raw)
 
     def update(self, state: RuleState, payload) -> RuleState:

@@ -20,13 +20,15 @@ hit the jit/pallas compile cache instead of retracing per shape (DESIGN
 plain next-multiple pad, and constant factors like 1/N are applied
 OUTSIDE the kernels so they never become static compile keys. The one
 exception is the per-step bitmap gains: `gains` hands the kernel the
-(C, W) candidate bitmaps as they are, since it runs on every step of a
-scan already compiled for the logical pool shape.
+candidate bitmaps as they are, (C, W) or their (W, C) view as
+plans.bitmap_orient picks, since it runs on every step of a scan already
+compiled for the logical pool shape.
 
 Every wrapper that launches a kernel counts, while it is traced
 (`runtime.telemetry`): one ``launches``, the ``relayout_bytes`` its pads
 write to reshape operands, and a ``stream`` record of the operand the
-kernel streams (logical shape, padded shape, bytes); the pairwise build
+kernel streams (logical shape, padded shape, bytes, and for the bitmap
+gains the ``orient`` it streams in); the pairwise build
 also counts the HBM bytes its planned tiling moves (``build_bytes``,
 plans.feature_bytes, the full rectangular walk) and the blocks the
 symmetric build's mirror fills (``mirrored_blocks``). The greedy driver
@@ -138,16 +140,17 @@ def _relayout(src, out):
     return out
 
 
-def _launch(kernel: str, logical, streamed) -> None:
+def _launch(kernel: str, logical, streamed, **extra) -> None:
     """Trace-time counts of one kernel launch: ``launches``, and a
     ``stream`` record of the operand the kernel streams, logical shape
-    against the padded shape it is handed."""
+    against the padded shape it is handed (and any `extra` fields, such
+    as the bitmap gains' ``orient``)."""
     telemetry.count("launches")
     telemetry.record("stream", kernel=kernel,
                      logical=[int(x) for x in logical],
                      padded=[int(x) for x in streamed.shape],
                      bytes=int(streamed.size * streamed.dtype.itemsize),
-                     repeat=telemetry.multiplier())
+                     repeat=telemetry.multiplier(), **extra)
 
 
 def _dummy_ground():
@@ -163,13 +166,15 @@ def _cast_row(row, rule: KernelRule):
 
 
 @jax.named_scope("ops.gains")
-def gains(ground, row, cands, cand_valid, rule: KernelRule, backend=None):
+def gains(ground, row, cands, cand_valid, rule: KernelRule, backend=None,
+          orient: str = "cw"):
     """Per-step marginal gains for any rule: RAW part sums (C,) f32, −inf
     at invalid candidates. Callers normalize by the valid ground count.
 
     Feature rules: ground (N, D), row (N,) state (mind/curmax/cursum),
     cands (C, D). Bitmap rules: ground ignored (may be None), row (W,)
-    covered words, cands (C, W) candidate bitmaps.
+    covered words, cands the (C, W) candidate bitmaps or, with ``orient``
+    'wc', their (W, C) view.
 
     When REPRO_FUSED_CACHE_DTYPE forces 'int8', the per-step path stores
     the ground features quantized too (per-row scale; the kernel
@@ -183,25 +188,12 @@ def gains(ground, row, cands, cand_valid, rule: KernelRule, backend=None):
     if b == "ref":
         if quant:
             ground = _quantized_ground(ground.astype(F32))[2]
-        return ref.gains(ground, _cast_row(row, rule), cands, cand_valid,
+        return ref.gains(ground, _cast_row(row, rule),
+                         cands.T if orient == "wc" else cands, cand_valid,
                          rule)
-    c = cands.shape[0]
     if rule.is_bitmap:
-        # the bitmaps are read in place, in blocks of whole rows: no copy
-        # per step. The step runs inside the greedy's scan, compiled for
-        # the logical pool shape, so a bucketed pad would save no compile
-        w = cands.shape[1]
-        tc = plans.bitmap_block_c(w)
-        if not tc:
-            raise ValueError(
-                f"per-step bitmap gains: no block of 8 candidate rows over "
-                f"{w} words fits the {flags.fused_vmem_mb()} MB VMEM budget")
-        _launch("gains_pallas", cands.shape, cands)
-        raw = gains_pallas(
-            _dummy_ground(), _cast_row(row, rule).reshape(1, -1), cands,
-            rule, interpret=(b == "interpret"), block_c=tc,
-            vmem_limit_bytes=plans.vmem_limit(plans.bitmap_gains_need(tc, w)))
-        return jnp.where(cand_valid, raw[:c], -jnp.inf)
+        return _bitmap_gains(row, cands, cand_valid, rule, b, orient)
+    c = cands.shape[0]
     n_pad = plans.bucket_len(ground.shape[0], FEATURE_TILE_N)
     c_pad = plans.bucket_len(c, FEATURE_TILE_C)
     tiles = plans.feature_tiles("gains", n_pad, c_pad, ground.shape[1],
@@ -222,6 +214,34 @@ def gains(ground, row, cands, cand_valid, rule: KernelRule, backend=None):
                        tiles=(tiles.tn, tiles.tc, tiles.td),
                        vmem_limit_bytes=tiles.limit)[:c]
     return jnp.where(cand_valid, raw, -jnp.inf)
+
+
+def _bitmap_gains(row, cands, cand_valid, rule: KernelRule, b: str,
+                  given: str):
+    """`gains`' bitmap branch. The bitmaps are read in place, in blocks
+    of all W words, in the orientation plans.bitmap_orient picks from the
+    logical (C, W): no copy per step where the caller holds the pool that
+    way (the greedy's step engine does), and only a transpose of a
+    gathered subset where it does not. The step runs inside the greedy's
+    scan, compiled for the logical pool shape, so a bucketed pad would
+    save no compile."""
+    c, w = cands.shape[::-1] if given == "wc" else cands.shape
+    orient = plans.bitmap_orient(c, w)
+    if orient != given:
+        cands = cands.T
+    tc = plans.bitmap_block_c(w, orient)
+    if not tc:
+        raise ValueError(
+            f"per-step bitmap gains: no block of 8 candidates over {w} "
+            f"words fits the {flags.fused_vmem_mb()} MB VMEM budget")
+    _launch("gains_pallas", cands.shape, cands, orient=orient)
+    row = _cast_row(row, rule)
+    raw = gains_pallas(
+        _dummy_ground(), row.reshape((-1, 1) if orient == "wc" else (1, -1)),
+        cands, rule, interpret=(b == "interpret"), block_c=tc, orient=orient,
+        vmem_limit_bytes=plans.vmem_limit(
+            plans.bitmap_gains_need(tc, w, orient)))
+    return jnp.where(cand_valid, raw[:c], -jnp.inf)
 
 
 # ---------------------------------------------------------------------------

@@ -31,14 +31,17 @@ Three entry points, the first and last driven by a `KernelRule`
     candidates × TN ground rows × TD features), accumulating the matrix
     block over the features as the pairwise build does and folding its
     gains into the revisited (1, TC) output on the last feature tile,
-    and bitmap rules read the (C, W) candidate bitmaps in place, in
-    blocks of whole rows (TC × W words, TC from plans.bitmap_block_c)
-    with AND-NOT + popcount.
+    and bitmap rules read the candidate bitmaps in place with AND-NOT +
+    popcount, in the orientation plans.bitmap_orient picks from their
+    shape: the (C, W) array in blocks of whole rows (TC × W words), or
+    its (W, C) view in blocks of TC candidate lanes over all W words,
+    summed down the sublanes (TC from plans.bitmap_block_c).
 
 Tiles come from `plans.feature_tiles` (feature rules), which picks the
 tiling that moves the fewest HBM bytes under the VMEM budget, with its
 VMEM model `plans.feature_need` (≈ 7.6 MB at TN = TC = 512, TD = 384);
-bitmap blocks from `plans.bitmap_gains_need` (≈ 7.9 MB at W = 515).
+bitmap blocks from `plans.bitmap_gains_need` (≈ 8 MB at W = 515 in
+either orientation).
 Where the features fit one tile, each kernel computes exactly the
 full-feature product it did before the contraction axis was tiled.
 """
@@ -266,12 +269,61 @@ def _bitmap_gains_kernel(ground_ref, row_ref, cands_ref, out_ref, *,
     out_ref[:, :tc] = R.bitmap_gains(cands_ref[...], row_ref[...], rule)
 
 
+def _bitmap_gains_wc_kernel(ground_ref, col_ref, cands_ref, out_ref, *,
+                            rule: KernelRule):
+    # one (W, TC) block, candidates on lanes: the (W, 1) covered words
+    # broadcast along the lanes, and the popcounts sum down the sublanes
+    # straight into the lane-dense (1, TC) output
+    del ground_ref
+    part = R.gain_part(col_ref[...], cands_ref[...], rule)      # (W, TC)
+    out_ref[...] = jnp.sum(part, axis=0, keepdims=True)
+
+
+def _bitmap_gains(ground, row, cands, rule, interpret, block_c, orient,
+                  vmem_limit_bytes):
+    """`gains_pallas`' bitmap branch: grid (⌈C/TC⌉,) over blocks of
+    `block_c` candidates (all of them when C is smaller), each over all W
+    words. 'cw': cands (C, W), row (1, W); 'wc': cands (W, C), row
+    (W, 1)."""
+    wc = orient == "wc"
+    w, c = cands.shape if wc else cands.shape[::-1]
+    assert row.shape == ((w, 1) if wc else (1, w)) and block_c > 0, \
+        (row.shape, orient, block_c)
+    tc = min(block_c, c)
+    lanes = tc if wc else -(-tc // 128) * 128
+    blocks = pl.cdiv(c, tc)
+    if wc:
+        kernel = functools.partial(_bitmap_gains_wc_kernel, rule=rule)
+        cand_spec = pl.BlockSpec((w, tc), lambda ci: (0, ci))
+    else:
+        kernel = functools.partial(_bitmap_gains_kernel, rule=rule, tc=tc)
+        cand_spec = pl.BlockSpec((tc, w), lambda ci: (ci, 0))
+    out = pl.pallas_call(
+        kernel,
+        name="gains_pallas",
+        grid=(blocks,),
+        in_specs=[
+            pl.BlockSpec(ground.shape, lambda ci: (0, 0)),
+            pl.BlockSpec(row.shape, lambda ci: (0, 0)),
+            cand_spec,
+        ],
+        out_specs=pl.BlockSpec((1, lanes), lambda ci: (0, ci)),
+        out_shape=jax.ShapeDtypeStruct((1, blocks * lanes), F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=vmem_limit_bytes or None),
+        interpret=interpret,
+    )(ground, row, cands)
+    return out.reshape(blocks, lanes)[:, :tc].reshape(-1)
+
+
 @functools.partial(jax.jit, static_argnames=("rule", "interpret", "block_c",
-                                             "tiles", "vmem_limit_bytes"))
+                                             "orient", "tiles",
+                                             "vmem_limit_bytes"))
 def gains_pallas(ground: jax.Array, row: jax.Array, cands: jax.Array,
                  rule: KernelRule, interpret: bool = False,
-                 gscale=None, block_c: int = 0, tiles: tuple = (),
-                 vmem_limit_bytes: int = 0) -> jax.Array:
+                 gscale=None, block_c: int = 0, orient: str = "cw",
+                 tiles: tuple = (), vmem_limit_bytes: int = 0) -> jax.Array:
     """RAW marginal-gain sums (C,) f32 for ANY registered rule (callers
     normalize outside the kernel so the logical N never becomes a static
     compile key).
@@ -288,37 +340,20 @@ def gains_pallas(ground: jax.Array, row: jax.Array, cands: jax.Array,
     the kernel rescales each block to f32 on-chip — quartering the
     dominant per-step HBM read.
 
-    Bitmap rules: ground is an ignored (8, 128) placeholder, row (1, W)
-    covered words, cands (C, W) candidate bitmaps of any shape, read in
-    place: each block is `block_c` candidate rows (all of them when C is
-    smaller) over all W words, grid (⌈C/TC⌉,). The last block may run
-    past C; its rows only reach entries past C, which the caller cuts.
-    `vmem_limit_bytes`: Mosaic's scoped-VMEM limit (plans.vmem_limit).
+    Bitmap rules: ground is an ignored (8, 128) placeholder; the
+    candidate bitmaps, of any shape, are read in place in the `orient`
+    that plans.bitmap_orient picked: 'cw', cands (C, W) with row (1, W)
+    covered words, blocks of `block_c` whole rows; 'wc', cands the (W, C)
+    view with row (W, 1), blocks of `block_c` candidate lanes. Each block
+    covers all W words (all C candidates when C is smaller than a block),
+    grid (⌈C/TC⌉,). The last block may run past C; it only reaches
+    entries past C, which the caller cuts. `vmem_limit_bytes`: Mosaic's
+    scoped-VMEM limit (plans.vmem_limit).
     """
-    c = cands.shape[0]
     if rule.is_bitmap:
-        w = cands.shape[1]
-        assert row.shape == (1, w) and block_c > 0, (row.shape, block_c)
-        tc = min(block_c, c)
-        lanes = -(-tc // 128) * 128
-        blocks = pl.cdiv(c, tc)
-        out = pl.pallas_call(
-            functools.partial(_bitmap_gains_kernel, rule=rule, tc=tc),
-            name="gains_pallas",
-            grid=(blocks,),
-            in_specs=[
-                pl.BlockSpec(ground.shape, lambda ci: (0, 0)),
-                pl.BlockSpec((1, w), lambda ci: (0, 0)),
-                pl.BlockSpec((tc, w), lambda ci: (ci, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, lanes), lambda ci: (0, ci)),
-            out_shape=jax.ShapeDtypeStruct((1, blocks * lanes), F32),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel",),
-                vmem_limit_bytes=vmem_limit_bytes or None),
-            interpret=interpret,
-        )(ground, row, cands)
-        return out.reshape(blocks, lanes)[:, :tc].reshape(-1)
+        return _bitmap_gains(ground, row, cands, rule, interpret, block_c,
+                             orient, vmem_limit_bytes)
+    c = cands.shape[0]
     tn, tc, td = tiles
     n, d = ground.shape
     assert n % tn == 0 and c % tc == 0 and d % td == 0 and td % 128 == 0, \

@@ -176,29 +176,53 @@ def loop_block_n(n_pad: int, c_pad: int, itemsize: int = 4) -> int:
                          lambda bn: loop_need(bn, n_pad, c_pad, itemsize))
 
 
-# candidate rows per block of the per-step bitmap gains kernel, at most
-# (a v5e reading, PERF §6)
+# candidate rows per block of the per-step bitmap gains kernel on a
+# row-major ('cw') operand, at most (a v5e reading, PERF §6)
 BITMAP_BLOCK_C_MAX = 1024
 
 
-def bitmap_gains_need(tc: int, w: int) -> int:
-    """VMEM bytes of one per-step bitmap-gains grid cell: the (TC, W)
-    uint32 candidate block, double-buffered, and the kernel's (TC, W) f32
-    popcount temporary, each row padded to whole 128-lane vregs."""
-    lanes = -(-w // 128) * 128
-    return 3 * tc * lanes * 4
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
-def bitmap_block_c(w: int) -> int:
-    """Candidate rows per block for the per-step bitmap gains over all
-    `w` words of a row: the largest multiple of 128 up to
-    `BITMAP_BLOCK_C_MAX`, else of 64, 32, 16 or 8 rows, whose
-    `bitmap_gains_need` fits the fused VMEM budget; 0 if none fits."""
+def bitmap_gains_need(tc: int, w: int, orient: str) -> int:
+    """VMEM bytes of one per-step bitmap-gains grid cell: the uint32
+    candidate block, double-buffered, and the kernel's f32 popcount
+    temporary of the same shape. A 'cw' block is (TC, W), each row padded
+    to whole 128-lane vregs; a 'wc' block is (W, TC), the words padded to
+    whole 8-row sublane tiles."""
+    if orient == "wc":
+        return 3 * _round_up(w, 8) * tc * 4
+    return 3 * tc * _round_up(w, 128) * 4
+
+
+def bitmap_block_c(w: int, orient: str) -> int:
+    """Candidates per block of the per-step bitmap gains over all `w`
+    words of each, 0 if no block fits the fused VMEM budget. 'wc': the
+    widest multiple of 128 lanes whose `bitmap_gains_need` fits. 'cw':
+    the largest multiple of 128 rows up to `BITMAP_BLOCK_C_MAX`, else 64,
+    32, 16 or 8 rows, that fits."""
     vmem = flags.fused_vmem_mb() * 2 ** 20
+    if orient == "wc":
+        return int(vmem // bitmap_gains_need(128, w, "wc")) * 128
     for tc in [*range(BITMAP_BLOCK_C_MAX, 0, -128), 64, 32, 16, 8]:
-        if bitmap_gains_need(tc, w) <= vmem:
+        if bitmap_gains_need(tc, w, "cw") <= vmem:
             return tc
     return 0
+
+
+def bitmap_orient(c: int, w: int) -> str:
+    """How the per-step bitmap gains stream a pool of `c` candidates of
+    `w` uint32 words. 'wc': the (W, C) view, words on sublanes and
+    candidates on lanes, where its (8, 128)-tiled footprint
+    ⌈W/8⌉·8 × ⌈C/128⌉·128 is the smaller of the two and a block of 128
+    candidates fits the VMEM budget. Else 'cw': the (C, W) array, words
+    on lanes. The TPU compiler lays a 2-D array out the way that pads it
+    less, so the 'wc' view of such a pool is a bitcast of the array as it
+    lies, and neither orientation streams the other's padding."""
+    wc = _round_up(w, 8) * _round_up(c, 128)
+    cw = _round_up(c, 8) * _round_up(w, 128)
+    return "wc" if wc < cw and bitmap_block_c(w, "wc") else "cw"
 
 
 # Mosaic's scoped-VMEM limit for a kernel that names none (v5e: 16 MiB)

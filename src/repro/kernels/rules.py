@@ -370,10 +370,12 @@ def matrix_block(g, c, rule: KernelRule):
 
 
 def bitmap_gains(cands, row, rule: KernelRule):
-    """Per-step bitmap gains kernel body: (TC, W) candidate bitmaps
-    against the (1, W) covered words → (1, TC) f32. The cands-major
-    layout avoids the block transpose: part works elementwise either
-    way. (Feature rules finish a matrix block, then `partial_gains`.)"""
+    """Per-step bitmap gains kernel body on a row-major block: (TC, W)
+    candidate bitmaps against the (1, W) covered words → (1, TC) f32.
+    The cands-major layout avoids the block transpose: part works
+    elementwise either way. (A word-major (W, TC) block sums `gain_part`
+    down its sublanes instead; feature rules finish a matrix block, then
+    `partial_gains`.)"""
     part = gain_part(row, cands, rule)                 # (TC, W)
     return jnp.sum(part, axis=1, keepdims=True).T      # (1, TC)
 

@@ -142,8 +142,9 @@ def test_driver_launches_per_lane(lanes):
 
 def test_step_engine_streams_the_bitmaps_in_place():
     """Bitmap per-step gains: the kernel streams the candidate bitmaps as
-    they are, so the streamed operand's padded shape is its logical shape
-    and no step writes a copy."""
+    they are, here the (4, N) word-major view of the (N, 4) pool, so the
+    streamed operand's padded shape is its logical shape and no step
+    writes a copy."""
     obj = make_objective("coverage", universe=128, backend="interpret")
     jax.make_jaxpr(lambda i, p, v: greedy(obj, i, p, v, K, engine="step"))(
         jnp.arange(N, dtype=jnp.int32), _pool("coverage"),
@@ -151,8 +152,8 @@ def test_step_engine_streams_the_bitmaps_in_place():
     rec = telemetry.records("greedy")[-1]
     (s,) = rec["streams"]
     assert s == {"span": s["span"], "kernel": "gains_pallas",
-                 "logical": [N, 4], "padded": [N, 4],
-                 "bytes": N * 4 * 4, "repeat": K}
+                 "logical": [4, N], "padded": [4, N],
+                 "bytes": N * 4 * 4, "repeat": K, "orient": "wc"}
     assert rec["relayout_bytes"] == 0
 
 

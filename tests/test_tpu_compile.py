@@ -92,8 +92,9 @@ def test_pairwise(spec, name, dtype):
               spec((N, D)), spec((C, D)), kernels=["pairwise_pallas"])
 
 
-# per-step bitmap gains read (C, W) in place: FIMI retail's and kosarak's
-# transaction counts over their item words, off every alignment
+# per-step bitmap gains read the bitmaps in place, (C, W) or their (W, C)
+# view: FIMI retail's and kosarak's transaction counts over their item
+# words, off every alignment
 BITMAPS = {"coverage": (C, WORDS), "retail": (88_162, 515),
            "kosarak": (990_002, 1_290)}
 
@@ -137,23 +138,35 @@ def _loop_bodies(text):
     return "\n".join(line for n in seen for line in comps[n])
 
 
-def test_step_engine_greedy_streams_retail_in_place(spec):
-    """The whole k-cover greedy at FIMI retail's shape takes the per-step
-    engine; its loop body copies nothing: no pad, and no bitmap array of
-    power-of-two rows (131,072) or whole 512-word tiles (1,024 words)."""
-    (n, words), k = BITMAPS["retail"], 64
-    obj = make_objective("coverage", universe=16_470, backend="pallas")
+@pytest.mark.parametrize("case", ["retail", "kosarak"])
+def test_step_engine_greedy_streams_retail_in_place(spec, case):
+    """The whole k-cover greedy at FIMI retail's (and kosarak's) shape
+    takes the per-step engine and streams the pool in the TPU's own
+    layout of it: the loop body reads the (W, C) view, which the entry
+    computation makes by a bitcast of the pool, never a copy (which would
+    hold a (C, W) array the size of the pool as a temporary); the loop
+    body copies nothing: no pad, and no bitmap array of power-of-two rows
+    or whole 512-word tiles."""
+    (n, words), k = BITMAPS[case], 64
+    obj = make_objective("coverage", universe=32 * words - 10,
+                         backend="pallas")
     fn = lambda i, p, v: greedy(obj, i, p, v, k, engine="auto")
-    text = jax.jit(fn).lower(spec((n,), I32), spec((n, words), U32),
-                             spec((n,), jnp.bool_)).compile().as_text()
+    compiled = jax.jit(fn).lower(spec((n,), I32), spec((n, words), U32),
+                                 spec((n,), jnp.bool_)).compile()
+    text = compiled.as_text()
     assert plans.select_engine(obj.rule, words, n, None,
                                backend="pallas").engine == "step"
+    assert plans.bitmap_orient(n, words) == "wc"
     body = _loop_bodies(text)
-    assert "gains_pallas" in body and f"u32[{n},{words}]" in body
+    assert "gains_pallas" in body and f"u32[{words},{n}]" in body
+    assert f"u32[{n},{words}]" not in body
     assert not re.search(r"\bpad\(", body)
     shapes = re.findall(r"u32\[(\d+),(\d+)\]", body)
-    assert shapes and not [s for s in shapes
-                           if s[0] == "131072" or s[1] == "1024"]
+    padded = {str(1 << (n - 1).bit_length()), str(-(-words // 512) * 512)}
+    assert shapes and not [s for s in shapes if padded & set(s)]
+    assert re.search(rf"= u32\[{words},{n}\]\S* bitcast\(", text)
+    assert not re.search(rf"= u32\[{n},{words}\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 20
 
 
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
